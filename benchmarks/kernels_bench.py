@@ -102,4 +102,6 @@ def tuned_vs_default():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -131,5 +131,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     main()

@@ -74,4 +74,6 @@ def main(tag="baseline"):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -10,11 +10,13 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core.quantize import frac_bits_for, quantize
 from repro.kernels.ops import matmul
 from repro.models import api
 
+enable_compile_cache()
 cfg = dataclasses.replace(get_config("qwen2-0.5b"), n_layers=2, d_model=128,
                           n_heads=4, n_kv_heads=2, d_ff=256, vocab=512)
 key = jax.random.PRNGKey(0)

@@ -11,10 +11,12 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (ConvSpec, Primitives, apply, frac_bits_for, init,
                         quantize)
 from repro.core.qconv import qconv_apply, quantize_conv_params
 
+enable_compile_cache()
 key = jax.random.PRNGKey(0)
 x = jax.random.normal(key, (1, 32, 32, 16)) * 0.5
 

@@ -11,9 +11,9 @@ and the hard verifier can never disagree:
   formulas that used to live in ``tune/runner.py``.
 * :func:`check_schedule` — the hard feasibility verdict the executor, the
   dispatch layer, and the cache audit enforce: unknown/invalid schedule
-  keys are errors, a footprint over the per-backend VMEM budget is an
-  error, a schedule that silently degrades (requested != effective) is a
-  warning.
+  keys, a block that breaks the TPU tiling rule, and a footprint over the
+  chip's VMEM budget are errors; a schedule that silently degrades
+  (requested != effective) is a warning.
 * :func:`audit_cache` — re-verify every entry of a persistent tune cache
   (``scripts/check_plan.py`` runs it over ``artifacts/tune_cache.json`` in
   CI), flagging stale infeasible entries.
@@ -28,22 +28,8 @@ import os
 import re
 from typing import Dict, Iterable, List, Optional, Tuple
 
-from repro.core.energy import TPUv5e
-from repro.kernels.common import cdiv
-
-_TPU = TPUv5e()
-
-# Per-backend VMEM budgets (bytes). Keys are jax backend base names; the
-# cpu/interpret entries use the TPU budget because interpret mode VALIDATES
-# TPU feasibility on CPU — a schedule that only fits in host RAM is still
-# infeasible on the target. REPRO_VMEM_BUDGET overrides everything (e.g. to
-# model a smaller part, the paper's Cortex-M framing).
-BUDGETS: Dict[str, int] = {
-    "tpu": _TPU.vmem_bytes,
-    "cpu": _TPU.vmem_bytes,
-    "gpu": _TPU.vmem_bytes,
-}
-DEFAULT_BUDGET = _TPU.vmem_bytes
+from repro.core.energy import target_chip
+from repro.kernels.common import MXU, SUBLANE, cdiv, round_up
 
 # Schedule keys each kernel's wrapper understands — anything else in a
 # config dict is a typo'd knob that would be silently ignored at dispatch.
@@ -84,15 +70,16 @@ def weight_block_bytes(n_elems_packed_axis: int, n_rest: int,
     return n_elems_packed_axis * n_rest * element_bytes(dtype)
 
 
-def vmem_budget(backend: Optional[str] = None) -> int:
-    """Per-backend VMEM budget in bytes (REPRO_VMEM_BUDGET wins)."""
+def vmem_budget() -> int:
+    """VMEM budget in bytes of the chip the kernels are scheduled for
+    (``core.energy.target_chip``: v5e, also on the CPU where interpret mode
+    validates v5e feasibility; it raises on a TPU of another kind).
+    REPRO_VMEM_BUDGET overrides it (e.g. to model a smaller part, the
+    paper's Cortex-M framing)."""
     env = os.environ.get("REPRO_VMEM_BUDGET")
     if env:
         return int(env)
-    if backend is None:
-        import jax
-        backend = jax.default_backend()
-    return BUDGETS.get(str(backend).split("+")[0], DEFAULT_BUDGET)
+    return target_chip().vmem_bytes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -191,23 +178,28 @@ def kernel_footprint(sig, config: Optional[dict] = None,
         bco = eff["block_co"]
         bn, bh, bw = eff["block_n"], eff["block_h"], eff["block_w"]
         halo = hk - 1
+        rows = bn * bh * bw
         return _fp(
             k,
             img=bn * (bh + halo) * (bw + halo) * ci * eb,
             wts=hk * hk * weight_block_bytes(ci, bco, dtype),
-            out=bn * bh * bw * bco * eb,
-            # |x - w| broadcast: the (BN*BH*BW, Cx, BCO) intermediate is the
-            # VMEM hog the spatial tile exists to bound
-            acc=(bn * bh * bw * ci * bco + bn * bh * bw * bco) * ACC_BYTES,
+            out=rows * bco * eb,
+            # |x - w| broadcast: the (rows, Cx, BCO) intermediate is the VMEM
+            # hog the spatial tile exists to bound. Each (Cx, BCO) slab
+            # occupies whole (8, 128) vreg tiles, and the difference and its
+            # absolute value are live together
+            acc=(2 * rows * round_up(ci, SUBLANE) * round_up(bco, MXU)
+                 + rows * bco) * ACC_BYTES,
         )
 
     if k == "maxpool2d":
         win, s = sig.get("k"), sig.get("s")
         bc = eff["block_c"]
         bn, bh, bw = eff["block_n"], eff["block_h"], eff["block_w"]
+        nph, halo = min(win, s), (win - 1) // s    # stride-phase tiles
         return _fp(
             k,
-            img=bn * ((bh - 1) * s + win) * ((bw - 1) * s + win) * bc * eb,
+            img=bn * nph * nph * (bh + halo) * (bw + halo) * bc * eb,
             out=bn * bh * bw * bc * eb,
         )
 
@@ -241,6 +233,36 @@ def kernel_footprint(sig, config: Optional[dict] = None,
 # Hard feasibility verdict
 # --------------------------------------------------------------------------
 
+def tiling_errors(sig, eff: dict) -> List[str]:
+    """The TPU block-shape rule on an effective schedule: the last two dims
+    of every block are multiples of (8, 128) or span the whole array axis.
+    The kernels' layouts put channels (or matmul N/K) on the 128-lane axis
+    and the output tile width (or matmul M) on the 8-sublane axis, so the
+    rule reduces to one (block, extent, multiple) triple per knob.
+    ``causal_conv1d`` is not covered (off the serving paths)."""
+    from repro.tune.space import _out_hw
+
+    k = sig.kernel
+    rules = []                       # (knob, block, full extent, multiple)
+    if k in ("conv2d", "depthwise2d", "shift_conv2d", "add_conv2d",
+             "maxpool2d"):
+        if k == "conv2d":
+            knob, full = "block_co", sig.get("co") // max(sig.get("g"), 1)
+        elif k in ("shift_conv2d", "add_conv2d"):
+            knob, full = "block_co", sig.get("co")
+        else:
+            knob, full = "block_c", sig.get("c")
+        rules.append((knob, eff[knob], full, MXU))
+        rules.append(("block_w", eff["block_w"], _out_hw(sig)[1], SUBLANE))
+    elif k == "matmul":
+        rules += [("bm", eff["bm"], sig.get("m"), SUBLANE),
+                  ("bn", eff["bn"], sig.get("n"), MXU),
+                  ("bk", eff["bk"], sig.get("k"), MXU)]
+    return [f"{knob}={b} breaks the TPU tiling rule: a multiple of {mult} "
+            f"or the whole extent {full}"
+            for knob, b, full, mult in rules if b != full and b % mult]
+
+
 @dataclasses.dataclass
 class Verdict:
     """Result of one :func:`check_schedule` call."""
@@ -265,12 +287,12 @@ class Verdict:
 
 
 def check_schedule(sig, config: Optional[dict], dtype: str = "int8", *,
-                   budget: Optional[int] = None,
-                   backend: Optional[str] = None) -> Verdict:
+                   budget: Optional[int] = None) -> Verdict:
     """Static feasibility verdict for one (kernel, shape, schedule, dtype).
 
     Errors (``ok=False``): unknown schedule keys, non-positive block values,
-    VMEM footprint over the per-backend budget. Warnings: a requested block
+    a block that breaks the TPU tiling rule (:func:`tiling_errors`), VMEM
+    footprint over the chip's VMEM budget. Warnings: a requested block
     the kernel silently degrades (requested != effective schedule) —
     legal, but the measured entry then describes a different schedule than
     its config dict suggests.
@@ -279,7 +301,7 @@ def check_schedule(sig, config: Optional[dict], dtype: str = "int8", *,
 
     config = dict(config or {})
     dtype = str(dtype)
-    budget = vmem_budget(backend) if budget is None else int(budget)
+    budget = vmem_budget() if budget is None else int(budget)
     errors: List[str] = []
     warnings: List[str] = []
 
@@ -297,6 +319,7 @@ def check_schedule(sig, config: Optional[dict], dtype: str = "int8", *,
     fp: Optional[Footprint] = None
     if not errors:
         eff = effective_config(sig, config)
+        errors.extend(tiling_errors(sig, eff))
         fp = kernel_footprint(sig, eff, dtype)
         if fp.total_bytes > budget:
             errors.append(
@@ -352,9 +375,9 @@ def audit_cache(cache=None, *, budget: Optional[int] = None) -> List[dict]:
     rows = []
     for key in sorted(cache.entries):
         entry = cache.entries[key]
-        sig, dtype, backend = parse_cache_key(key)
+        sig, dtype, _ = parse_cache_key(key)
         v = check_schedule(sig, entry.get("config") or {}, dtype,
-                           budget=budget, backend=backend)
+                           budget=budget)
         # a cached config larger than the shape is deterministic clamping
         # (candidates() dedupes by effective schedule) — informational,
         # not a hazard, so it lands in "notes" rather than "warnings"

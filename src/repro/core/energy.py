@@ -146,7 +146,9 @@ class TPUv5e:
     ici_link_bw: float = 50e9                # B/s per link
     ici_links: int = 4                       # v5e 2D torus: 4 links/chip
     dcn_bw: float = 25e9                     # B/s per host pair (pod axis)
-    vmem_bytes: int = 16 * 2 ** 20           # ~16 MiB more precisely 128 MB? v5e: 128 MiB? kept conservative
+    # 128 MiB of VMEM per chip; Mosaic kernels get the 16 MiB default
+    # scoped limit, which is the budget the footprint model enforces
+    vmem_bytes: int = 16 * 2 ** 20
     hbm_bytes: int = 16 * 2 ** 30
     # order-of-magnitude energy terms (pJ) — used by the energy model only
     pj_per_flop: float = 0.35
@@ -167,3 +169,25 @@ class TPUv5e:
             memory_s=hbm_bytes / self.hbm_bw,
             collective_s=ici_bytes / (self.ici_links * self.ici_link_bw),
         )
+
+
+#: ``device_kind`` strings JAX reports for a TPU v5e chip
+V5E_KINDS = ("TPU v5 lite", "TPU v5e")
+
+
+def target_chip() -> TPUv5e:
+    """Constants of the chip the kernels are scheduled for (analytic peaks,
+    VMEM budget). Off the TPU — the CPU, or Pallas interpret mode — the
+    target is v5e on purpose: interpret mode validates v5e feasibility. On
+    a TPU the attached chip must be a v5e; any other kind raises instead of
+    being scheduled with v5e numbers."""
+    import jax
+    from repro.kernels.common import use_interpret
+    if jax.default_backend() != "tpu" or use_interpret():
+        return TPUv5e()
+    kind = jax.devices()[0].device_kind
+    if kind not in V5E_KINDS:
+        raise RuntimeError(
+            f"no hardware constants for TPU device_kind {kind!r}: the tuner's "
+            f"peaks and the VMEM budget describe only {V5E_KINDS}")
+    return TPUv5e()

@@ -150,6 +150,24 @@ def depthwise_conv(x, w_dw, *, stride=1, padding="SAME", preferred=None):
     )
 
 
+def int8_conv(x, w, *, stride=1, padding="SAME", groups=1):
+    """Exact int8 x int8 -> int32 convolution (operands cast to int8 codes),
+    the same on every backend. A grouped convolution runs as one dense
+    convolution per group: on a TPU v5e, XLA miscomputes integer
+    convolutions with ``feature_group_count > 1`` (int8 operands) and
+    rounds int32-operand ones once accumulators grow."""
+    x, w = x.astype(jnp.int8), w.astype(jnp.int8)
+    if groups == 1:
+        return standard_conv(x, w, stride=stride, padding=padding,
+                             preferred=jnp.int32)
+    cxg, cog = x.shape[-1] // groups, w.shape[-1] // groups
+    return jnp.concatenate(
+        [standard_conv(x[..., g * cxg:(g + 1) * cxg],
+                       w[..., g * cog:(g + 1) * cog], stride=stride,
+                       padding=padding, preferred=jnp.int32)
+         for g in range(groups)], axis=-1)
+
+
 def shift_channels(x, shifts, *, max_shift: Optional[int] = None):
     """Per-channel spatial shift (Eq. 2): I[k,l,m] = X[k+a_m, l+b_m, m].
 

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .primitives import ConvSpec, shift_channels, _DN
+from .primitives import ConvSpec, int8_conv, shift_channels, _DN
 from .quantize import (QTensor, QTensorW4, addmac_align, quantize_w4,
                        requantize, rshift_round)
 
@@ -37,10 +37,7 @@ from .quantize import (QTensor, QTensorW4, addmac_align, quantize_w4,
 def _conv_int(x_q: jax.Array, w_q: jax.Array, *, stride=1, padding="SAME",
               groups=1) -> jax.Array:
     """int8 x int8 -> int32 convolution (the MXU-native contraction)."""
-    return lax.conv_general_dilated(
-        x_q.astype(jnp.int32), w_q.astype(jnp.int32), (stride, stride), padding,
-        dimension_numbers=_DN, feature_group_count=groups,
-    )
+    return int8_conv(x_q, w_q, stride=stride, padding=padding, groups=groups)
 
 
 def _bias_acc(bias: Optional[QTensor], acc_fb: int) -> Optional[jax.Array]:

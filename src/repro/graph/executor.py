@@ -110,8 +110,8 @@ class CompiledPlan:
         the compiled pallas artifact. The xla oracles are bit-exact with
         the pallas kernels (tests/test_kernels.py), so already-served
         results stay comparable — only throughput degrades. Called by the
-        serving layer after repeated round failures; idempotent, logged
-        once, counted as ``graph.degraded`` in the process metrics."""
+        serving layer after repeated injected round failures; idempotent,
+        logged once, counted as ``graph.degraded`` in the process metrics."""
         if self.degraded:
             return
         self.degraded = True
@@ -122,7 +122,7 @@ class CompiledPlan:
         import warnings
         warnings.warn(
             "CompiledPlan degraded to the xla reference path after repeated "
-            "kernel failure — serving continues bit-exact but slower",
+            "injected failure — serving continues bit-exact but slower",
             RuntimeWarning, stacklevel=2)
 
     # ------------------------------------------------------------- dispatch

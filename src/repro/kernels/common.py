@@ -12,7 +12,8 @@ import os
 import jax
 import jax.numpy as jnp
 
-# MXU native tile: 128x128 systolic; VPU lanes (8, 128).
+# MXU native tile: 128x128 systolic; VPU lanes (8, 128). A block's last two
+# dims must be multiples of (SUBLANE, MXU) or span the whole array axis.
 MXU = 128
 SUBLANE = 8
 
@@ -67,6 +68,18 @@ def pick_block(dim: int, preferred: int) -> int:
 
 def acc_dtype(dtype) -> jnp.dtype:
     return jnp.int32 if jnp.issubdtype(dtype, jnp.integer) else jnp.float32
+
+
+def mxu_dot(a: jax.Array, b: jax.Array, adt) -> jax.Array:
+    """One MXU contraction accumulating at ``adt``. Integer operands enter
+    the MXU as int8 — the v5e MXU has no int32 matmul, and int8 x int8 ->
+    int32 is exact (W4 codes after ``shift_w4_block`` fit int8 by the
+    quantizer's shift clamp). Float operands are widened to ``adt``."""
+    if jnp.issubdtype(adt, jnp.integer):
+        a, b = a.astype(jnp.int8), b.astype(jnp.int8)
+    else:
+        a, b = a.astype(adt), b.astype(adt)
+    return jnp.dot(a, b, preferred_element_type=adt)
 
 
 def apply_requant(acc: jax.Array, requant_shift: int | None) -> jax.Array:
@@ -158,6 +171,29 @@ def halo_tiles(x: jax.Array, n_th: int, n_tw: int, step_h: int, step_w: int,
                       for j in range(n_tw)], axis=2)
 
 
+def w4_nibble(wp: jax.Array, hi: int) -> jax.Array:
+    """The sign-extended int32 codes in the low (``hi=0``, bits 0-3) or
+    high (``hi=1``, bits 4-7) nibble of every byte of a W4-packed block."""
+    return jnp.right_shift(jnp.left_shift(wp.astype(jnp.int32),
+                                          24 if hi else 28), 28)
+
+
+def w4_plane_order(size: int):
+    """Channel order of :func:`w4_planes`: even indices, then odd ones."""
+    import numpy as np
+    return np.concatenate([np.arange(0, size, 2), np.arange(1, size, 2)])
+
+
+def w4_planes(wp: jax.Array, size: int) -> jax.Array:
+    """Unpack a W4 block packed along axis 0 into int32 codes in
+    :func:`w4_plane_order` — the low nibbles (even codes), then the high
+    ones (odd codes) — by one sublane concatenation. Cheaper to compile
+    than :func:`unpack_w4_block`'s interleave; for a reduction whose other
+    operand the wrapper permutes into the same order."""
+    return jnp.concatenate([w4_nibble(wp, 0), w4_nibble(wp, 1)[:size // 2]],
+                           axis=0)
+
+
 def unpack_w4_block(wp: jax.Array, size: int, axis: int = 0) -> jax.Array:
     """In-register nibble unpack for a W4-packed weight block: int8 bytes
     holding two two's-complement int4 codes -> int32 codes, ``shape[axis]``
@@ -168,10 +204,7 @@ def unpack_w4_block(wp: jax.Array, size: int, axis: int = 0) -> jax.Array:
     ``core.quantize.unpack_w4`` bit-for-bit. Zero bytes unpack to zero
     codes, so Pallas' zero-padded ragged blocks stay neutral."""
     axis = axis % wp.ndim
-    pi = wp.astype(jnp.int32)
-    lo = jnp.right_shift(jnp.left_shift(pi, 28), 28)    # sign-extend bits 0-3
-    hi = jnp.right_shift(jnp.left_shift(pi, 24), 28)    # sign-extend bits 4-7
-    out = jnp.stack([lo, hi], axis=axis + 1)
+    out = jnp.stack([w4_nibble(wp, 0), w4_nibble(wp, 1)], axis=axis + 1)
     shape = list(wp.shape)
     shape[axis] = shape[axis] * 2
     out = out.reshape(shape)
@@ -187,6 +220,23 @@ def shift_w4_block(w4: jax.Array, ws: jax.Array, axis: int = 0) -> jax.Array:
     bshape = [1] * w4.ndim
     bshape[axis % w4.ndim] = ws.shape[-1]
     return jnp.left_shift(w4, ws.astype(jnp.int32).reshape(bshape))
+
+
+def contraction_block(k: int, bk: int) -> int:
+    """The K block the matmul actually runs: all of K when ``bk >= k``,
+    else the largest lane-aligned (multiple of ``MXU``) divisor of ``k``
+    that is <= ``bk``, else all of K. K blocks must tile K exactly: a
+    ragged last K block would feed the accumulator whatever lies past the
+    array's end (interpret mode pads with int-min, the chip with garbage).
+    Shared by the kernel wrapper and the tuner's effective schedule."""
+    if bk >= k:
+        return k
+    b = (bk // MXU) * MXU
+    while b >= MXU:
+        if k % b == 0:
+            return b
+        b -= MXU
+    return k
 
 
 def effective_block(dim: int, block: int) -> int:

@@ -24,21 +24,24 @@ from jax.experimental import pallas as pl
 
 from .common import (acc_dtype, apply_act, apply_requant,
                      batch_spatial_schedule, effective_block, halo_tiles,
-                     resolve_interpret, resolve_tile_config, shift_w4_block,
-                     unpack_w4_block)
+                     resolve_interpret, resolve_tile_config, w4_plane_order,
+                     w4_planes)
 
 
 def _kernel(x_ref, w_ref, o_ref, *, hk, bh, bw, out_dtype, requant_shift,
             x_preshift, w_preshift, act=None, bias_ref=None, ws_ref=None):
     # x_ref: (BN, 1, 1, BH+HK-1, BW+HK-1, Cx); w_ref: (HK, HK, Cx, BCO)
-    # (W4: (HK, HK, ceil(Cx/2), BCO) nibble-packed + ws_ref (Cx,) shifts.
-    # The unpack slices the packed tail element off before |x - w| — a
-    # zero-padded weight channel is NOT neutral for L1 distance.)
+    # (W4: (HK, HK, ceil(Cx/2), BCO) nibble-packed + ws_ref (Cx,) shifts;
+    # x and the shifts arrive in w4_plane_order. The unpack drops the packed
+    # tail element before |x - w| — a zero-padded weight channel is NOT
+    # neutral for L1 distance.)
     adt = acc_dtype(x_ref.dtype)
     cx = x_ref.shape[-1]
     bco = w_ref.shape[-1]
     bn = x_ref.shape[0]
     acc = jnp.zeros((bn * bh * bw, bco), adt)
+    if ws_ref is not None:
+        ws = ws_ref[...].astype(jnp.int32).reshape(cx, 1)
     for i in range(hk):
         for j in range(hk):
             patch = x_ref[:, 0, 0, i:i + bh, j:j + bw, :].astype(adt)
@@ -47,9 +50,8 @@ def _kernel(x_ref, w_ref, o_ref, *, hk, bh, bw, out_dtype, requant_shift,
             if ws_ref is None:
                 wv = w_ref[i, j].astype(adt)    # (Cx, BCO)
             else:                           # group shifts first (to the base
-                wv = shift_w4_block(        # scale), then the common align
-                    unpack_w4_block(w_ref[i, j], cx, 0),
-                    ws_ref[...], 0).astype(adt)
+                wv = jnp.left_shift(        # scale), then the common align
+                    w4_planes(w_ref[i, j], cx), ws).astype(adt)
             if w_preshift:
                 wv = jnp.left_shift(wv, w_preshift)
             a = patch.reshape(bn * bh * bw, cx)
@@ -62,7 +64,7 @@ def _kernel(x_ref, w_ref, o_ref, *, hk, bh, bw, out_dtype, requant_shift,
     o_ref[...] = acc.reshape(bn, bh, bw, bco).astype(out_dtype)
 
 
-def add_conv2d(x: jax.Array, w: jax.Array, bias=None, *, block_co: int = 8,
+def add_conv2d(x: jax.Array, w: jax.Array, bias=None, *, block_co: int = 128,
                block_n: int = 1, block_h: int | None = None,
                block_w: int | None = None,
                requant_shift: int | None = None, x_preshift: int = 0,
@@ -101,7 +103,7 @@ def add_conv2d(x: jax.Array, w: jax.Array, bias=None, *, block_co: int = 8,
                                              "x_preshift", "w_preshift",
                                              "act", "out_dtype", "interpret"))
 def _add_conv2d(x: jax.Array, w: jax.Array, bias=None, w_shifts=None, *,
-                block_co: int = 8,
+                block_co: int = 128,
                 block_n: int = 1, block_h: int | None = None,
                 block_w: int | None = None,
                 requant_shift: int | None = None, x_preshift: int = 0,
@@ -117,6 +119,9 @@ def _add_conv2d(x: jax.Array, w: jax.Array, bias=None, w_shifts=None, *,
         assert w.shape[2] == (cx + 1) // 2, \
             f"packed Cx extent {w.shape[2]} != ceil({cx}/2)"
     out_dtype = out_dtype or (jnp.int8 if requant_shift is not None else x.dtype)
+    if w4:                  # the |x - w| sum runs in the unpacked planes'
+        order = w4_plane_order(cx)  # channel order (an exact reordering)
+        x, w_shifts = x[..., order], w_shifts[order]
     ph, pw = hk // 2, (hk - 1) // 2
     xp = jnp.pad(x, ((0, 0), (ph, pw), (ph, pw), (0, 0)))
     bco = effective_block(cy, block_co)

@@ -22,27 +22,27 @@ from jax.experimental import pallas as pl
 
 from .common import (acc_dtype, apply_act, apply_requant,
                      batch_spatial_schedule, effective_block, halo_tiles,
-                     resolve_interpret, resolve_tile_config, shift_w4_block,
-                     unpack_w4_block)
+                     resolve_interpret, resolve_tile_config, w4_nibble)
 
 
 def _kernel(x_ref, w_ref, o_ref, *, hk, bh, bw, out_dtype, requant_shift,
             act=None, ws_ref=None):
     # x_ref: (BN, 1, 1, BH+HK-1, BW+HK-1, BC); w_ref: (HK, HK, BC)
     # (W4: (ceil(HK/2), HK, BC) nibble-packed along the tap-row axis —
-    # channels stay the blocked 128-lane axis — + ws_ref (HK,) shifts)
+    # channels stay the blocked 128-lane axis — + ws_ref (HK, BC) shifts)
     adt = acc_dtype(x_ref.dtype)
     bc = w_ref.shape[-1]
     bn = x_ref.shape[0]
-    if ws_ref is None:
-        wv = w_ref[...]
-    else:
-        wv = shift_w4_block(unpack_w4_block(w_ref[...], hk, 0), ws_ref[...], 0)
     acc = jnp.zeros((bn, bh, bw, bc), adt)
     for i in range(hk):
+        if ws_ref is None:
+            wrow = w_ref[i]                  # (HK, BC)
+        else:                                # tap row i: nibble i % 2 of
+            wrow = jnp.left_shift(           # packed row i // 2, 2-D only
+                w4_nibble(w_ref[i // 2], i % 2), ws_ref[i:i + 1, :])
         for j in range(hk):
             acc = acc + (x_ref[:, 0, 0, i:i + bh, j:j + bw, :].astype(adt)
-                         * wv[i, j].astype(adt)[None, None, None, :])
+                         * wrow[j].astype(adt)[None, None, None, :])
     acc = apply_act(acc, act)
     acc = apply_requant(acc, requant_shift)
     o_ref[...] = acc.astype(out_dtype)
@@ -124,8 +124,11 @@ def _depthwise2d(x: jax.Array, w_dw: jax.Array, w_shifts=None, *,
     ]
     args = [tiles, w_dw]
     if w4:
-        in_specs.append(pl.BlockSpec((hk,), lambda b, s, cb: (0,)))
-        args.append(w_shifts)
+        # per-tap-row shifts spread over the channel lanes: the VPU can
+        # broadcast a (1, BC) row across sublanes, not a (1, 1) scalar
+        in_specs.append(pl.BlockSpec((hk, bc), lambda b, s, cb: (0, cb)))
+        args.append(jnp.broadcast_to(w_shifts.astype(jnp.int32)[:, None],
+                                     (hk, c)))
 
     def kern(*refs):
         it = iter(refs)

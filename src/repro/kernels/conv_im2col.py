@@ -31,20 +31,23 @@ from jax.experimental import pallas as pl
 
 from .common import (acc_dtype, apply_act, apply_requant,
                      batch_spatial_schedule, effective_block, halo_tiles,
-                     resolve_interpret, resolve_tile_config, shift_w4_block,
-                     unpack_w4_block)
+                     mxu_dot, resolve_interpret, resolve_tile_config,
+                     w4_plane_order, w4_planes)
 
 
 def _kernel(x_ref, w_ref, o_ref, *, hk: int, bh: int, bw: int,
             out_dtype, requant_shift: int | None, act: str | None = None,
             bias_ref=None, ws_ref=None):
     # x_ref: (BN, 1, 1, BH+HK-1, BW+HK-1, Cx); w_ref: (HK, HK, Cx, BCO)
-    # (W4: (HK, HK, ceil(Cx/2), BCO) nibble-packed + ws_ref (Cx,) shifts)
+    # (W4: (HK, HK, ceil(Cx/2), BCO) nibble-packed + ws_ref (Cx,) shifts;
+    # x and the shifts arrive in w4_plane_order)
     cx = x_ref.shape[-1]
     bco = w_ref.shape[-1]
     bn = x_ref.shape[0]
     adt = acc_dtype(x_ref.dtype)
     acc = jnp.zeros((bn * bh * bw, bco), adt)
+    if ws_ref is not None:
+        ws = ws_ref[...].astype(jnp.int32).reshape(cx, 1)
     for i in range(hk):                      # static unroll: HK^2 MXU calls
         for j in range(hk):
             patch = x_ref[:, 0, 0, i:i + bh, j:j + bw, :]
@@ -52,12 +55,11 @@ def _kernel(x_ref, w_ref, o_ref, *, hk: int, bh: int, bw: int,
             if ws_ref is None:
                 b = w_ref[i, j]
             else:                            # unpack W4 in-register, then the
-                b = shift_w4_block(          # unchanged int8 MXU body
-                    unpack_w4_block(w_ref[i, j], cx, 0), ws_ref[...], 0)
-            acc = acc + jnp.dot(a.astype(adt), b.astype(adt),
-                                preferred_element_type=adt)
-    if bias_ref is not None:
-        acc = acc + bias_ref[...].astype(adt)[None, :]
+                b = jnp.left_shift(          # unchanged int8 MXU body
+                    w4_planes(w_ref[i, j], cx), ws)
+            acc = acc + mxu_dot(a, b, adt)
+    if bias_ref is not None:                 # (1, BCO) block
+        acc = acc + bias_ref[...].astype(adt)
     # fused activation at accumulator scale, then Algorithm 1: round-to-
     # nearest shift, clip, int8
     acc = apply_act(acc, act)
@@ -132,36 +134,48 @@ def _conv2d_im2col(x: jax.Array, w: jax.Array, bias=None, w_shifts=None, *,
     bn, bh, bw, n_th, n_tw = batch_spatial_schedule(n, h, wd, block_n,
                                                     block_h, block_w)
     halo = hk - 1
-    tiles = halo_tiles(xp, n_th, n_tw, bh, bw, bh + halo, bw + halo)
     hp_out, wp_out = n_th * bh, n_tw * bw
+    # group-major layout: activations, weights, bias and output carry the
+    # group as a LEADING axis, so every block's last two dims are the
+    # group's whole channel extent (or a lane-aligned channel block) — the
+    # TPU tiling rule holds for any group count. groups=1 is a free reshape.
+    cxw = w.shape[2]
+    xg = jnp.moveaxis(xp.reshape(xp.shape[:3] + (groups, cxg)), 3, 0)
+    if w4:                  # the contraction runs in the unpacked planes'
+        order = w4_plane_order(cxg)  # channel order (an exact reordering)
+        xg, w_shifts = xg[..., order], w_shifts[order]
+    tiles = halo_tiles(xg.reshape((groups * n,) + xg.shape[2:]), n_th, n_tw,
+                       bh, bw, bh + halo, bw + halo)
+    tiles = tiles.reshape((groups, n) + tiles.shape[1:])
+    wg = jnp.moveaxis(w.reshape(hk, hk, cxw, groups, co_per_g), 3, 0)
 
     # index maps close over the RESOLVED schedule (n_co/n_tw computed from
     # the effective blocks above) — no default-arg captures, so a config
     # that rounds through effective_block can never leave a stale divisor
     # in the lambdas
     def x_index(b, s, g, c):
-        return (b, s // n_tw, s % n_tw, 0, 0, g)
+        return (g, b, s // n_tw, s % n_tw, 0, 0, 0)
 
     def w_index(b, s, g, c):
-        return (0, 0, 0, g * n_co + c)
+        return (g, 0, 0, 0, c)
 
     def co_index(b, s, g, c):
-        return (g * n_co + c,)
+        return (g, 0, c)
 
     def o_index(b, s, g, c):
-        return (b, s // n_tw, s % n_tw, g * n_co + c)
+        return (g, b, s // n_tw, s % n_tw, c)
 
     in_specs = [
-        pl.BlockSpec((bn, 1, 1, bh + halo, bw + halo, cxg), x_index),
-        pl.BlockSpec((hk, hk, (cxg + 1) // 2 if w4 else cxg, bco), w_index),
+        pl.BlockSpec((None, bn, 1, 1, bh + halo, bw + halo, cxg), x_index),
+        pl.BlockSpec((None, hk, hk, cxw, bco), w_index),
     ]
-    args = [tiles, w]
+    args = [tiles, wg]
     if w4:                  # shifts ride whole (the packed axis is unblocked)
         in_specs.append(pl.BlockSpec((cxg,), lambda b, s, g, c: (0,)))
         args.append(w_shifts)
     if bias is not None:
-        in_specs.append(pl.BlockSpec((bco,), co_index))
-        args.append(bias)
+        in_specs.append(pl.BlockSpec((None, 1, bco), co_index))
+        args.append(bias.reshape(groups, 1, co_per_g))
 
     def kern(*refs):
         it = iter(refs)
@@ -176,8 +190,10 @@ def _conv2d_im2col(x: jax.Array, w: jax.Array, bias=None, w_shifts=None, *,
         kern,
         grid=(n // bn, n_th * n_tw, groups, n_co),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bn, bh, bw, bco), o_index),
-        out_shape=jax.ShapeDtypeStruct((n, hp_out, wp_out, cy), out_dtype),
+        out_specs=pl.BlockSpec((None, bn, bh, bw, bco), o_index),
+        out_shape=jax.ShapeDtypeStruct((groups, n, hp_out, wp_out, co_per_g),
+                                       out_dtype),
         interpret=interpret,
     )(*args)
+    out = jnp.moveaxis(out, 0, 3).reshape(n, hp_out, wp_out, cy)
     return out[:, :h, :wd, :]

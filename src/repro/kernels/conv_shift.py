@@ -21,8 +21,8 @@ from jax.experimental import pallas as pl
 
 from .common import (acc_dtype, apply_act, apply_requant,
                      batch_spatial_schedule, effective_block, halo_tiles,
-                     resolve_interpret, resolve_tile_config, shift_w4_block,
-                     unpack_w4_block)
+                     mxu_dot, resolve_interpret, resolve_tile_config,
+                     shift_w4_block, unpack_w4_block)
 
 
 def _kernel(x_ref, w_ref, o_ref, *, groups, bh, bw, pad, out_dtype,
@@ -42,9 +42,8 @@ def _kernel(x_ref, w_ref, o_ref, *, groups, bh, bw, pad, out_dtype,
     for start, size, (da, db) in groups:     # static unroll over shift groups
         r0, c0 = pad + da, pad + db
         patch = x_ref[:, 0, 0, r0:r0 + bh, c0:c0 + bw, start:start + size]
-        acc = acc + jnp.dot(patch.reshape(bn * bh * bw, size).astype(adt),
-                            wv[start:start + size, :].astype(adt),
-                            preferred_element_type=adt)
+        acc = acc + mxu_dot(patch.reshape(bn * bh * bw, size),
+                            wv[start:start + size, :], adt)
     if bias_ref is not None:                 # bias at accumulator scale
         acc = acc + bias_ref[...].astype(adt)[None, :]
     acc = apply_act(acc, act)

@@ -18,19 +18,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .common import (acc_dtype, apply_act, apply_requant, cdiv,
-                     resolve_interpret, shift_w4_block, unpack_w4_block)
-
-
-def _make_compiler_params(n_parallel: int):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        sem = ("parallel",) * n_parallel + ("arbitrary",)
-        try:
-            return pltpu.CompilerParams(dimension_semantics=sem)
-        except AttributeError:      # older naming
-            return pltpu.TPUCompilerParams(dimension_semantics=sem)
-    except Exception:               # pragma: no cover - CPU-only envs
-        return None
+                     contraction_block, mxu_dot, resolve_interpret,
+                     shift_w4_block, unpack_w4_block)
 
 
 def _kernel(a_ref, b_ref, o_ref, acc_ref, *, nk, out_dtype, requant_shift,
@@ -39,17 +28,14 @@ def _kernel(a_ref, b_ref, o_ref, acc_ref, *, nk, out_dtype, requant_shift,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    adt = acc_ref.dtype
     if ws_ref is None:
-        bv = b_ref[...].astype(adt)
+        bv = b_ref[...]
     else:
-        # W4: b block is (BK/2, BN) nibble-packed along K; padded tail bytes
-        # unpack to zero codes, matching a's zero-padded ragged block
+        # W4: b block is (ceil(BK/2), BN) nibble-packed along K; an odd
+        # whole-K block's tail nibble is a zero code, sliced off here
         bv = shift_w4_block(
-            unpack_w4_block(b_ref[...], 2 * b_ref.shape[0], 0),
-            ws_ref[...], 0).astype(adt)
-    acc_ref[...] += jnp.dot(a_ref[...].astype(adt), bv,
-                            preferred_element_type=adt)
+            unpack_w4_block(b_ref[...], a_ref.shape[1], 0), ws_ref[...], 0)
+    acc_ref[...] += mxu_dot(a_ref[...], bv, acc_ref.dtype)
 
     @pl.when(pl.program_id(2) == nk - 1)
     def _epilogue():
@@ -120,18 +106,23 @@ def _matmul(a: jax.Array, b: jax.Array, w_shifts=None, *, bm: int = 256,
         assert k == k2
     out_dtype = out_dtype or (jnp.int8 if requant_shift is not None else a.dtype)
     adt = acc_dtype(a.dtype)
-    bm_, bn_, bk_ = min(bm, m), min(bn, n), min(bk, k)
-    if w4 and bk_ % 2:          # packed K blocks must not straddle a byte
-        bk_ += 1
-    grid = (cdiv(m, bm_), cdiv(n, bn_), cdiv(k, bk_))
+    # ragged M/N blocks only write rows/cols the output crops; K blocks
+    # tile K exactly (a partial K block would pollute the accumulator).
+    # Lane-aligned K blocks are even, so packed W4 blocks never straddle a
+    # byte; an odd K runs as one whole block with a zero tail nibble.
+    bm_, bn_ = min(bm, m), min(bn, n)
+    bk_ = contraction_block(k, bk)
+    grid = (cdiv(m, bm_), cdiv(n, bn_), k // bk_)
     in_specs = [
         pl.BlockSpec((bm_, bk_), lambda i, j, kk: (i, kk)),
-        pl.BlockSpec((bk_ // 2 if w4 else bk_, bn_), lambda i, j, kk: (kk, j)),
+        pl.BlockSpec(((bk_ + 1) // 2 if w4 else bk_, bn_),
+                     lambda i, j, kk: (kk, j)),
     ]
     args = [a, b]
     if w4:
-        in_specs.append(pl.BlockSpec((bk_,), lambda i, j, kk: (kk,)))
-        args.append(w_shifts)
+        # (1, K): a rank-1 int8 block must span the array or 512 lanes
+        in_specs.append(pl.BlockSpec((1, bk_), lambda i, j, kk: (0, kk)))
+        args.append(w_shifts.reshape(1, k))
 
     def kern(*refs):
         it = iter(refs)

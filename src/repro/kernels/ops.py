@@ -27,9 +27,9 @@ jit count once per trace, eager calls once per call.
 Failure model (EXPERIMENTS.md §Resilience): the ``kernels.dispatch`` fault
 seam (``repro.faults``) fires once per pallas dispatch (per trace from
 inside a jit). An injected raise is retried a bounded number of times;
-repeated failure degrades THAT kernel to its jnp oracle for the rest of
-the process — sticky, one warning, counted as
-``kernels.degraded.<kernel>`` — which is semantics-preserving because the
+repeated injected failure degrades THAT kernel to its jnp oracle for the
+rest of the process (a real kernel or compile error propagates instead) —
+sticky, one warning, counted as ``kernels.degraded.<kernel>`` — which is semantics-preserving because the
 oracles are bit-exact with the pallas kernels (tests/test_kernels.py).
 ``reset_degraded()`` clears the sticky state (tests); the dispatch
 counters keep recording the *requested* method, so degraded traffic is
@@ -96,10 +96,12 @@ def _is_degraded(kernel: str) -> bool:
 
 def _pallas_guard(kernel: str, pallas_fn, xla_fn):
     """Run ``pallas_fn`` behind the ``kernels.dispatch`` fault seam with
-    bounded retries; repeated failure (injected or real) degrades
-    ``kernel`` to ``xla_fn`` — once, stickily, with one warning. The
-    schedule lookup / explicit-config feasibility check stay OUTSIDE this
-    guard: a CheckError is a caller bug, not a transient kernel fault."""
+    bounded retries; repeated INJECTED failure degrades ``kernel`` to
+    ``xla_fn`` — once, stickily, with one warning. A real compile or kernel
+    error propagates: degrading it would serve a broken kernel's traffic
+    from the oracle and hide the fault. The schedule lookup /
+    explicit-config feasibility check stay OUTSIDE this guard: a
+    CheckError is a caller bug, not a transient kernel fault."""
     last: Optional[BaseException] = None
     for _ in range(_MAX_DISPATCH_RETRIES + 1):
         try:
@@ -107,9 +109,6 @@ def _pallas_guard(kernel: str, pallas_fn, xla_fn):
             return pallas_fn()
         except _faults.InjectedFault as e:
             last = e                    # transient by construction: retry
-        except Exception as e:
-            last = e                    # deterministic failure: degrading
-            break                       # now beats re-failing twice more
     _DEGRADED[kernel] = repr(last)
     _obs_metrics.counter(f"kernels.degraded.{kernel}").inc()
     warnings.warn(

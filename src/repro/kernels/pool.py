@@ -8,9 +8,12 @@ keep activations int8 across pool boundaries (zero float round-trips).
 
 Grid: (batch_block, spatial_tile, channel-block); one grid step reduces a
 ``block_n``-image, halo-padded (``block_h``, ``block_w``) OUTPUT tile as
-W^2 statically-strided element-wise maxima on the 8x128 VPU — the same
-shifted accumulation pattern as conv_dw, with max replacing multiply-add
-(the input tile covers ``(block-1)*stride + window`` rows/cols).
+W^2 element-wise maxima on the 8x128 VPU — the same shifted accumulation
+pattern as conv_dw, with max replacing multiply-add. The TPU has no strided
+vector loads for int8, so the wrapper splits the map into its stride
+phases (``x[:, pi::s, pj::s]``, one XLA strided slice each); window tap
+``(i, j)`` is then a unit-stride slice of phase ``(i % s, j % s)`` at
+offset ``(i // s, j // s)``.
 """
 from __future__ import annotations
 
@@ -18,25 +21,25 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
 
-from .common import (batch_spatial_schedule, effective_block, halo_tiles,
-                     resolve_interpret, resolve_tile_config)
+from .common import (acc_dtype, batch_spatial_schedule, effective_block,
+                     halo_tiles, resolve_interpret, resolve_tile_config)
 
 
 def _kernel(x_ref, o_ref, *, win, stride, bh, bw):
-    xv = x_ref[:, 0, 0]                      # (BN, TH_in, TW_in, BC)
-    bn, bc = xv.shape[0], xv.shape[-1]
+    # x_ref: (BN, P, 1, 1, BH + (win-1)//s, BW + (win-1)//s, BC), P phases
+    nph = min(win, stride)
+    # the v5e VPU has no 8-bit max: compare int8 codes as int32 (exact)
+    acc = acc_dtype(x_ref.dtype)
     out = None
     for i in range(win):                     # static unroll over window taps
         for j in range(win):
-            v = lax.slice(xv, (0, i, j, 0),
-                          (bn, i + (bh - 1) * stride + 1,
-                           j + (bw - 1) * stride + 1, bc),
-                          (1, stride, stride, 1))
+            r0, c0 = i // stride, j // stride
+            v = x_ref[:, (i % stride) * nph + j % stride, 0, 0,
+                      r0:r0 + bh, c0:c0 + bw, :].astype(acc)
             out = v if out is None else jnp.maximum(out, v)
-    o_ref[...] = out
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
 def maxpool2d(x: jax.Array, *, window: int = 2, stride: int | None = None,
@@ -72,14 +75,24 @@ def _maxpool2d(x: jax.Array, *, window: int, stride: int, block_c: int,
     bc = effective_block(c, block_c)
     bn, bh, bw, n_th, n_tw = batch_spatial_schedule(n, hout, wout, block_n,
                                                     block_h, block_w)
-    # output tile (bh, bw) consumes input rows [th*bh*s, th*bh*s +
-    # (bh-1)*s + win): overlapping tiles at stride bh*s (pad rows only feed
-    # output rows the final crop discards)
-    tiles = halo_tiles(x, n_th, n_tw, bh * stride, bw * stride,
-                       (bh - 1) * stride + window, (bw - 1) * stride + window)
+    # stride phases: output row r, tap i reads input row s*(r + i//s) + i%s,
+    # i.e. row r + i//s of phase i%s; each phase keeps the rows the valid
+    # outputs need (pad rows only feed output rows the final crop discards)
+    nph, halo = min(window, stride), (window - 1) // stride
+    need_h, need_w = hout + halo, wout + halo
+    phases = []
+    for pi in range(nph):
+        for pj in range(nph):
+            ph = x[:, pi::stride, pj::stride, :][:, :need_h, :need_w, :]
+            phases.append(jnp.pad(ph, ((0, 0), (0, need_h - ph.shape[1]),
+                                       (0, need_w - ph.shape[2]), (0, 0))))
+    xph = jnp.stack(phases, axis=1).reshape(n * nph * nph, need_h, need_w, c)
+    # output tile (bh, bw) consumes phase rows [th*bh, th*bh + bh + halo)
+    tiles = halo_tiles(xph, n_th, n_tw, bh, bw, bh + halo, bw + halo)
+    tiles = tiles.reshape((n, nph * nph) + tiles.shape[1:])
 
     def x_index(b, s, cb):
-        return (b, s // n_tw, s % n_tw, 0, 0, cb)
+        return (b, 0, s // n_tw, s % n_tw, 0, 0, cb)
 
     def o_index(b, s, cb):
         return (b, s // n_tw, s % n_tw, cb)
@@ -88,8 +101,8 @@ def _maxpool2d(x: jax.Array, *, window: int, stride: int, block_c: int,
     out = pl.pallas_call(
         kern,
         grid=(n // bn, n_th * n_tw, c // bc),
-        in_specs=[pl.BlockSpec((bn, 1, 1, (bh - 1) * stride + window,
-                                (bw - 1) * stride + window, bc), x_index)],
+        in_specs=[pl.BlockSpec((bn, nph * nph, 1, 1, bh + halo, bw + halo,
+                                bc), x_index)],
         out_specs=pl.BlockSpec((bn, bh, bw, bc), o_index),
         out_shape=jax.ShapeDtypeStruct((n, n_th * bh, n_tw * bw, c), x.dtype),
         interpret=interpret,

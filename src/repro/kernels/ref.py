@@ -32,8 +32,7 @@ def conv2d_ref(x, w, bias=None, *, groups: int = 1, act=None):
 
 def conv2d_q8_ref(x_q, w_q, bias_q=None, *, groups: int = 1,
                   requant_shift: int = 0, act=None):
-    acc = P.standard_conv(x_q.astype(jnp.int32), w_q.astype(jnp.int32),
-                          groups=groups)
+    acc = P.int8_conv(x_q, w_q, groups=groups)
     if bias_q is not None:
         acc = acc + bias_q.astype(jnp.int32)
     acc = apply_act(acc, act)
@@ -46,8 +45,16 @@ def depthwise2d_ref(x, w_dw, *, act=None):
 
 
 def depthwise2d_q8_ref(x_q, w_dw_q, *, requant_shift: int = 0, act=None):
-    w4 = w_dw_q[..., None] if w_dw_q.ndim == 3 else w_dw_q
-    acc = P.depthwise_conv(x_q.astype(jnp.int32), w4.astype(jnp.int32))
+    # per-tap int32 multiply-accumulate: exact on every backend (XLA:TPU
+    # miscomputes grouped integer convolutions, see P.int8_conv)
+    w = w_dw_q[..., 0] if w_dw_q.ndim == 4 else w_dw_q
+    hk = w.shape[0]
+    h, wd = x_q.shape[1:3]
+    lo = (hk - 1) // 2                      # XLA's SAME split of hk - 1
+    xp = jnp.pad(x_q.astype(jnp.int32),
+                 ((0, 0), (lo, hk - 1 - lo), (lo, hk - 1 - lo), (0, 0)))
+    acc = sum(xp[:, i:i + h, j:j + wd, :] * w[i, j].astype(jnp.int32)
+              for i in range(hk) for j in range(hk))
     acc = apply_act(acc, act)
     return apply_requant(acc, requant_shift).astype(jnp.int8)
 
@@ -63,9 +70,8 @@ def shift_conv2d_q8_ref(x_q, shifts, w_pw_q, bias_q=None, *,
     """Shift is pure data movement — exact in the integer domain (the paper's
     point) — so only the pointwise matmul accumulates."""
     w4 = w_pw_q[None, None] if w_pw_q.ndim == 2 else w_pw_q
-    shifted = P.shift_channels(x_q.astype(jnp.int32), jnp.asarray(shifts),
-                               max_shift=max_shift)
-    acc = P.standard_conv(shifted, w4.astype(jnp.int32))
+    shifted = P.shift_channels(x_q, jnp.asarray(shifts), max_shift=max_shift)
+    acc = P.int8_conv(shifted, w4)
     if bias_q is not None:
         acc = acc + bias_q.astype(jnp.int32)
     acc = apply_act(acc, act)
@@ -163,7 +169,7 @@ def matmul_ref(a, b, *, requant_shift=None, act=None):
     if requant_shift is None:
         return apply_act(jnp.dot(a, b, preferred_element_type=jnp.float32),
                          act).astype(a.dtype)
-    acc = jnp.dot(a.astype(jnp.int32), b.astype(jnp.int32),
+    acc = jnp.dot(a.astype(jnp.int8), b.astype(jnp.int8),
                   preferred_element_type=jnp.int32)
     acc = apply_act(acc, act)
     return apply_requant(acc, requant_shift).astype(jnp.int8)

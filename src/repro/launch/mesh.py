@@ -5,19 +5,6 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``jax.make_mesh`` kwargs for explicitly-Auto axis types.
-
-    ``jax.sharding.AxisType`` only exists on newer JAX (>= 0.5); older
-    releases neither expose it nor accept ``axis_types=`` — there every axis
-    is implicitly Auto, so omitting the kwarg is semantically identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """v5e pod slice: 16x16 = 256 chips single pod; 2 pods = 512 chips.
 
@@ -26,13 +13,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh for tests/elastic restore."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh) -> tuple:

@@ -28,7 +28,8 @@ request ends in a terminal ``status`` (ok | timeout | error | shed). The
 raise is absorbed by ``max_retries`` bounded retries, then by a ONE-SHOT
 whole-plan degradation to the xla reference path
 (:meth:`CompiledPlan.degrade_to_xla` — logged once, counted in obs
-metrics) before the round's batch retires with ``status="error"``. A
+metrics) before the round's batch retires with ``status="error"``. A real
+(non-injected) exception propagates out of :meth:`run_until_drained`. A
 ``corrupt`` fault poisons the round's host logits; the affected uids are
 recorded in ``CNNEngine.poisoned_uids`` (contained, not detected).
 Deadlines cancel at round admission; a full queue sheds at ``submit``
@@ -258,14 +259,14 @@ class CNNEngine:
                     if self.scfg.retry_backoff_s > 0:
                         time.sleep(self.scfg.retry_backoff_s
                                    * (2 ** (att - 1)))
+                # only injected faults are absorbed: a real compile or
+                # kernel error propagates to the caller instead of being
+                # served from the xla path
                 try:
                     got = attempt_round()
                     break
                 except faults.InjectedFault as e:
                     last_err = e        # fired pre-dispatch: retry is safe
-                except Exception as e:
-                    last_err = e        # real plan failure: stop retrying,
-                    break               # fall through to degradation
             if got is None and not self.plan.degraded:
                 # one-shot graceful degradation: recompile the whole plan
                 # on the xla reference path (logged + counted inside
@@ -274,7 +275,7 @@ class CNNEngine:
                 self._m["degraded"].inc()
                 try:
                     got = attempt_round()
-                except Exception as e:
+                except faults.InjectedFault as e:
                     last_err = e
             if got is None:
                 for r in batch:         # one shared forward — the whole

@@ -14,8 +14,8 @@ the analytic half — MAC count alone misranks schedules, so the model scores
 
 * :func:`analytic_config` — no measurement: a first-order TPU cost model
   built from the paper's analytic machinery (``ConvSpec.mac_count`` for
-  arithmetic, ``core.energy.TPUv5e`` for peak FLOPs / HBM bandwidth / VMEM
-  capacity) plus schedule-dependent terms: per-grid-step overhead, HBM
+  arithmetic, ``core.energy.target_chip`` for peak FLOPs / HBM bandwidth /
+  VMEM capacity) plus schedule-dependent terms: per-grid-step overhead, HBM
   traffic as a function of blocking, VPU/MXU lane utilization, and a hard
   VMEM-overflow penalty.
 
@@ -31,7 +31,7 @@ import numpy as np
 
 from repro.check.footprint import (element_bytes, kernel_footprint,
                                    vmem_budget, weight_bytes)
-from repro.core.energy import TPUv5e
+from repro.core.energy import target_chip
 from repro.core.primitives import ConvSpec
 from repro.kernels.common import cdiv
 from repro.obs import metrics as _obs_metrics
@@ -41,7 +41,6 @@ from . import cache as _cache
 from . import space as _space
 from .space import ShapeSig, effective_config
 
-TPU = TPUv5e()
 
 # First-order schedule constants (relative scoring is what matters).
 GRID_STEP_OVERHEAD_S = 2e-6          # per grid step: DMA setup + dispatch
@@ -84,7 +83,7 @@ def _vmem_cost(fp) -> float:
     """Soft penalty from the SAME footprint model ``check.check_schedule``
     enforces as a hard verdict — the cost model and the verifier can never
     disagree about what fits."""
-    return VMEM_PENALTY if fp.total_bytes > vmem_budget("tpu") else 1.0
+    return VMEM_PENALTY if fp.total_bytes > vmem_budget() else 1.0
 
 
 def _tiles(sig: ShapeSig, eff: Dict[str, int]):
@@ -110,6 +109,7 @@ def estimate_s(sig: ShapeSig, config: Dict[str, int], dtype: str) -> float:
     with the ``VMEM_PENALTY`` multiplier.
     """
     k = sig.kernel
+    tpu = target_chip()
 
     if k == "conv2d":
         n, h, w = sig.get("n"), sig.get("h"), sig.get("w")
@@ -127,9 +127,9 @@ def estimate_s(sig: ShapeSig, config: Dict[str, int], dtype: str) -> float:
         fp = kernel_footprint(sig, eff, dtype)
         t = dict(fp.terms)
         traffic = steps * (t["img"] + t["wts"] + t["out"])
-        compute = flops / (TPU.peak_bf16_flops * _util(bco) * _util(cxg))
+        compute = flops / (tpu.peak_bf16_flops * _util(bco) * _util(cxg))
         return (_vmem_cost(fp)
-                * (compute + traffic / TPU.hbm_bw + steps * GRID_STEP_OVERHEAD_S))
+                * (compute + traffic / tpu.hbm_bw + steps * GRID_STEP_OVERHEAD_S))
 
     if k == "depthwise2d":
         n, h, w, c, hk = (sig.get("n"), sig.get("h"), sig.get("w"),
@@ -142,9 +142,9 @@ def estimate_s(sig: ShapeSig, config: Dict[str, int], dtype: str) -> float:
         fp = kernel_footprint(sig, eff, dtype)
         t = dict(fp.terms)
         traffic = steps * (t["img"] + t["wts"] + t["out"])
-        compute = flops / (TPU.peak_bf16_flops * VPU_DERATE * _util(bc))
+        compute = flops / (tpu.peak_bf16_flops * VPU_DERATE * _util(bc))
         return (_vmem_cost(fp)
-                * (compute + traffic / TPU.hbm_bw + steps * GRID_STEP_OVERHEAD_S))
+                * (compute + traffic / tpu.hbm_bw + steps * GRID_STEP_OVERHEAD_S))
 
     if k == "shift_conv2d":
         n, h, w, c, co = (sig.get("n"), sig.get("h"), sig.get("w"),
@@ -157,9 +157,9 @@ def estimate_s(sig: ShapeSig, config: Dict[str, int], dtype: str) -> float:
         fp = kernel_footprint(sig, eff, dtype)
         t = dict(fp.terms)
         traffic = steps * (t["img"] + t["wts"] + t["out"])
-        compute = flops / (TPU.peak_bf16_flops * _util(bco) * _util(c))
+        compute = flops / (tpu.peak_bf16_flops * _util(bco) * _util(c))
         return (_vmem_cost(fp)
-                * (compute + traffic / TPU.hbm_bw + steps * GRID_STEP_OVERHEAD_S))
+                * (compute + traffic / tpu.hbm_bw + steps * GRID_STEP_OVERHEAD_S))
 
     if k == "add_conv2d":
         n, h, w = sig.get("n"), sig.get("h"), sig.get("w")
@@ -175,9 +175,9 @@ def estimate_s(sig: ShapeSig, config: Dict[str, int], dtype: str) -> float:
         fp = kernel_footprint(sig, eff, dtype)
         t = dict(fp.terms)
         traffic = steps * (t["img"] + t["wts"] + t["out"])
-        compute = flops / (TPU.peak_bf16_flops * VPU_DERATE * _util(bco, SUBLANE))
+        compute = flops / (tpu.peak_bf16_flops * VPU_DERATE * _util(bco, SUBLANE))
         return (_vmem_cost(fp)
-                * (compute + traffic / TPU.hbm_bw + steps * GRID_STEP_OVERHEAD_S))
+                * (compute + traffic / tpu.hbm_bw + steps * GRID_STEP_OVERHEAD_S))
 
     if k == "maxpool2d":
         n, c, win, s = sig.get("n"), sig.get("c"), sig.get("k"), sig.get("s")
@@ -190,9 +190,9 @@ def estimate_s(sig: ShapeSig, config: Dict[str, int], dtype: str) -> float:
         fp = kernel_footprint(sig, eff, dtype)
         t = dict(fp.terms)
         traffic = steps * (t["img"] + t["out"])
-        compute = flops / (TPU.peak_bf16_flops * VPU_DERATE * _util(bc))
+        compute = flops / (tpu.peak_bf16_flops * VPU_DERATE * _util(bc))
         return (_vmem_cost(fp)
-                * (compute + traffic / TPU.hbm_bw + steps * GRID_STEP_OVERHEAD_S))
+                * (compute + traffic / tpu.hbm_bw + steps * GRID_STEP_OVERHEAD_S))
 
     if k == "causal_conv1d":
         b, l, d, kk = (sig.get("b"), sig.get("l"), sig.get("d"), sig.get("k"))
@@ -203,9 +203,9 @@ def estimate_s(sig: ShapeSig, config: Dict[str, int], dtype: str) -> float:
         fp = kernel_footprint(sig, eff, dtype)
         t = dict(fp.terms)
         traffic = steps * (t["img"] + t["wts"] + t["out"])
-        compute = flops / (TPU.peak_bf16_flops * VPU_DERATE * _util(bc))
+        compute = flops / (tpu.peak_bf16_flops * VPU_DERATE * _util(bc))
         return (_vmem_cost(fp)
-                * (compute + traffic / TPU.hbm_bw + steps * GRID_STEP_OVERHEAD_S))
+                * (compute + traffic / tpu.hbm_bw + steps * GRID_STEP_OVERHEAD_S))
 
     if k == "matmul":
         m, kk, n = sig.get("m"), sig.get("k"), sig.get("n")
@@ -219,10 +219,10 @@ def estimate_s(sig: ShapeSig, config: Dict[str, int], dtype: str) -> float:
         # A/B blocks stream every step; the output block lands once per
         # (i, j) after the k-axis accumulation
         traffic = steps * (t["a"] + t["b"]) + gi * gj * t["out"]
-        compute = flops / (TPU.peak_bf16_flops
+        compute = flops / (tpu.peak_bf16_flops
                            * _util(bn) * _util(bk) * _util(bm, SUBLANE))
         return (_vmem_cost(fp)
-                * (compute + traffic / TPU.hbm_bw + steps * GRID_STEP_OVERHEAD_S))
+                * (compute + traffic / tpu.hbm_bw + steps * GRID_STEP_OVERHEAD_S))
 
     raise ValueError(f"unknown kernel {k!r}")
 

@@ -17,7 +17,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Iterator, List, Tuple
 
-from repro.kernels.common import batch_spatial_schedule, effective_block
+from repro.kernels.common import (batch_spatial_schedule, contraction_block,
+                                  effective_block)
 
 # Kernels the tuner knows about. Names match repro.kernels.ops entry points.
 KERNELS = ("conv2d", "depthwise2d", "shift_conv2d", "add_conv2d",
@@ -33,7 +34,7 @@ _DEFAULTS: Dict[str, Dict[str, int]] = {
     "conv2d": {"block_co": 128, "block_n": 1},
     "depthwise2d": {"block_c": 128, "block_n": 1},
     "shift_conv2d": {"block_co": 128, "block_n": 1},
-    "add_conv2d": {"block_co": 8, "block_n": 1},
+    "add_conv2d": {"block_co": 128, "block_n": 1},
     "causal_conv1d": {"block_l": 512, "block_c": 512},
     "matmul": {"bm": 256, "bn": 256, "bk": 512},
     "maxpool2d": {"block_c": 128, "block_n": 1},
@@ -148,9 +149,10 @@ def effective_config(sig: ShapeSig, cfg: Dict[str, int]) -> Dict[str, int]:
 
     Divisor-gridded kernels degrade blocks via ``effective_block`` (and the
     tiled-grid kernels resolve block_n/block_h/block_w through
-    ``batch_spatial_schedule``); matmul's cdiv grid only clamps to the
-    dimension. Two configs with equal effective schedules are the same
-    compiled kernel — the space dedupes on this, and tuned-vs-default
+    ``batch_spatial_schedule``); matmul's cdiv M/N grid only clamps to the
+    dimension, and its K block is ``contraction_block`` (tiles K exactly).
+    Two configs with equal effective schedules are the same compiled
+    kernel — the space dedupes on this, and tuned-vs-default
     comparisons are only meaningful across distinct effective schedules.
     """
     k = sig.kernel
@@ -181,7 +183,7 @@ def effective_config(sig: ShapeSig, cfg: Dict[str, int]) -> Dict[str, int]:
     if k == "matmul":
         return {"bm": min(get("bm"), sig.get("m")),
                 "bn": min(get("bn"), sig.get("n")),
-                "bk": min(get("bk"), sig.get("k"))}
+                "bk": contraction_block(sig.get("k"), get("bk"))}
     raise AssertionError(k)  # pragma: no cover - ShapeSig guards kernel
 
 

@@ -253,8 +253,9 @@ def test_ops_dispatch_accepts_tiled_configs():
     """The ops layer threads the new knobs through config= like any other
     schedule parameter (pallas == xla on a tiled schedule)."""
     x = rnd((4, 12, 12, 8))
-    w = rnd((3, 3, 8, 16), key=jax.random.PRNGKey(1))
-    got = ops.conv2d(x, w, config={"block_n": 2, "block_h": 8, "block_co": 8})
+    w = rnd((3, 3, 8, 256), key=jax.random.PRNGKey(1))
+    got = ops.conv2d(x, w, config={"block_n": 2, "block_h": 8,
+                                   "block_co": 128})
     want = ops.conv2d(x, w, method="xla")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)

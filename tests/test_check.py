@@ -67,7 +67,7 @@ def test_block_n_64_rejected_on_table2_shape():
     v = check_schedule(sig, {"block_n": 64}, "int8")
     assert not v.ok
     assert any("exceeds" in e and "budget" in e for e in v.errors)
-    assert v.footprint.total_bytes > vmem_budget("tpu")
+    assert v.footprint.total_bytes > vmem_budget()
 
 
 def test_unknown_key_and_bad_value_are_errors():
@@ -86,7 +86,7 @@ def test_degradation_is_a_warning_not_an_error():
 
 def test_vmem_budget_env_override(monkeypatch):
     monkeypatch.setenv("REPRO_VMEM_BUDGET", "4096")
-    assert vmem_budget("tpu") == 4096
+    assert vmem_budget() == 4096
     sig = sig_matmul(256, 256, 256)
     assert not check_schedule(sig, {}, "int8").ok
 
@@ -219,6 +219,31 @@ def test_candidates_pruned_to_feasible_with_default_kept():
                for c in cands[1:])
 
 
+def test_tiling_rule_prunes_illegal_candidates():
+    """Block shapes the TPU tiling rule refuses (last two block dims a
+    multiple of (8, 128) or the whole extent) are errors, and the tuner's
+    space never offers them: block_co=8 of 32 output channels is out,
+    the whole 32 (or a 128-multiple) is in."""
+    sig = space.sig_add_conv2d(1, 16, 16, 16, 32, 3)
+    v = check_schedule(sig, {"block_co": 8}, "int8")
+    assert not v.ok and any("tiling rule" in e for e in v.errors)
+    assert check_schedule(sig, {"block_co": 32}, "int8").ok
+    effs = [space.effective_config(sig, c)["block_co"]
+            for c in space.candidates(sig, "int8")]
+    assert effs and set(effs) == {32}
+    # output tile width: a multiple of 8 sublanes, or the whole map
+    conv = sig_conv2d(1, 16, 16, 8, 16, 3)
+    assert not check_schedule(conv, {"block_w": 4}, "int8").ok
+    assert check_schedule(conv, {"block_w": 8}, "int8").ok
+    # matmul: N and K blocks are lane-aligned or whole; K tiles K exactly
+    mm = sig_matmul(8, 4864, 896)
+    assert not check_schedule(mm, {"bn": 64}, "int8").ok
+    assert space.effective_config(mm, {"bk": 1024})["bk"] == 256
+    for c in space.candidates(mm, "int8"):
+        assert not any("tiling rule" in e
+                       for e in check_schedule(mm, c, "int8").errors)
+
+
 # ------------------------------------------------- ops explicit configs ---
 
 def test_ops_rejects_explicit_infeasible_config(monkeypatch):
@@ -230,6 +255,17 @@ def test_ops_rejects_explicit_infeasible_config(monkeypatch):
     monkeypatch.setenv("REPRO_VMEM_BUDGET", "1024")
     with pytest.raises(CheckError, match="exceeds"):
         ops.conv2d(x, w, config=dict(default_config("conv2d")))
+
+
+def test_ops_rejects_explicit_config_breaking_tiling_rule():
+    from repro.kernels import ops
+    x = np.zeros((1, 8, 8, 8), np.int8)
+    w = np.zeros((3, 3, 8, 32), np.int8)
+    with pytest.raises(CheckError, match="tiling rule"):
+        ops.add_conv2d(x, w, requant_shift=4, config={"block_co": 8})
+    with pytest.raises(CheckError, match="tiling rule"):
+        ops.matmul(np.zeros((8, 256), np.int8), np.zeros((256, 256), np.int8),
+                   requant_shift=4, bn=64)
 
 
 # --------------------------------------------------------- serve configs ---

@@ -351,6 +351,23 @@ def test_kernel_dispatch_degrades_sticky_and_bit_exact():
     assert ops.degraded() == {}
 
 
+def test_real_kernel_error_raises_instead_of_degrading(monkeypatch):
+    """Only injected faults degrade: a real kernel or compile error raises
+    out of ops.* and leaves no sticky degradation behind."""
+    from repro.kernels import ops
+
+    def broken(*a, **k):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(ops, "_conv_pallas", broken)
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 6, 6, 3))
+    w = jax.random.normal(jax.random.PRNGKey(1), (3, 3, 3, 4))
+    ops.reset_degraded()
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        ops.conv2d(x, w, method="pallas")
+    assert ops.degraded() == {}
+
+
 # ----------------------------------------------------------------- LM chaos
 
 
@@ -586,6 +603,26 @@ def test_cnn_exhausted_retries_degrade_then_serve():
     assert rep.ok, rep.summary()
     assert all(s == "ok" for s in rep.statuses.values())
     assert ex.degraded and rep.stats["degraded"] == 1
+
+
+def test_cnn_real_plan_error_propagates():
+    """A real (non-injected) failure of the batched forward raises out of
+    CNNEngine: no retry, no degradation to the xla path."""
+    from repro.serve import CNNEngine, CNNServeConfig
+    plan, CompiledPlan = cnn_setup()
+    ex = CompiledPlan(plan, method="xla")
+
+    def broken(x):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    ex.forward_batch = broken
+    eng = CNNEngine(ex, CNNServeConfig(max_batch=4))
+    for r in make_images(3)():
+        eng.submit(r)
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        eng.run_until_drained()
+    assert not ex.degraded
+    assert eng.stats["degraded"] == 0 and eng.stats["retries"] == 0
 
 
 def test_cnn_degraded_plan_error_when_faults_persist():

@@ -269,3 +269,33 @@ def test_ops_unknown_method_rejected():
     ]:
         with pytest.raises(ValueError, match="unknown method"):
             fn(*args, method="nope")
+
+
+_I8 = jnp.zeros((2, 8, 8, 16), jnp.int8)
+
+
+@pytest.mark.parametrize("oracle,args,kw,n_contractions", [
+    (ref.conv2d_q8_ref, (_I8, jnp.zeros((3, 3, 8, 32), jnp.int8)),
+     {"groups": 2, "requant_shift": 4}, 2),
+    (ref.depthwise2d_q8_ref, (_I8, jnp.zeros((3, 3, 16), jnp.int8)),
+     {"requant_shift": 4}, 0),
+    (ref.shift_conv2d_q8_ref,
+     (_I8, np.zeros((16, 2), np.int32), jnp.zeros((16, 32), jnp.int8)),
+     {"requant_shift": 4, "max_shift": 1}, 1),
+    (ref.matmul_ref, (jnp.zeros((8, 64), jnp.int8),
+                      jnp.zeros((64, 32), jnp.int8)), {"requant_shift": 4}, 1),
+], ids=["conv2d", "depthwise2d", "shift_conv2d", "matmul"])
+def test_integer_oracles_contract_int8_operands(oracle, args, kw,
+                                                n_contractions):
+    """The integer oracles feed int8 codes to every contraction, accumulate
+    in int32, and never use a grouped convolution: on a v5e, XLA rounds
+    int32 x int32 convolutions once accumulators grow and miscomputes
+    grouped int8 ones, which made the oracle (not the kernel) disagree."""
+    jaxpr = jax.make_jaxpr(lambda *a: oracle(*a, **kw))(*args)
+    eqns = [e for e in jaxpr.jaxpr.eqns
+            if e.primitive.name in ("conv_general_dilated", "dot_general")]
+    assert len(eqns) == n_contractions
+    for e in eqns:
+        assert [v.aval.dtype for v in e.invars] == [jnp.int8, jnp.int8]
+        assert e.outvars[0].aval.dtype == jnp.int32
+        assert e.params.get("feature_group_count", 1) == 1

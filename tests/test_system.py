@@ -107,3 +107,25 @@ def test_paper_headline_claims():
     add = ConvSpec(primitive="add", in_channels=16, out_channels=16)
     assert shift.param_count() < std.param_count()
     assert add.mac_count(32) == std.mac_count(32)
+
+
+def test_compile_cache_dir_from_env_or_checkout(monkeypatch):
+    """Entry points keep JAX's persistent compilation cache where
+    JAX_COMPILATION_CACHE_DIR says (setting nothing themselves), else at
+    one fixed directory inside the checkout."""
+    import os
+    from repro.compile_cache import DEFAULT_DIR, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(DEFAULT_DIR)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert DEFAULT_DIR.parent == type(DEFAULT_DIR)(root)
+        assert DEFAULT_DIR.name == ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

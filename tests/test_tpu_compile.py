@@ -1,0 +1,139 @@
+"""Ahead-of-time compiles of the serving paths' Pallas kernels for a TPU v5e.
+
+Each case compiles one kernel with ``interpret=False`` for a described (not
+attached) v5e chip, at the shapes the paper's CNN (default ``CNNConfig``,
+batch 8) and qwen2-0.5b's FFN (896 x 4864) serve, under the schedule the
+tuner resolves for that shape. The chip's compiler then refuses what
+interpret mode accepts: unaligned blocks, unsupported vector ops, VMEM
+overruns. Nothing runs; results are checked by the interpret-mode tests.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU compiler library at a time.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import tune
+from repro.kernels.conv_add import add_conv2d
+from repro.kernels.conv_dw import depthwise2d
+from repro.kernels.conv_im2col import conv2d_im2col
+from repro.kernels.conv_shift import shift_conv2d
+from repro.kernels.matmul_q8 import matmul
+from repro.kernels.pool import maxpool2d
+
+N = 8                                   # CNNEngine's max_batch bucket
+I8, I32 = jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_hlo(sharding, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _shifts(c):
+    i = np.arange(c)
+    return np.stack([i % 3 - 1, (i // 3) % 3 - 1], axis=1)
+
+
+# (name, builder) — a builder returns (kernel fn, [(shape, dtype), ...]) at
+# one of the CNN's three layer shapes (32x32x3->16, 16x16x16->32,
+# 8x8x32->64) or the LM FFN's widths; schedules are the tuner's picks
+def _conv(h, ci, co, groups=1, w4=False):
+    sig = tune.sig_conv2d(N, h, h, ci, co, 3, groups)
+    cfg = tune.get_config(sig, "w4a8" if w4 else "int8")
+    cxg = ci // groups
+    if w4:
+        return (lambda x, w, ws, b: conv2d_im2col(
+            x, w, b, groups=groups, requant_shift=6, act="relu",
+            interpret=False, config=cfg, w_shifts=ws),
+            [((N, h, h, ci), I8), ((3, 3, (cxg + 1) // 2, co), I8),
+             ((cxg,), I8), ((co,), I32)])
+    return (lambda x, w, b: conv2d_im2col(
+        x, w, b, groups=groups, requant_shift=6, act="relu",
+        interpret=False, config=cfg),
+        [((N, h, h, ci), I8), ((3, 3, cxg, co), I8), ((co,), I32)])
+
+
+def _dws(h, c):
+    cfg = tune.get_config(tune.sig_depthwise2d(N, h, h, c, 3), "int8")
+    return (lambda x, w: depthwise2d(x, w, requant_shift=6, interpret=False,
+                                     config=cfg),
+            [((N, h, h, c), I8), ((3, 3, c), I8)])
+
+
+def _shift(h, c, co):
+    cfg = tune.get_config(tune.sig_shift_conv2d(N, h, h, c, co), "int8")
+    return (lambda x, w, b: shift_conv2d(x, _shifts(c), w, b, requant_shift=6,
+                                         act="relu", interpret=False,
+                                         config=cfg),
+            [((N, h, h, c), I8), ((c, co), I8), ((co,), I32)])
+
+
+def _add(h, ci, co):
+    cfg = tune.get_config(tune.sig_add_conv2d(N, h, h, ci, co, 3), "int8")
+    return (lambda x, w, b: add_conv2d(x, w, b, requant_shift=6, act="relu",
+                                       interpret=False, config=cfg),
+            [((N, h, h, ci), I8), ((3, 3, ci, co), I8), ((co,), I32)])
+
+
+def _pool(h, c):
+    cfg = tune.get_config(tune.sig_maxpool2d(N, h, h, c, 2, 2), "int8")
+    return (lambda x: maxpool2d(x, window=2, interpret=False, config=cfg),
+            [((N, h, h, c), I8)])
+
+
+def _mm(m, k, n, w4=False):
+    cfg = tune.get_config(tune.sig_matmul(m, k, n), "w4a8" if w4 else "int8")
+    if w4:
+        return (lambda a, b, ws: matmul(a, b, requant_shift=6,
+                                        interpret=False, config=cfg,
+                                        w_shifts=ws),
+                [((m, k), I8), (((k + 1) // 2, n), I8), ((k,), I8)])
+    return (lambda a, b: matmul(a, b, requant_shift=6, interpret=False,
+                                config=cfg),
+            [((m, k), I8), ((k, n), I8)])
+
+
+CASES = {
+    "conv2d_int8_stem": lambda: _conv(32, 3, 16),
+    "conv2d_int8": lambda: _conv(16, 16, 32),
+    "conv2d_int8_pointwise": lambda: _conv(8, 32, 64),
+    "conv2d_grouped_int8": lambda: _conv(16, 16, 32, groups=2),
+    "depthwise2d_int8": lambda: _dws(16, 16),
+    "shift_conv2d_int8": lambda: _shift(16, 16, 32),
+    "add_conv2d_int8_stem": lambda: _add(32, 3, 16),
+    "add_conv2d_int8": lambda: _add(8, 32, 64),
+    "conv2d_w4": lambda: _conv(16, 16, 32, w4=True),
+    "maxpool2d_int8": lambda: _pool(32, 16),
+    "matmul_q8_ffn_up": lambda: _mm(N, 896, 4864),
+    "matmul_q8_ffn_down": lambda: _mm(N, 4864, 896),
+    "matmul_w4_ffn_up": lambda: _mm(N, 896, 4864, w4=True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(one_chip, case):
+    fn, shapes = CASES[case]()
+    hlo = _compiled_hlo(one_chip, fn, *shapes)
+    assert "tpu_custom_call" in hlo

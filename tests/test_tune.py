@@ -252,7 +252,7 @@ def test_ops_parity_with_planted_cache_config():
 
 def test_ops_explicit_config_overrides():
     x = rnd((1, 8, 8, 8))
-    w = rnd((3, 3, 8, 16), key=jax.random.PRNGKey(1))
-    np.testing.assert_allclose(ops.conv2d(x, w, config={"block_co": 2}),
+    w = rnd((3, 3, 8, 256), key=jax.random.PRNGKey(1))
+    np.testing.assert_allclose(ops.conv2d(x, w, config={"block_co": 128}),
                                ops.conv2d(x, w, method="xla"),
                                rtol=2e-5, atol=2e-5)
