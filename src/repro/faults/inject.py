@@ -2,8 +2,7 @@
 
 Fault *sites* are named seams in the hot paths — the instrumented code
 calls :func:`check(site)` at each seam. With no plan active that call is
-one module-global read and a ``None`` compare (the same near-zero-cost
-discipline as ``repro.obs.trace``'s ``_NullSpan``), so the seams ride in
+one module-global read and a ``None`` compare, so the seams ride in
 production paths permanently. With a plan active, the per-site hit
 counter advances and any matching :class:`FaultSpec` fires:
 

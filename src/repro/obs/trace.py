@@ -1,31 +1,40 @@
-"""Span tracer with Chrome trace-event export (Perfetto / chrome://tracing).
+"""Span tracer: every span on the profiler's clock, and on request a Chrome
+trace-event export (Perfetto / chrome://tracing).
 
-One process-wide :data:`TRACER` collects duration events ("B"/"E" pairs)
-from every instrumented layer — serve-engine request lifecycles, decode
-rounds, executor layers, autotuner candidates — and exports them as the
-Chrome trace-event JSON format, which loads directly in Perfetto
-(https://ui.perfetto.dev) or ``chrome://tracing``.
+Every :func:`span` (and :func:`traced` call) opens a
+``jax.profiler.TraceAnnotation`` of the same name, its attributes the
+annotation's metadata. While a profiler session is active
+(``jax.profiler.start_trace`` / ``trace``) the span lands in the
+``.xplane.pb`` on the host plane of its thread, on the clock the device
+operations use; with no session an annotation costs about a microsecond.
+Each span also reads ``time.perf_counter()`` on entry and exit and keeps
+the pair (``t0``, ``t1``, ``seconds``), so a caller can add the span's
+length to a counter without reading the clock again.
 
-Enabling: tracing is OFF by default and gated by the ``REPRO_TRACE`` env
-var (any value other than ``""``/``"0"``), read once when the tracer is
-constructed; :func:`enable`/:func:`disable` toggle it programmatically.
-When disabled every entry point is a near-zero-cost no-op — ``span()``
-returns a shared null context manager after one attribute check, and
-``begin``/``end``/``complete`` return immediately — so instrumented hot
-paths (the serve engines' per-round loops) carry no measurable overhead
-with tracing off.
+Chrome export: one process-wide :data:`TRACER` collects duration events
+("B"/"E" pairs) from every instrumented layer — serve-engine request
+lifecycles, decode rounds, executor layers, autotuner candidates — and
+exports them as the Chrome trace-event JSON format, which loads directly
+in Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``. It is OFF
+by default and gated by the ``REPRO_TRACE`` env var (any value other than
+``""``/``"0"``), read once when the tracer is constructed;
+:func:`enable`/:func:`disable` toggle it programmatically. When it is off,
+``begin``/``end``/``complete`` return immediately and spans emit no
+events.
 
-Clocks: event timestamps come from ``time.perf_counter()`` (monotonic, so
-intervals can never go negative under wall-clock adjustment), rebased to
-the tracer's construction instant and expressed in microseconds as the
-trace format requires. The wall-clock time of that instant is recorded in
-the export's ``otherData`` so absolute times are recoverable.
+Clocks: Chrome event timestamps are the spans' ``perf_counter`` stamps
+(monotonic, so intervals can never go negative under wall-clock
+adjustment), rebased to the tracer's construction instant and expressed
+in microseconds as the trace format requires. The wall-clock time of that
+instant is recorded in the export's ``otherData`` so absolute times are
+recoverable.
 
 Lanes: ``tid`` defaults to the real thread id, but callers may pass a
 synthetic lane id — the serve engines replay each retired request's
 lifecycle (queue-wait -> prefill -> generate) onto its own fresh lane, so
 overlapping requests render as parallel tracks and B/E pairs still nest
-properly per lane.
+properly per lane. Replayed events are Chrome-only: a profiler annotation
+cannot be back-dated.
 """
 from __future__ import annotations
 
@@ -36,6 +45,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 ENV_VAR = "REPRO_TRACE"
 
@@ -54,31 +65,18 @@ def next_lane() -> int:
     return _LANE_BASE + next(_lane_counter)
 
 
-class _NullSpan:
-    """Shared do-nothing context manager returned when tracing is off."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def set(self, **attrs):
-        pass
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class _Span:
-    """Context manager emitting one balanced B/E pair on the owning tracer.
+    """Context manager for one span: a profiler annotation, a
+    ``perf_counter`` pair, and a balanced B/E pair on the owning tracer
+    when it is enabled at entry.
 
-    Attributes passed at construction ride on the "B" event; attributes
-    added via :meth:`set` during the span ride on the "E" event (Perfetto
-    merges both into the slice's args).
+    Attributes passed at construction ride on the annotation and the "B"
+    event; attributes added via :meth:`set` during the span join the
+    annotation's metadata and ride on the "E" event (Perfetto merges both
+    into the slice's args).
     """
-    __slots__ = ("_tr", "_name", "_tid", "_cat", "_attrs", "_exit_attrs")
+    __slots__ = ("_tr", "_name", "_tid", "_cat", "_attrs", "_exit_attrs",
+                 "_ann", "_emit", "t0", "t1")
 
     def __init__(self, tr: "Tracer", name: str, tid, cat: str, attrs: dict):
         self._tr = tr
@@ -87,20 +85,38 @@ class _Span:
         self._cat = cat
         self._attrs = attrs
         self._exit_attrs: Dict[str, Any] = {}
+        self._ann = None
+        self._emit = False
+        self.t0 = self.t1 = 0.0
+
+    @property
+    def seconds(self) -> float:
+        """perf_counter seconds from entry to exit."""
+        return self.t1 - self.t0
 
     def set(self, **attrs):
         """Attach attributes discovered mid-span (e.g. a measured time)."""
         self._exit_attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
         return self
 
     def __enter__(self):
-        self._tr.begin(self._name, tid=self._tid, cat=self._cat,
-                       **self._attrs)
+        self._ann = TraceAnnotation(self._name, **self._attrs)
+        self._ann.__enter__()
+        self._emit = self._tr.enabled
+        self.t0 = time.perf_counter()
+        if self._emit:
+            self._tr.begin(self._name, ts=self.t0, tid=self._tid,
+                           cat=self._cat, **self._attrs)
         return self
 
     def __exit__(self, *exc):
-        self._tr.end(self._name, tid=self._tid, cat=self._cat,
-                     **self._exit_attrs)
+        self.t1 = time.perf_counter()
+        if self._emit:
+            self._tr.end(self._name, ts=self.t1, tid=self._tid,
+                         cat=self._cat, **self._exit_attrs)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -169,8 +185,6 @@ class Tracer:
 
     def span(self, name: str, *, tid=None, cat: str = "repro", **attrs):
         """Context manager measuring the enclosed block as one span."""
-        if not self._enabled:
-            return _NULL_SPAN
         return _Span(self, name, tid, cat, attrs)
 
     # ------------------------------------------------------------- export --
@@ -233,15 +247,13 @@ def traced(name: Optional[str] = None, *, cat: str = "repro"):
     """Decorator form: trace every call of ``fn`` as one span.
 
     ``@traced()`` uses the function's qualname; ``@traced("label")`` names
-    the span explicitly. Disabled-mode cost is one attribute check.
+    the span explicitly.
     """
     def deco(fn):
         label = name or fn.__qualname__
 
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
-            if not TRACER.enabled:
-                return fn(*args, **kwargs)
             with TRACER.span(label, cat=cat):
                 return fn(*args, **kwargs)
         return wrapper

@@ -56,12 +56,23 @@ TTFT/TPOT/queue-wait latency quantiles, decode throughput, and block-pool
 gauges (``blocks_in_use`` / ``blocks_free`` / ``prefix_hit_rate``; zero
 under the contiguous layout). The stats are backed by a private
 ``repro.obs.metrics.Registry`` per engine (same keys as the pre-registry
-dict, plus the histogram quantiles), and with ``REPRO_TRACE=1`` the
-engine emits per-request lifecycle spans (queue_wait -> prefill ->
-generate, each request on its own trace lane) plus per-round decode spans
-and paged-pool events (``engine.block_alloc`` / ``engine.block_free`` /
-``engine.prefix_lookup``) to the process tracer — export with
-``repro.obs.trace.export(path)`` and open in Perfetto.
+dict, plus the histogram quantiles).
+
+Spans (``repro.obs.trace``; in the profiler's trace whenever a session is
+active, on the device's clock): ``engine.drain`` around each
+:meth:`Engine.run_until_drained`, and inside it, tiling each pass of the
+continuous loop, ``engine.arena_init`` (the KV cache and ``BlockPool``
+built per drain or rebuilt after a failure), ``engine.admit`` (one per
+admission: prefix lookup, page allocation, ``engine.prefill``, the logits
+copy and the first pick), ``engine.upload`` (page growth and the
+``len`` / block-table uploads), ``engine.decode_round`` and
+``engine.sample`` (the logits copy, the per-row picks, retirement). The
+counters ``serve.admit_time_s``, ``serve.upload_time_s``,
+``serve.sample_time_s`` and ``serve.decode_time_s`` add up the
+``perf_counter`` time of their spans. With ``REPRO_TRACE=1`` the same
+spans and per-request lifecycle lanes (queue_wait -> prefill -> generate,
+each request on its own lane) also go to the Chrome export — write it
+with ``repro.obs.trace.export(path)`` and open it in Perfetto.
 
 Timing discipline: decode-round timers ``jax.block_until_ready`` the round
 outputs before stopping, so ``decode_tok_s`` measures real device time and
@@ -606,6 +617,11 @@ class Engine:
             "requests_done": self.metrics.counter("serve.requests_done"),
             "occupied": self.metrics.counter("serve.occupied_slot_rounds"),
             "decode_time": self.metrics.counter("serve.decode_time_s"),
+            # host phases of a continuous round, each the perf_counter time
+            # of its span (engine.admit / engine.upload / engine.sample)
+            "admit_time": self.metrics.counter("serve.admit_time_s"),
+            "upload_time": self.metrics.counter("serve.upload_time_s"),
+            "sample_time": self.metrics.counter("serve.sample_time_s"),
             "ttft": self.metrics.histogram("serve.ttft_s"),
             "tpot": self.metrics.histogram("serve.tpot_s"),
             "queue_wait": self.metrics.histogram("serve.queue_wait_s"),
@@ -800,23 +816,25 @@ class Engine:
         B = self.scfg.max_batch
         paged = self.scfg.kv_layout == "paged"
         bs = self.scfg.kv_block_size
-        if paged:
-            from repro.check.config import paged_num_blocks
-            nblocks = paged_num_blocks(self.scfg)
-            cache = api.init_paged_cache(self.cfg, B, nblocks, bs,
-                                         self.scfg.max_len,
-                                         kv=self.scfg.kv_cache)
-            pool = BlockPool(nblocks, bs,
-                             prefix_cache=self.scfg.prefix_cache)
-            self.pool = pool            # audited by repro.faults.chaos
-            table = np.zeros((B, self.scfg.max_len // bs), np.int32)
-            slot_ids: List[List[int]] = [[] for _ in range(B)]
-            slot_hashed = [0] * B       # leading refcounted pages per slot
-            holdback: "collections.deque[Request]" = collections.deque()
-            self._update_pool_gauges(pool)
-        else:
-            cache = api.init_slot_cache(self.cfg, B, self.scfg.max_len,
-                                        kv=self.scfg.kv_cache)
+        with obs_trace.span("engine.arena_init",
+                            layout=self.scfg.kv_layout):
+            if paged:
+                from repro.check.config import paged_num_blocks
+                nblocks = paged_num_blocks(self.scfg)
+                cache = api.init_paged_cache(self.cfg, B, nblocks, bs,
+                                             self.scfg.max_len,
+                                             kv=self.scfg.kv_cache)
+                pool = BlockPool(nblocks, bs,
+                                 prefix_cache=self.scfg.prefix_cache)
+                self.pool = pool        # audited by repro.faults.chaos
+                table = np.zeros((B, self.scfg.max_len // bs), np.int32)
+                slot_ids: List[List[int]] = [[] for _ in range(B)]
+                slot_hashed = [0] * B   # leading refcounted pages per slot
+                holdback: "collections.deque[Request]" = collections.deque()
+                self._update_pool_gauges(pool)
+            else:
+                cache = api.init_slot_cache(self.cfg, B, self.scfg.max_len,
+                                            kv=self.scfg.kv_cache)
         slots: List[Optional[Request]] = [None] * B
         admit_seq = [0] * B             # admission order, for victim choice
         seq = 0
@@ -831,26 +849,24 @@ class Engine:
                 return holdback.popleft()
             return self._next_request()
 
-        def admit_paged(i: int, req: Request, plen: int):
+        def admit_paged(i: int, req: Request, plen: int, sp):
             """Returns last-position logits, or None when the pool cannot
             supply the prompt's pages (admission backpressure). Exception-
             safe: any failure after pages were referenced/allocated rolls
             the pool back before re-raising, so a retried (or retired)
-            admission never leaks pages."""
+            admission never leaks pages. ``sp`` is the engine.admit span,
+            which gets the page counts."""
             nonlocal cache
             nb = -(-plen // bs)         # pages covering positions [0, plen)
             keys = pool.prefix_keys(req.prompt)
-            with obs_trace.span("engine.prefix_lookup", uid=req.uid,
-                                blocks=len(keys)):
-                hit_ids = pool.lookup(keys)
+            hit_ids = pool.lookup(keys)
             n_hit = len(hit_ids)
+            sp.set(hit_blocks=n_hit, new_blocks=nb - n_hit)
             # reference the hit pages BEFORE alloc so its eviction scan
             # cannot reclaim them out from under this admission
             pool.acquire(hit_ids)
             try:
-                with obs_trace.span("engine.block_alloc", uid=req.uid,
-                                    n=nb - n_hit):
-                    fresh = pool.alloc(nb - n_hit)
+                fresh = pool.alloc(nb - n_hit)
             except Exception:
                 pool.release(hit_ids)   # injected blockpool.alloc fault
                 raise
@@ -923,11 +939,23 @@ class Engine:
             return logits
 
         def try_admit(i: int, req: Request) -> str:
-            """Admit ``req`` into free slot ``i``. Returns "ok", "full"
-            (paged pool backpressure — park in the holdback), or "failed"
-            (the admission survived max_retries and the request was
-            retired with status="error"). The ``engine.prefill`` fault
-            seam fires once per attempt, BEFORE any device call or pool
+            """Admit ``req`` into free slot ``i`` inside one engine.admit
+            span (an admission that finishes its request at once retires
+            it there too). Returns "ok", "full" (paged pool backpressure —
+            park in the holdback), or "failed" (the admission survived
+            max_retries and the request was retired with status="error")."""
+            with obs_trace.span("engine.admit", uid=req.uid,
+                                plen=len(req.prompt)) as sp:
+                res = admit_attempts(i, req, sp)
+                sp.set(result=res)
+                if res == "ok":
+                    maybe_retire(i)
+            self._m["admit_time"].inc(sp.seconds)
+            return res
+
+        def admit_attempts(i: int, req: Request, sp) -> str:
+            """try_admit's attempts. The ``engine.prefill`` fault seam
+            fires once per attempt, BEFORE any device call or pool
             mutation, so an injected raise is always retry-safe."""
             nonlocal cache, seq
             self._validate_prompt_len(req)   # directly enqueued requests
@@ -942,7 +970,7 @@ class Engine:
                 try:
                     fired = faults.check("engine.prefill")
                     if paged:
-                        logits = admit_paged(i, req, plen)
+                        logits = admit_paged(i, req, plen, sp)
                         if logits is None:
                             return "full"
                     else:
@@ -1027,9 +1055,7 @@ class Engine:
             slots[i] = None
             lens[i] = 0
             if paged:
-                with obs_trace.span("engine.block_free", uid=req.uid,
-                                    n=len(slot_ids[i])):
-                    pool.free(slot_ids[i], hashed=slot_hashed[i])
+                pool.free(slot_ids[i], hashed=slot_hashed[i])
                 slot_ids[i] = []
                 slot_hashed[i] = 0
                 table[i, :] = 0
@@ -1050,9 +1076,7 @@ class Engine:
             pages usually survive as evictable, so the re-prefill hits)."""
             nonlocal cache
             req = slots[victim]
-            with obs_trace.span("engine.block_free", uid=req.uid,
-                                n=len(slot_ids[victim]), preempt=True):
-                pool.free(slot_ids[victim], hashed=slot_hashed[victim])
+            pool.free(slot_ids[victim], hashed=slot_hashed[victim])
             # the discarded tokens stay in tokens_out (they were real decode
             # work); the replay after re-admission counts its own
             req.out_tokens = []
@@ -1077,13 +1101,15 @@ class Engine:
                 self._m["retries"].inc()
                 return None
 
-        def grow_tables():
+        def grow_tables() -> int:
             """Allocate the next page for every slot whose write position
             reached a page boundary; under pool pressure preempt youngest-
             admitted slots (oldest-first processing guarantees progress —
-            a lone grower can always reclaim evictable pages)."""
+            a lone grower can always reclaim evictable pages). Returns the
+            number of pages allocated."""
             order = sorted((i for i in range(B) if slots[i] is not None),
                            key=lambda i: admit_seq[i])
+            grown = 0
             for i in order:
                 if slots[i] is None:        # preempted by an older grower
                     continue
@@ -1091,9 +1117,7 @@ class Engine:
                 if pos >= self.scfg.max_len or pos % bs \
                         or pos // bs < len(slot_ids[i]):
                     continue
-                with obs_trace.span("engine.block_alloc",
-                                    uid=slots[i].uid, n=1):
-                    got = pool_alloc(1)
+                got = pool_alloc(1)
                 while got is None:
                     victim = max((v for v in range(B)
                                   if slots[v] is not None),
@@ -1106,7 +1130,9 @@ class Engine:
                     continue
                 slot_ids[i].append(got[0])
                 table[i, pos // bs] = got[0]
+                grown += 1
             self._update_pool_gauges(pool)
+            return grown
 
         def rebuild_arena():
             """Fresh KV arena after an unrecoverable decode failure: the
@@ -1116,21 +1142,24 @@ class Engine:
             pages of the reset arena."""
             nonlocal cache, pool
             self._m["arena_rebuilds"].inc()
-            if paged:
-                cache = api.init_paged_cache(self.cfg, B, nblocks, bs,
-                                             self.scfg.max_len,
-                                             kv=self.scfg.kv_cache)
-                pool = BlockPool(nblocks, bs,
-                                 prefix_cache=self.scfg.prefix_cache)
-                self.pool = pool
-                table[:] = 0
-                for i in range(B):
-                    slot_ids[i] = []
-                    slot_hashed[i] = 0
-                self._update_pool_gauges(pool)
-            else:
-                cache = api.init_slot_cache(self.cfg, B, self.scfg.max_len,
-                                            kv=self.scfg.kv_cache)
+            with obs_trace.span("engine.arena_init",
+                                layout=self.scfg.kv_layout, rebuild=True):
+                if paged:
+                    cache = api.init_paged_cache(self.cfg, B, nblocks, bs,
+                                                 self.scfg.max_len,
+                                                 kv=self.scfg.kv_cache)
+                    pool = BlockPool(nblocks, bs,
+                                     prefix_cache=self.scfg.prefix_cache)
+                    self.pool = pool
+                    table[:] = 0
+                    for i in range(B):
+                        slot_ids[i] = []
+                        slot_hashed[i] = 0
+                    self._update_pool_gauges(pool)
+                else:
+                    cache = api.init_slot_cache(self.cfg, B,
+                                                self.scfg.max_len,
+                                                kv=self.scfg.kv_cache)
 
         stalls = 0                      # consecutive can't-admit iterations
         decode_failures = 0             # consecutive failed round attempts
@@ -1152,13 +1181,11 @@ class Engine:
                         # prefill would be wasted work past the budget
                         retire_unadmitted(req, "timeout")
                         continue
-                    res = try_admit(i, req)
-                    if res == "full":
+                    if try_admit(i, req) == "full":
                         holdback.appendleft(req)
                         blocked = True
-                    elif res == "ok":
-                        maybe_retire(i)
-                    # "failed": retired inside try_admit — keep refilling
+                    # "ok" / "failed": retired inside try_admit if it is
+                    # done already — keep refilling
                 if blocked:
                     break
             active = [i for i in range(B) if slots[i] is not None]
@@ -1182,12 +1209,16 @@ class Engine:
                 break                   # the admit loop drained the queue
             stalls = 0
             if paged:
-                grow_tables()
-                active = [i for i in range(B) if slots[i] is not None]
+                with obs_trace.span("engine.upload") as sp:
+                    sp.set(new_blocks=grow_tables())
+                    active = [i for i in range(B) if slots[i] is not None]
+                    if active:
+                        cache["len"] = jnp.asarray(np.asarray(lens,
+                                                              np.int32))
+                        cache["block_table"] = jnp.asarray(table)
+                self._m["upload_time"].inc(sp.seconds)
                 if not active:
                     continue            # preemption emptied the batch
-                cache["len"] = jnp.asarray(np.asarray(lens, np.int32))
-                cache["block_table"] = jnp.asarray(table)
             try:
                 # the seam fires BEFORE the device call (retrying an
                 # injected fault is safe: the donated cache is untouched)
@@ -1223,30 +1254,35 @@ class Engine:
                 decode_failures = 0
                 continue
             decode_failures = 0
-            logits = np.asarray(logits)
-            if round_fired is not None:
-                # corrupt directive: poison this round's host logits; every
-                # active request sampled from them is contained, not fixed
-                logits = round_fired.apply(logits)
+            with obs_trace.span("engine.sample", active=len(active)) as sp:
+                logits = np.asarray(logits)
+                if round_fired is not None:
+                    # corrupt directive: poison this round's host logits;
+                    # every active request sampled from them is contained,
+                    # not fixed
+                    logits = round_fired.apply(logits)
+                    for i in active:
+                        self.poisoned_uids.add(slots[i].uid)
+                self._round += 1
+                self._m["decode_steps"].inc()
+                self._m["occupied"].inc(len(active))
+                now_r = time.perf_counter()
                 for i in active:
-                    self.poisoned_uids.add(slots[i].uid)
-            self._round += 1
-            self._m["decode_steps"].inc()
-            self._m["occupied"].inc(len(active))
-            now_r = time.perf_counter()
-            for i in active:
-                lens[i] += 1            # this round wrote K/V at lens[i]
-                req = slots[i]
-                t = self._pick(logits[i, -1], req)
-                req.out_tokens.append(t)
-                self._m["tokens_out"].inc()
-                cur[i, 0] = t
-                maybe_retire(i)
-                if slots[i] is not None and self._expired(req, now_r):
-                    retire_slot(i, "timeout")   # round-boundary cancel
+                    lens[i] += 1        # this round wrote K/V at lens[i]
+                    req = slots[i]
+                    t = self._pick(logits[i, -1], req)
+                    req.out_tokens.append(t)
+                    self._m["tokens_out"].inc()
+                    cur[i, 0] = t
+                    maybe_retire(i)
+                    if slots[i] is not None and self._expired(req, now_r):
+                        retire_slot(i, "timeout")   # round-boundary cancel
+            self._m["sample_time"].inc(sp.seconds)
             # decode advanced every row's length, including retired/empty
             # slots; re-zero them so dead rows can never drift past max_len
-            cache["len"] = jnp.asarray(np.asarray(lens, np.int32))
+            with obs_trace.span("engine.upload") as sp:
+                cache["len"] = jnp.asarray(np.asarray(lens, np.int32))
+            self._m["upload_time"].inc(sp.seconds)
         return finished
 
     # ------------------------------------------------------------- static --
@@ -1318,15 +1354,17 @@ class Engine:
             self.poisoned_uids.update(r.uid for r in reqs)
         cur = np.zeros((b, 1), np.int32)
         now = time.perf_counter()
-        for i, r in enumerate(reqs):
-            t = self._pick(lg[i, -1], r)
-            r.first_token_t = now
-            self._m["ttft"].observe(r.ttft_s)
-            r.out_tokens.append(t)
-            self._m["tokens_out"].inc()
-            cur[i, 0] = t
-            if t == self._effective_eos(r) or r.max_new_tokens <= 1:
-                r.done = True
+        with obs_trace.span("engine.sample", active=b) as sp:
+            for i, r in enumerate(reqs):
+                t = self._pick(lg[i, -1], r)
+                r.first_token_t = now
+                self._m["ttft"].observe(r.ttft_s)
+                r.out_tokens.append(t)
+                self._m["tokens_out"].inc()
+                cur[i, 0] = t
+                if t == self._effective_eos(r) or r.max_new_tokens <= 1:
+                    r.done = True
+        self._m["sample_time"].inc(sp.seconds)
         steps = max(r.max_new_tokens for r in reqs) - 1
         for _ in range(max(steps, 0)):
             now = time.perf_counter()
@@ -1369,17 +1407,20 @@ class Engine:
                     r.uid for r in reqs if not r.done)
             self._round += 1
             self._m["decode_steps"].inc()
-            for i, r in enumerate(reqs):
-                if r.done:
-                    continue
-                self._m["occupied"].inc()
-                t = self._pick(lg[i, -1], r)
-                r.out_tokens.append(t)
-                self._m["tokens_out"].inc()
-                cur[i, 0] = t
-                if (t == self._effective_eos(r)
-                        or len(r.out_tokens) >= r.max_new_tokens):
-                    r.done = True
+            with obs_trace.span("engine.sample",
+                                active=sum(not r.done for r in reqs)) as sp:
+                for i, r in enumerate(reqs):
+                    if r.done:
+                        continue
+                    self._m["occupied"].inc()
+                    t = self._pick(lg[i, -1], r)
+                    r.out_tokens.append(t)
+                    self._m["tokens_out"].inc()
+                    cur[i, 0] = t
+                    if (t == self._effective_eos(r)
+                            or len(r.out_tokens) >= r.max_new_tokens):
+                        r.done = True
+            self._m["sample_time"].inc(sp.seconds)
         return self._finish_batch(reqs)
 
     def _finish_batch(self, reqs: List[Request]) -> List[Request]:

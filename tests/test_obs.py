@@ -1,13 +1,16 @@
 """repro.obs contracts: tracer span nesting + thread-safety, Chrome
-trace-event schema validity, disabled-mode no-op, histogram percentile
+trace-event schema validity, disabled-mode no-op, spans in the profiler's
+trace (the engine's phase spans and their counters), histogram percentile
 correctness vs numpy, registry in-place reset, serve-engine stats parity
 (registry-backed ``stats`` keeps the legacy keys), request-lifecycle trace
 lanes, monotonic request timestamps, and the bench_snapshot compare gate."""
 import dataclasses
+import glob
 import importlib.util
 import json
 import os
 import threading
+import time
 
 import jax
 import numpy as np
@@ -53,10 +56,10 @@ def test_complete_replays_recorded_stamps():
 
 def test_disabled_mode_is_noop():
     tr = obs_trace.Tracer(enabled=False)
-    # shared null span: identity proves no per-call allocation
-    assert tr.span("x") is tr.span("y") is obs_trace._NULL_SPAN
+    # the Chrome sink records nothing; the span still times its block
     with tr.span("x", a=1) as sp:
         sp.set(b=2)
+    assert sp.t1 >= sp.t0 > 0.0 and sp.seconds == sp.t1 - sp.t0
     tr.begin("x")
     tr.end("x")
     tr.complete("x", 0.0, 1.0)
@@ -144,6 +147,63 @@ def test_next_lane_unique():
     lanes = {obs_trace.next_lane() for _ in range(100)}
     assert len(lanes) == 100
     assert all(l >= obs_trace._LANE_BASE for l in lanes)
+
+
+def _profile(tmp_path, fn):
+    """Run ``fn`` under a jax.profiler session; the host events of the
+    trace as (plane, line index, name, start_ns, end_ns, stats) tuples."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for k, line in enumerate(plane.lines):
+                out += [(plane.name, k, ev.name, ev.start_ns,
+                         ev.start_ns + ev.duration_ns, dict(ev.stats))
+                        for ev in line.events]
+    return out
+
+
+def test_spans_reach_the_profiler_trace(tmp_path):
+    """With the Chrome sink off, spans (and traced calls) land in the
+    profiler's trace with their attributes, set() ones included."""
+    tr = obs_trace.Tracer(enabled=False)
+
+    @obs_trace.traced("work")
+    def work():
+        return jax.numpy.ones(4).block_until_ready()
+
+    def body():
+        with tr.span("outer", uid=7) as sp:
+            with tr.span("inner", cfg={"a": 1}):
+                work()
+            sp.set(result="ok")
+
+    ev = {e[2]: e for e in _profile(tmp_path, body)}
+    assert {"outer", "inner", "work"} <= set(ev)
+    assert ev["outer"][5] == {"uid": 7, "result": "ok"}
+    assert ev["inner"][5] == {"cfg": "{'a': 1}"}
+    o, i, w = ev["outer"], ev["inner"], ev["work"]
+    assert o[:2] == i[:2] == w[:2]                 # one plane and line
+    assert o[3] <= i[3] <= w[3] <= w[4] <= i[4] <= o[4]
+    assert tr.events() == []
+
+
+def test_span_perf_counter_pair_feeds_chrome_stamps():
+    tr = obs_trace.Tracer(enabled=True)
+    with tr.span("s") as sp:
+        time.sleep(0.002)
+    b, e = tr.events()
+    assert sp.seconds >= 0.002
+    assert b["ts"] == pytest.approx((sp.t0 - tr._t0) * 1e6)
+    assert e["ts"] == pytest.approx((sp.t1 - tr._t0) * 1e6)
 
 
 # --------------------------------------------------------------- metrics ---
@@ -344,6 +404,81 @@ def test_engine_trace_lanes(engine_setup, tmp_path):
         # lifecycle sub-spans present on the lane
         assert {"queue_wait", "prefill", "generate"} <= {
             e["name"] for e in seq}
+
+
+PAGED = dict(max_batch=2, max_len=32, kv_layout="paged", kv_block_size=8)
+ENGINE_SPANS = {"engine.drain", "engine.arena_init", "engine.admit",
+                "engine.prefill", "engine.upload", "engine.decode_round",
+                "engine.sample"}
+
+
+def test_engine_spans_in_profiler_trace(engine_setup, tmp_path):
+    """A 3-request paged drain under the profiler: every phase span of the
+    continuous loop is on the host plane, nested in engine.drain, with
+    engine.prefill inside engine.admit."""
+    cfg, params = engine_setup
+    ev = _profile(tmp_path, lambda: _drain(cfg, params, n_req=3, **PAGED))
+    spans = [e for e in ev if e[2].startswith("engine.")]
+    assert ENGINE_SPANS <= {e[2] for e in spans}
+    assert {"engine.prefix_lookup", "engine.block_alloc",
+            "engine.block_free"}.isdisjoint(e[2] for e in ev)
+    (drain,) = [e for e in spans if e[2] == "engine.drain"]
+    for e in spans:
+        assert e[:2] == drain[:2]                  # the drain's thread
+        assert drain[3] <= e[3] <= e[4] <= drain[4]
+    admits = [e for e in spans if e[2] == "engine.admit"]
+    assert sorted(e[5]["uid"] for e in admits) == [0, 1, 2]
+    assert all(e[5]["result"] == "ok" and e[5]["plen"] == 4
+               and e[5]["new_blocks"] == 1 for e in admits)
+    for p in (e for e in spans if e[2] == "engine.prefill"):
+        assert any(a[3] <= p[3] <= p[4] <= a[4] for a in admits)
+    # phases never overlap each other: they tile the loop
+    phases = sorted((e[3], e[4]) for e in spans if e[2] in (
+        "engine.arena_init", "engine.admit", "engine.upload",
+        "engine.decode_round", "engine.sample"))
+    assert all(a[1] <= b[0] for a, b in zip(phases, phases[1:]))
+
+
+PHASE_COUNTERS = ("serve.admit_time_s", "serve.upload_time_s",
+                  "serve.sample_time_s", "serve.decode_time_s")
+
+
+@pytest.mark.parametrize("layout", ["paged", "contiguous"])
+def test_engine_phase_counters_fit_the_drain(engine_setup, layout):
+    """The phase counters sum to no more than the drain's wall time, and
+    they add up across drains (the engine's registry, not per drain)."""
+    cfg, params = engine_setup
+    kw = dict(PAGED, kv_layout=layout)
+    eng = Engine(cfg, params, ServeConfig(**kw))
+    rng = np.random.default_rng(0)
+    seen, uid = [], 0
+    for _ in range(2):
+        for _ in range(3):
+            eng.submit(Request(uid=uid, prompt=rng.integers(
+                0, 64, (4,)).astype(np.int32), max_new_tokens=3))
+            uid += 1
+        t0 = time.perf_counter()
+        eng.run_until_drained()
+        wall = time.perf_counter() - t0
+        vals = [eng.metrics.counter(k).value for k in PHASE_COUNTERS]
+        prev = seen[-1] if seen else [0.0] * len(vals)
+        assert all(v > p for v, p in zip(vals, prev))
+        assert sum(vals) - sum(prev) <= wall
+        seen.append(vals)
+    if layout == "contiguous":   # only the post-sample len upload
+        assert seen[1][1] > seen[0][1] > 0.0
+
+
+def test_static_scheduler_sample_span(engine_setup, tmp_path):
+    cfg, params = engine_setup
+    box = {}
+    ev = _profile(tmp_path, lambda: box.setdefault("eng", _drain(
+        cfg, params, n_req=2, max_batch=2, max_len=32,
+        scheduler="static")[0]))
+    samples = [e for e in ev if e[2] == "engine.sample"]
+    # the prefill's picks, then one per decode round
+    assert len(samples) == 1 + box["eng"].stats["decode_steps"]
+    assert box["eng"].metrics.counter("serve.sample_time_s").value > 0.0
 
 
 def _cnn_plan():
