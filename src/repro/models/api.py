@@ -210,7 +210,10 @@ def init_paged_cache(cfg: ModelConfig, batch: int, num_blocks: int,
                      kv: str = "float"):
     """A paged decode cache: K/V pools + per-slot block tables + lengths.
 
-    ``kv="int8"`` stores pool pages as int8 codes plus per-(position, head)
+    Float pools are (L, NB, bs, Hkv * D): one page of one layer is one
+    contiguous (bs, Hkv * D) tile, which the paged attention kernel reads
+    where it lies (``repro.kernels.paged_attention``). ``kv="int8"``
+    stores pool pages as int8 codes plus per-(position, head)
     f32 scale pools (``k_scale``/``v_scale``, (L, NB, bs, Hkv)) — the same
     per-token quantization as the contiguous int8 slot cache, so gathered
     pages dequantize bit-identically. Attention-family dense caches only;
@@ -238,7 +241,8 @@ def init_paged_cache(cfg: ModelConfig, batch: int, num_blocks: int,
                  "k_scale": jnp.ones(shape[:-1], jnp.float32),
                  "v_scale": jnp.ones(shape[:-1], jnp.float32)}
     else:
-        cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        flat = (L, num_blocks, block_size, hkv * dh)
+        cache = {"k": jnp.zeros(flat, dtype), "v": jnp.zeros(flat, dtype)}
     cache["block_table"] = jnp.zeros((batch, max_len // block_size),
                                      jnp.int32)
     cache["len"] = jnp.zeros((batch,), jnp.int32)
@@ -261,7 +265,7 @@ def _scatter_pages(live: dict, key: str, seg, ids):
         seg = seg.astype(live[key].dtype)
         L = seg.shape[0]
         out[key] = live[key].at[:, ids].set(
-            seg.reshape((L, n, bs) + seg.shape[2:]))
+            seg.reshape((L, n, bs) + live[key].shape[3:]))
     return out
 
 
@@ -317,7 +321,7 @@ def paged_write_kv(live: dict, k_new, v_new, block_ids, *,
 
 
 def paged_gather_prefix(live: dict, block_ids):
-    """Gather pages ``block_ids`` into contiguous (L, 1, n*bs, Hkv, D)
+    """Gather pages ``block_ids`` into contiguous (L, 1, n*bs, Hkv * D)
     prefix K/V for ``prefill_suffix_fn``. Float pools only: a dequantized
     int8 prefix is not the float prefix the donor computed, so int8 prefix
     hits recompute (storage-only sharing) instead of chaining."""
@@ -328,7 +332,7 @@ def paged_gather_prefix(live: dict, block_ids):
     ids = jnp.asarray(block_ids, jnp.int32)
     outs = []
     for key in ("k", "v"):
-        g = live[key][:, ids]                           # (L, n, bs, Hkv, D)
+        g = live[key][:, ids]                           # (L, n, bs, Hkv*D)
         L = g.shape[0]
         outs.append(g.reshape((L, 1, g.shape[1] * g.shape[2]) + g.shape[3:]))
     return tuple(outs)

@@ -235,7 +235,10 @@ def decode_attention_q8(q, k_cache, v_cache, k_scale, v_scale, cache_len):
 # at the reserved garbage block 0, whose positions are >= cache_len and
 # therefore masked to an exact-zero softmax weight) — so the paged decode
 # variants below are bit-identical to their contiguous counterparts by
-# construction, provided block_size divides max_len.
+# construction, provided block_size divides max_len. The int8 pools decode
+# through them; float pools decode through the paged attention kernel
+# (repro.kernels.paged_attention), which reads only the pages below each
+# row's length and is tested against decode_attention_paged.
 
 def gather_kv_blocks(pool, block_table):
     """pool: (NB, bs, ...); block_table: (B, nb) int32 -> (B, nb*bs, ...).
@@ -249,7 +252,8 @@ def gather_kv_blocks(pool, block_table):
 def decode_attention_paged(q, k_pool, v_pool, block_table, cache_len):
     """:func:`decode_attention` over a paged pool: per-layer pools are
     (NB, bs, Hkv, D); the gather-by-block-table view is bit-identical to the
-    contiguous cache, so so is the attention output."""
+    contiguous cache, so so is the attention output. The reference the
+    paged attention kernel is tested against."""
     k = gather_kv_blocks(k_pool, block_table)
     v = gather_kv_blocks(v_pool, block_table)
     return decode_attention(q, k, v, cache_len)

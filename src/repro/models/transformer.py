@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
+from repro.kernels.paged_attention import paged_attention
 from repro.parallel.sharding import constrain
 
 from . import attention as A
@@ -255,22 +256,20 @@ def attn_decode(lp, x, cfg: ModelConfig, cdt, k_cache, v_cache, cache_len,
 
 
 def attn_decode_paged(lp, x, cfg: ModelConfig, cdt, k_pool, v_pool,
-                      block_table, cache_len, *, scale_pools=None):
-    """One decode step against a paged KV pool (one layer's pools:
-    (NB, bs, Hkv, D); ``block_table`` (B, nb) int32; ``cache_len`` (B,)).
+                      block_table, cache_len, *, scale_pools):
+    """One decode step against an int8 paged KV pool (one layer's pools:
+    codes (NB, bs, Hkv, D), per-(position, head) f32 scale pools
+    ``scale_pools=(k_scale_pool, v_scale_pool)`` (NB, bs, Hkv);
+    ``block_table`` (B, nb) int32; ``cache_len`` (B,)).
 
-    Row i writes its new K/V into the pool page holding its own position —
-    page ``block_table[i, cache_len[i] // bs]``, offset ``cache_len[i] %
-    bs`` — then attends the gather-by-block-table view, which is
-    bit-identical to the contiguous cache (attention.gather_kv_blocks).
-    Retired rows (length 0, zeroed table row) write into the reserved
-    garbage page 0, which no live table references.
-
-    ``scale_pools=(k_scale_pool, v_scale_pool)`` marks an int8 pool (codes
-    in ``k_pool``/``v_pool``, per-(position, head) f32 scale pools
-    (NB, bs, Hkv)): the new K/V is quantized on write at its own page slot
-    and the pool is dequantized on read. Returns ``(out, k_pool, v_pool
-    [, k_scale_pool, v_scale_pool])``.
+    Row i quantizes its new K/V on write into the pool page holding its own
+    position — page ``block_table[i, cache_len[i] // bs]``, offset
+    ``cache_len[i] % bs`` — then attends the dequantized
+    gather-by-block-table view, which is bit-identical to the contiguous
+    cache (attention.gather_kv_blocks). Retired rows (length 0, zeroed
+    table row) write into the reserved garbage page 0, which no live table
+    references. Returns ``(out, k_pool, v_pool, k_scale_pool,
+    v_scale_pool)``.
     """
     b = x.shape[0]
     bs = k_pool.shape[1]
@@ -279,24 +278,44 @@ def attn_decode_paged(lp, x, cfg: ModelConfig, cdt, k_pool, v_pool,
     q, k, v = _qkv(lp, x, cfg, cdt, positions)
     bi = jnp.take_along_axis(block_table, (cl // bs)[:, None], axis=1)[:, 0]
     off = cl % bs
-    kv8 = scale_pools is not None
-    if kv8:
-        k_scale_pool, v_scale_pool = scale_pools
-        k, ks_new = A.quantize_kv(k)          # (B,1,Hkv,D) int8, (B,1,Hkv) f32
-        v, vs_new = A.quantize_kv(v)
-        k_scale_pool = k_scale_pool.at[bi, off].set(ks_new[:, 0])
-        v_scale_pool = v_scale_pool.at[bi, off].set(vs_new[:, 0])
+    k_scale_pool, v_scale_pool = scale_pools
+    k, ks_new = A.quantize_kv(k)          # (B,1,Hkv,D) int8, (B,1,Hkv) f32
+    v, vs_new = A.quantize_kv(v)
+    k_scale_pool = k_scale_pool.at[bi, off].set(ks_new[:, 0])
+    v_scale_pool = v_scale_pool.at[bi, off].set(vs_new[:, 0])
     k_pool = k_pool.at[bi, off].set(k[:, 0].astype(k_pool.dtype))
     v_pool = v_pool.at[bi, off].set(v[:, 0].astype(v_pool.dtype))
-    if kv8:
-        o = A.decode_attention_paged_q8(q, k_pool, v_pool, k_scale_pool,
-                                        v_scale_pool, block_table, cl + 1)
-    else:
-        o = A.decode_attention_paged(q, k_pool, v_pool, block_table, cl + 1)
+    o = A.decode_attention_paged_q8(q, k_pool, v_pool, k_scale_pool,
+                                    v_scale_pool, block_table, cl + 1)
     out = o.reshape(b, 1, cfg.n_heads * cfg.head_dim) @ lp["wo"].astype(cdt)
-    if kv8:
-        return out, k_pool, v_pool, k_scale_pool, v_scale_pool
-    return out, k_pool, v_pool
+    return out, k_pool, v_pool, k_scale_pool, v_scale_pool
+
+
+def attn_decode_pages(lp, x, cfg: ModelConfig, cdt, k_pools, v_pools, layer,
+                      block_table, cache_len):
+    """One decode step of layer ``layer`` against the stacked float pools
+    (L, NB, bs, Hkv * D), read and written where they lie.
+
+    Row i writes its new K/V at ``[layer, block_table[i, cache_len[i] //
+    bs], cache_len[i] % bs]``, one scatter into the pools, then the paged
+    attention kernel reads the pages that hold row i's ``cache_len[i] + 1``
+    positions and no other. Retired rows (length 0, zeroed table row)
+    write into the reserved garbage page 0, which no live table references.
+    Returns ``(out, k_pools, v_pools)``.
+    """
+    b = x.shape[0]
+    bs = k_pools.shape[2]
+    cl = jnp.asarray(cache_len)
+    q, k, v = _qkv(lp, x, cfg, cdt, cl[:, None].astype(jnp.int32))
+    bi = jnp.take_along_axis(block_table, (cl // bs)[:, None], axis=1)[:, 0]
+    off = cl % bs
+    k_pools = k_pools.at[layer, bi, off].set(
+        k.reshape(b, -1).astype(k_pools.dtype))
+    v_pools = v_pools.at[layer, bi, off].set(
+        v.reshape(b, -1).astype(v_pools.dtype))
+    o = paged_attention(q[:, 0], k_pools, v_pools, layer, block_table, cl + 1)
+    out = o.reshape(b, 1, cfg.n_heads * cfg.head_dim) @ lp["wo"].astype(cdt)
+    return out, k_pools, v_pools
 
 
 def _sp_decode(q, k_cache, v_cache, n_valid, axis: str):
@@ -556,9 +575,10 @@ def decode_step(params, token, cache, cfg: ModelConfig, *,
                       cache["conv"], cache["ssm"]))
         new_cache.update(k=k_new, v=v_new, conv=conv_new, ssm=ssm_new)
     elif "block_table" in cache:
-        # paged pools: (L, NB, bs, Hkv, D) [+ (L, NB, bs, Hkv) scales];
-        # the (B, nb) block table is shared by every layer (same logical
-        # layout, per-layer pools indexed by the same page ids)
+        # paged pools: float (L, NB, bs, Hkv*D), or int8 (L, NB, bs, Hkv, D)
+        # + (L, NB, bs, Hkv) scales; the (B, nb) block table is shared by
+        # every layer (same logical layout, per-layer pools indexed by the
+        # same page ids)
         bt = cache["block_table"]
         if sp_axis is not None:
             raise NotImplementedError("paged KV decode: sequence-parallel "
@@ -579,17 +599,22 @@ def decode_step(params, token, cache, cfg: ModelConfig, *,
                           cache["k_scale"], cache["v_scale"]))
             new_cache.update(k=k_new, v=v_new, k_scale=ks_new, v_scale=vs_new)
         else:
-            def body(hh, xs):
-                lp, kp, vp = xs
+            # the stacked pools ride in the carry, never sliced per layer:
+            # each layer scatters its new K/V into them and the kernel
+            # reads its pages at the layer index
+            def body(carry, xs):
+                hh, kp, vp = carry
+                lp, layer = xs
                 x = rmsnorm(hh, lp["ln1"], cfg.norm_eps)
-                a, kp, vp = attn_decode_paged(lp["attn"], x, cfg, cdt,
-                                              kp, vp, bt, clen)
+                a, kp, vp = attn_decode_pages(lp["attn"], x, cfg, cdt,
+                                              kp, vp, layer, bt, clen)
                 hh = hh + a
                 f = ffn_forward(lp, rmsnorm(hh, lp["ln2"], cfg.norm_eps),
                                 cfg, cdt, precision=precision)
-                return hh + f, (kp, vp)
-            h, (k_new, v_new) = lax.scan(
-                body, h, (params["layers"], cache["k"], cache["v"]))
+                return (hh + f, kp, vp), None
+            (h, k_new, v_new), _ = lax.scan(
+                body, (h, cache["k"], cache["v"]),
+                (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
             new_cache.update(k=k_new, v=v_new)
     elif kv8:
         def body(hh, xs):
@@ -785,7 +810,8 @@ def prefill_suffix(params, tokens, prefix_k, prefix_v, prefix_len: int,
     so the returned logits and suffix K/V are bitwise what a full prefill
     of the whole prompt would have produced (tested in test_paged.py).
 
-    tokens: (B, S_sfx) int32; prefix_k/v: (L, B, prefix_len, Hkv, D) in the
+    tokens: (B, S_sfx) int32; prefix_k/v: (L, B, prefix_len, Hkv, D), or
+    (L, B, prefix_len, Hkv * D) as a float paged pool holds them, in the
     compute dtype; suffix_lens: (B,) int32 real suffix lengths. Returns
     ``(last_logits, k_sfx, v_sfx)`` with k/v_sfx (L, B, S_sfx, Hkv, D) —
     the caller scatters them into pool pages. Attention families with
@@ -803,6 +829,9 @@ def prefill_suffix(params, tokens, prefix_k, prefix_v, prefix_len: int,
         x = rmsnorm(hh, lp["ln1"], cfg.norm_eps)
         positions = prefix_len + jnp.arange(s)[None, :]
         q, k, v = _qkv(lp["attn"], x, cfg, cdt, positions)
+        # paged pools hand the prefix over with the heads flattened
+        pk = pk.reshape(pk.shape[:2] + k.shape[2:])
+        pv = pv.reshape(pv.shape[:2] + v.shape[2:])
         k_cat = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
         v_cat = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
         o = A.attention(q, k_cat, v_cat, causal=True, impl=attn_impl,

@@ -69,10 +69,14 @@ copy and the first pick), ``engine.upload`` (page growth and the
 ``engine.sample`` (the logits copy, the per-row picks, retirement). The
 counters ``serve.admit_time_s``, ``serve.upload_time_s``,
 ``serve.sample_time_s`` and ``serve.decode_time_s`` add up the
-``perf_counter`` time of their spans. With ``REPRO_TRACE=1`` the same
-spans and per-request lifecycle lanes (queue_wait -> prefill -> generate,
-each request on its own lane) also go to the Chrome export — write it
-with ``repro.obs.trace.export(path)`` and open it in Perfetto.
+``perf_counter`` time of their spans. Under the paged layout,
+``engine.decode_round`` carries ``pages``, the KV pages per layer the
+round's rows hold (each row's new position included), which the paged
+attention reads; ``serve.kv_pages_read`` adds them up. With
+``REPRO_TRACE=1`` the same spans and per-request lifecycle lanes
+(queue_wait -> prefill -> generate, each request on its own lane) also go
+to the Chrome export — write it with ``repro.obs.trace.export(path)`` and
+open it in Perfetto.
 
 Timing discipline: decode-round timers ``jax.block_until_ready`` the round
 outputs before stopping, so ``decode_tok_s`` measures real device time and
@@ -613,6 +617,9 @@ class Engine:
         self._m = {
             "prefills": self.metrics.counter("serve.prefills"),
             "decode_steps": self.metrics.counter("serve.decode_steps"),
+            # KV pages per layer the paged decode rounds read (the pages
+            # each active row holds, its new position included)
+            "kv_pages_read": self.metrics.counter("serve.kv_pages_read"),
             "tokens_out": self.metrics.counter("serve.tokens_out"),
             "requests_done": self.metrics.counter("serve.requests_done"),
             "occupied": self.metrics.counter("serve.occupied_slot_rounds"),
@@ -1226,7 +1233,10 @@ class Engine:
                 t0 = time.perf_counter()
                 with obs_trace.span("engine.decode_round",
                                     round=self._round,
-                                    active=len(active)):
+                                    active=len(active)) as sp:
+                    if paged:
+                        pages = sum(lens[i] // bs + 1 for i in active)
+                        sp.set(pages=pages)
                     logits, cache = self.decode(self.params,
                                                 jnp.asarray(cur), cache)
                     # block on BOTH outputs before stopping the timer:
@@ -1235,6 +1245,8 @@ class Engine:
                     # async dispatch
                     jax.block_until_ready((logits, cache))
                 self._m["decode_time"].inc(time.perf_counter() - t0)
+                if paged:
+                    self._m["kv_pages_read"].inc(pages)
             except Exception as e:
                 retriable = isinstance(e, faults.InjectedFault)
                 decode_failures += 1

@@ -109,6 +109,23 @@ def test_paged_single_request_vs_contiguous(dense_setup):
     assert streams(got) == streams(ref)
 
 
+def test_kv_pages_read_counts_the_pages_rows_hold(dense_setup):
+    """``serve.kv_pages_read`` adds, per decode round, the pages each
+    active row holds with its new position: a 7-token prompt decoding 11
+    tokens at 16-position pages holds one page at lengths 7..15 (9 rounds)
+    and two at 16 and 17 (2 rounds); a second row of 20 tokens decoding 2
+    holds two pages in both of its rounds. The contiguous layout reads no
+    pages."""
+    cfg, params = dense_setup
+    reqs = [make_req(0, plen=7, max_new=12), make_req(1, plen=20, max_new=3)]
+    eng, _ = drain(cfg, params, [dataclasses.replace(r) for r in reqs],
+                   max_batch=2, max_len=32, kv_layout="paged",
+                   kv_block_size=16)
+    assert eng.metrics.counter("serve.kv_pages_read").value == 9 + 4 + 4
+    eng, _ = drain(cfg, params, reqs, max_batch=2, max_len=32)
+    assert eng.metrics.counter("serve.kv_pages_read").value == 0
+
+
 # ------------------------------------------------------------ prefix reuse
 
 

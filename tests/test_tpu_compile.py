@@ -2,7 +2,8 @@
 
 Each case compiles one kernel with ``interpret=False`` for a described (not
 attached) v5e chip, at the shapes the paper's CNN (default ``CNNConfig``,
-batch 8) and qwen2-0.5b's FFN (896 x 4864) serve, under the schedule the
+batch 8), qwen2-0.5b's FFN (896 x 4864) and its paged decode attention (64
+slots, 8193 pages of 16 x 128 bf16 per layer) serve, under the schedule the
 tuner resolves for that shape. The chip's compiler then refuses what
 interpret mode accepts: unaligned blocks, unsupported vector ops, VMEM
 overruns. Nothing runs; results are checked by the interpret-mode tests.
@@ -11,6 +12,7 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU compiler library at a time.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +26,7 @@ from repro.kernels.conv_dw import depthwise2d
 from repro.kernels.conv_im2col import conv2d_im2col
 from repro.kernels.conv_shift import shift_conv2d
 from repro.kernels.matmul_q8 import matmul
+from repro.kernels.paged_attention import paged_attention
 from repro.kernels.pool import maxpool2d
 
 N = 8                                   # CNNEngine's max_batch bucket
@@ -115,6 +118,18 @@ def _mm(m, k, n, w4=False):
             [((m, k), I8), ((k, n), I8)])
 
 
+# the qwen2-0.5b bf16 cell: 24 layers, 8193 pages of 16 positions x
+# (2 kv heads x 64), 64 slots of up to 128 pages, 14 query heads
+POOL = (24, 8193, 16, 128)
+
+
+def _paged_attention():
+    return (lambda q, k, v, layer, table, lens: paged_attention(
+        q, k, v, layer, table, lens, interpret=False),
+        [((64, 14, 64), jnp.bfloat16), (POOL, jnp.bfloat16),
+         (POOL, jnp.bfloat16), ((), I32), ((64, 128), I32), ((64,), I32)])
+
+
 CASES = {
     "conv2d_int8_stem": lambda: _conv(32, 3, 16),
     "conv2d_int8": lambda: _conv(16, 16, 32),
@@ -129,6 +144,7 @@ CASES = {
     "matmul_q8_ffn_up": lambda: _mm(N, 896, 4864),
     "matmul_q8_ffn_down": lambda: _mm(N, 4864, 896),
     "matmul_w4_ffn_up": lambda: _mm(N, 896, 4864, w4=True),
+    "paged_attention_decode": _paged_attention,
 }
 
 
@@ -137,3 +153,36 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     fn, shapes = CASES[case]()
     hlo = _compiled_hlo(one_chip, fn, *shapes)
     assert "tpu_custom_call" in hlo
+
+
+def test_paged_decode_step_moves_no_pool(one_chip, monkeypatch):
+    """The whole paged decode step of qwen2-0.5b, as the engine jits it
+    (cache donated): the kernel reads the stacked pools in place and the
+    new K/V is scattered into them, so no operation outside the kernel
+    produces a pool-sized (8193-page) or table-sized (8192-page) array
+    except the in-place scatters."""
+    from repro.configs import get_config
+    from repro.models import api
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    cfg = get_config("qwen2-0.5b")
+    put = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = put(api.param_structs(cfg))
+    cache = put(jax.eval_shape(
+        lambda: api.init_paged_cache(cfg, 64, 8193, 16, 2048)))
+    assert cache["k"].shape == POOL
+    tok = jax.ShapeDtypeStruct((64, 1), I32, sharding=one_chip)
+    hlo = jax.jit(api.decode_fn(cfg), donate_argnums=(2,)).lower(
+        params, tok, cache).compile().as_text()
+    assert "paged_attention" in hlo and "tpu_custom_call" in hlo
+    op = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+    big = []
+    for line in hlo.splitlines():
+        m = op.match(line)
+        if m and {"8193", "8192"} & set(m.group(1).split(",")):
+            big.append((m.group(2), "scatter" in line))
+    assert big
+    moves = [kind for kind, scatter in big if not scatter and kind not in
+             ("parameter", "get-tuple-element", "bitcast")]
+    assert not moves, moves
