@@ -1,12 +1,13 @@
-"""Serve-config static checks: enum/range validation and the KV-cache
-budget, computed before any parameter is touched.
+"""Serve-config static checks: enum/range validation, the combinations of
+model family, scheduler, cache layout and precision the engine serves, and
+the KV-cache budget, computed before any parameter is touched.
 
-``Engine.__init__`` already hard-raises on illegal enum combos; this module
-is the same contract as a pure function returning EVERY violation at once
+:func:`unsupported` is THE rule for which combinations the engine serves:
+``Engine.__init__`` raises ``NotImplementedError`` on what it returns, and
+:func:`check_serve_config` returns it with every other violation at once
 (CI and ``scripts/check_plan.py`` want the full list, not the first raise),
-plus the numeric checks the constructor skips: positive batch/length/bucket
-knobs and the resident KV budget ``kv_cache_bytes`` against an optional
-device budget.
+plus the numeric checks: positive batch/length/bucket knobs and the
+resident KV budget ``kv_cache_bytes`` against an optional device budget.
 """
 from __future__ import annotations
 
@@ -42,14 +43,56 @@ def kv_cache_bytes(cfg, scfg) -> int:
     table."""
     width = 1 if scfg.kv_cache == "int8" else \
         _DTYPE_BYTES.get(cfg.compute_dtype, 4)
+    layers = cfg.n_attn_layers
     per_pos = cfg.n_kv_heads * cfg.head_dim * width
     if scfg.kv_cache == "int8":
         per_pos += cfg.n_kv_heads * 4           # f32 scale per (pos, head)
     if getattr(scfg, "kv_layout", "contiguous") == "paged":
         positions = paged_num_blocks(scfg) * scfg.kv_block_size
         table = 4 * scfg.max_batch * (scfg.max_len // scfg.kv_block_size)
-        return cfg.n_layers * 2 * positions * per_pos + table
-    return cfg.n_layers * 2 * scfg.max_batch * scfg.max_len * per_pos
+        return layers * 2 * positions * per_pos + table
+    return layers * 2 * scfg.max_batch * scfg.max_len * per_pos
+
+
+def unsupported(scfg, cfg=None) -> List[str]:
+    """Every combination of scheduler, cache, layout, precision and model
+    the engine does not serve (values the enums do not know are left to
+    :func:`check_serve_config`)."""
+    errs: List[str] = []
+    static = scfg.scheduler == "static"
+    paged = getattr(scfg, "kv_layout", "contiguous") == "paged"
+    if scfg.kv_cache == "int8" and static:
+        errs.append("kv_cache='int8' needs scheduler='continuous' (the "
+                    "static path decodes off the float prefill cache)")
+    if paged and static:
+        errs.append("kv_layout='paged' needs scheduler='continuous' "
+                    "(the static path decodes off the prefill cache)")
+    if cfg is None:
+        return errs
+    recurrent = cfg.family in ("ssm", "hybrid", "encdec")
+    if cfg.family == "encdec" and scfg.scheduler == "continuous":
+        errs.append("continuous batching needs slotted caches; encdec "
+                    "is not slotted — use scheduler='static'")
+    if paged and recurrent:
+        errs.append("kv_layout='paged' covers attention-family dense "
+                    "KV caches only (no ssm / hybrid / encdec)")
+    if scfg.precision in PRECISIONS[1:] and (recurrent
+                                             or cfg.moe is not None):
+        errs.append(f"precision={scfg.precision!r} quantizes dense FFN "
+                    "matmuls; moe/ssm/hybrid/encdec are unsupported")
+    if scfg.kv_cache == "int8" and (recurrent or cfg.layer_types):
+        errs.append("kv_cache='int8' covers attention-family dense KV "
+                    "caches only (no ssm / hybrid / encdec / short "
+                    "convolutions)")
+    if cfg.layer_types and not paged:
+        errs.append("a pattern stack (layer_types) serves through "
+                    "kv_layout='paged' and scheduler='continuous' only")
+    if paged and cfg.layer_types and cfg.n_conv_layers \
+            and getattr(scfg, "prefix_cache", False):
+        errs.append("prefix_cache=True with short-convolution layers: a "
+                    "prefix hit would need the conv state at the page "
+                    "boundary, which is not kept — set prefix_cache=False")
+    return errs
 
 
 def _check_resilience(scfg, errs: List[str]):
@@ -112,18 +155,13 @@ def check_serve_config(scfg, cfg=None, *, hbm_budget: Optional[int] = None,
     if scfg.temperature < 0:
         errs.append(f"temperature must be >= 0, got {scfg.temperature!r}")
     _check_resilience(scfg, errs)
-    if scfg.kv_cache == "int8" and scfg.scheduler != "continuous":
-        errs.append("kv_cache='int8' needs scheduler='continuous' (the "
-                    "static path decodes off the float prefill cache)")
+    errs += unsupported(scfg, cfg)
 
     layout = getattr(scfg, "kv_layout", "contiguous")
     if layout not in KV_LAYOUTS:
         errs.append(f"unknown kv_layout: {layout!r} "
                     f"(choose from {KV_LAYOUTS})")
     elif layout == "paged":
-        if scfg.scheduler != "continuous":
-            errs.append("kv_layout='paged' needs scheduler='continuous' "
-                        "(the static path decodes off the prefill cache)")
         bs = scfg.kv_block_size
         if not isinstance(bs, int) or bs < 1:
             errs.append(f"kv_block_size must be a positive int, got {bs!r}")
@@ -153,21 +191,6 @@ def check_serve_config(scfg, cfg=None, *, hbm_budget: Optional[int] = None,
                     "requests (one page each) — slots can never all fill")
 
     if cfg is not None:
-        if layout == "paged" and cfg.family in ("ssm", "hybrid", "encdec"):
-            errs.append("kv_layout='paged' covers attention-family dense "
-                        "KV caches only (no ssm / hybrid / encdec)")
-        if cfg.family == "encdec" and scfg.scheduler == "continuous":
-            errs.append("continuous batching needs slotted caches; encdec "
-                        "is not slotted — use scheduler='static'")
-        if scfg.precision != "float" and (
-                cfg.family in ("ssm", "hybrid", "encdec")
-                or cfg.moe is not None):
-            errs.append(f"precision={scfg.precision!r} quantizes dense FFN "
-                        "matmuls; moe/ssm/hybrid/encdec are unsupported")
-        if scfg.kv_cache == "int8" and cfg.family in ("ssm", "hybrid",
-                                                      "encdec"):
-            errs.append("kv_cache='int8' covers attention-family dense KV "
-                        "caches only (no ssm / hybrid / encdec)")
         if strict and not cfg.sub_quadratic() and cfg.family != "encdec" \
                 and scfg.prefill_bucket > scfg.max_len:
             errs.append(f"prefill_bucket={scfg.prefill_bucket} exceeds "
@@ -178,7 +201,7 @@ def check_serve_config(scfg, cfg=None, *, hbm_budget: Optional[int] = None,
             if kv > hbm_budget:
                 errs.append(
                     f"resident KV cache needs {kv / 2**20:.1f} MiB "
-                    f"({cfg.n_layers} layers x 2 x {scfg.max_batch} slots "
+                    f"({cfg.n_attn_layers} layers x 2 x {scfg.max_batch} slots "
                     f"x {scfg.max_len} positions), over the "
                     f"{hbm_budget / 2**20:.1f} MiB budget — shrink "
                     "max_batch/max_len or use kv_cache='int8'")

@@ -17,6 +17,11 @@ class MoEConfig:
     capacity_factor: float = 1.25
     dense_residual: bool = False      # arctic: dense FFN in parallel w/ MoE
     every_n_layers: int = 1           # jamba: MoE on every other layer
+    # routing: "softmax" over the top-k logits, or "sigmoid" scores whose
+    # top-k are normalised to sum to 1 (divided by their sum + 1e-6)
+    router: str = "softmax"
+    expert_bias: bool = False         # a per-expert bias picks the top-k,
+    #                                   but does not weight them
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,6 +59,14 @@ class ModelConfig:
     # frontends (vlm/audio): the modality embedder is a stub; inputs arrive
     # as precomputed frame/patch embeddings of this many positions
     frontend_positions: int = 0
+    # per-layer operator pattern (LFM2): "conv", a gated short convolution
+    # of ``conv_kernel`` taps, or "full_attention"; the first n_layers
+    # entries are run. Empty: every layer is attention.
+    layer_types: tuple = ()
+    conv_kernel: int = 0
+    n_dense_layers: int = 0           # leading layers with a dense FFN
+    #                                   before the MoE ones (pattern stacks)
+    qk_norm: bool = False             # RMSNorm of each q / k head before RoPE
     act: str = "silu"
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
@@ -61,6 +74,31 @@ class ModelConfig:
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    def layer_kinds(self) -> tuple:
+        """The operator of each run layer: "conv" or "full_attention"."""
+        if not self.layer_types:
+            return ("full_attention",) * self.n_layers
+        if len(self.layer_types) < self.n_layers:
+            raise ValueError(f"{self.name}: {len(self.layer_types)} "
+                             f"layer_types for {self.n_layers} layers")
+        return tuple(self.layer_types[:self.n_layers])
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers that hold KV (every layer but the short convolutions)."""
+        return self.layer_kinds().count("full_attention")
+
+    @property
+    def n_conv_layers(self) -> int:
+        return self.layer_kinds().count("conv")
+
+    @property
+    def n_moe_layers(self) -> int:
+        """MoE layers of a pattern stack (the layers past n_dense_layers)."""
+        if self.moe is None:
+            return 0
+        return max(self.n_layers - self.n_dense_layers, 0)
 
     def sub_quadratic(self) -> bool:
         return self.family in ("ssm", "hybrid")
@@ -77,6 +115,21 @@ class ModelConfig:
             n_mat = 3 if self.act == "silu" else 2
             return n_mat * d * ff
         total = emb
+        if self.layer_types:
+            conv = 4 * d * d + self.conv_kernel * d
+            norms = 2 * self.head_dim if self.qk_norm else 0
+            moe = self.moe
+            per_moe = 0 if moe is None else (
+                moe.num_experts * ffn_params(moe.d_ff)
+                + d * moe.num_experts + moe.num_experts * moe.expert_bias)
+            kinds = self.layer_kinds()
+            for i, kind in enumerate(kinds):
+                total += (per_attn + norms if kind == "full_attention"
+                          else conv) + 2 * d
+                total += (ffn_params(self.d_ff)
+                          if moe is None or i < self.n_dense_layers
+                          else per_moe)
+            return total + d
         if self.family in ("dense", "vlm", "audio"):
             total += self.n_layers * (per_attn + ffn_params(self.d_ff) + 2 * d)
         elif self.family == "moe":

@@ -1,5 +1,6 @@
 """Depthwise causal conv1d Pallas kernel — the paper's depthwise primitive
-integrated into the LM stack (Mamba / Jamba hot path).
+integrated into the LM stack (Mamba / Jamba, and LFM2's short convolutions,
+whose prefill runs it on the served path).
 
 Mamba's short (K=4) causal conv1d is exactly a depthwise convolution in 1-D,
 so this is the flagship carry-over of the paper's primitive library into the
@@ -79,4 +80,5 @@ def _causal_conv1d(x: jax.Array, w: jax.Array, *, block_l: int = 512,
         out_specs=pl.BlockSpec((1, bl, bc), lambda bi, li, ci: (bi, li, ci)),
         out_shape=jax.ShapeDtypeStruct((b, l, d), x.dtype),
         interpret=interpret,
+        name="conv1d_causal",
     )(xp, xp, w)

@@ -120,6 +120,14 @@ def slot_batch_axes(cfg: ModelConfig) -> dict:
     return {"k": 1, "v": 1}
 
 
+def slot_state_axes(cfg: ModelConfig) -> dict:
+    """Batch axis of every per-slot state leaf a paged cache holds beside
+    its KV pages: a pattern stack's short-convolution state ``conv``
+    (L_conv, B, K - 1, d). Written at admission (:func:`write_slot_state`),
+    carried by decode, cleared at retirement (:func:`cache_free_slot`)."""
+    return {"conv": 1} if cfg.layer_types and cfg.n_conv_layers else {}
+
+
 def init_slot_cache(cfg: ModelConfig, batch: int, max_len: int,
                     dtype=jnp.bfloat16, kv: str = "float"):
     """A batched decode cache with per-slot lengths.
@@ -187,10 +195,28 @@ def cache_write_slot(cfg: ModelConfig, live: dict, new: dict, slot,
     return out
 
 
+def write_slot_state(cfg: ModelConfig, live: dict, new: dict, slot,
+                     src: int = 0) -> dict:
+    """Write row ``src`` of a fresh prefill's per-slot state leaves
+    (:func:`slot_state_axes`) into slot ``slot`` of a paged cache, whose
+    KV goes to pages (:func:`paged_write_prompt`)."""
+    out = dict(live)
+    for key, ax in slot_state_axes(cfg).items():
+        row = jnp.take(new[key], src, axis=ax).astype(live[key].dtype)
+        out[key] = live[key].at[:, slot].set(row)
+    return out
+
+
 def cache_free_slot(live: dict, slot) -> dict:
     """Retire a slot by zeroing its length — the per-slot attention mask
-    makes the stale K/V unreachable, so no data movement is needed."""
-    return dict(live, len=live["len"].at[slot].set(0))
+    makes the stale K/V unreachable, so no data movement is needed. A
+    paged cache's short-convolution state (``conv``) is cleared too: the
+    next admission writes its own, and a stale one never reaches another
+    request."""
+    out = dict(live, len=live["len"].at[slot].set(0))
+    if "conv" in live and "block_table" in live:
+        out["conv"] = live["conv"].at[:, slot].set(0)
+    return out
 
 
 # ----------------------------------------------------------- paged KV pool --
@@ -216,7 +242,10 @@ def init_paged_cache(cfg: ModelConfig, batch: int, num_blocks: int,
     stores pool pages as int8 codes plus per-(position, head)
     f32 scale pools (``k_scale``/``v_scale``, (L, NB, bs, Hkv)) — the same
     per-token quantization as the contiguous int8 slot cache, so gathered
-    pages dequantize bit-identically. Attention-family dense caches only;
+    pages dequantize bit-identically. A pattern stack (LFM2) pools its
+    attention layers only, in their order, and adds its per-slot state
+    (:func:`slot_state_axes`) and ``moe_counts``; its pools are float.
+    Attention-family dense caches only;
     ``block_size`` must divide ``max_len`` (the gathered view then has
     length exactly ``max_len``, which is what makes paged decode attention
     bit-identical to the contiguous path — see attention.gather_kv_blocks).
@@ -233,7 +262,7 @@ def init_paged_cache(cfg: ModelConfig, batch: int, num_blocks: int,
     if num_blocks < 2:
         raise ValueError("init_paged_cache: need >= 2 blocks (page 0 is the "
                          "reserved garbage page)")
-    L, hkv, dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+    L, hkv, dh = cfg.n_attn_layers, cfg.n_kv_heads, cfg.head_dim
     shape = (L, num_blocks, block_size, hkv, dh)
     if kv == "int8":
         cache = {"k": jnp.zeros(shape, jnp.int8),
@@ -243,6 +272,8 @@ def init_paged_cache(cfg: ModelConfig, batch: int, num_blocks: int,
     else:
         flat = (L, num_blocks, block_size, hkv * dh)
         cache = {"k": jnp.zeros(flat, dtype), "v": jnp.zeros(flat, dtype)}
+    if cfg.layer_types:
+        cache.update(T.init_pattern_state(cfg, batch, dtype))
     cache["block_table"] = jnp.zeros((batch, max_len // block_size),
                                      jnp.int32)
     cache["len"] = jnp.zeros((batch,), jnp.int32)

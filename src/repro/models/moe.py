@@ -11,6 +11,12 @@ cumsum over a (tokens, E) one-hot and tokens scatter into a dense
     all_to_all so each model shard computes only its E/nm local experts
     (expert parallelism), and a2a'd back. DP/pod axes stay batch-parallel.
     This is the DeepSpeed-MoE / GShard layout mapped to jax collectives.
+
+A pattern stack (``ModelConfig.layer_types``, LFM2) serves its expert
+layers through ``moe_ffn_dropless`` instead: every routed token is
+computed by the ``moe_experts`` kernel, so a token's result never depends
+on what else is in the batch. Its expert matrices are stored (E, ff, d)
+(``init_moe_dropless``).
 """
 from __future__ import annotations
 
@@ -40,6 +46,21 @@ def init_moe(key, d: int, moe: MoEConfig, act: str, dtype):
     return p
 
 
+def init_moe_dropless(key, d: int, moe: MoEConfig, dtype):
+    """Router (d, E), selection bias (E,) when ``moe.expert_bias``, and the
+    SwiGLU experts stored (E, ff, d), the expert width leading (the layout
+    ``kernels/moe_experts.py`` reads in contiguous chunks)."""
+    ks = jax.random.split(key, 4)
+    e, ff = moe.num_experts, moe.d_ff
+    p = {"router": jax.random.normal(ks[0], (d, e), dtype) * d ** -0.5,
+         "w_gate": jax.random.normal(ks[1], (e, ff, d), dtype) * d ** -0.5,
+         "w_up": jax.random.normal(ks[2], (e, ff, d), dtype) * d ** -0.5,
+         "w_down": jax.random.normal(ks[3], (e, ff, d), dtype) * ff ** -0.5}
+    if moe.expert_bias:
+        p["bias"] = jnp.zeros((e,), dtype)
+    return p
+
+
 def moe_specs(act, prefix_layers=True):
     L = ("layers",) if prefix_layers else ()
     p = {"router": L + ("embed", None),
@@ -53,13 +74,6 @@ def moe_specs(act, prefix_layers=True):
 def _capacity(tokens: int, moe: MoEConfig) -> int:
     c = int(moe.top_k * tokens * moe.capacity_factor / moe.num_experts)
     return max(c, 1)
-
-
-def _route(x2d, router, top_k: int):
-    logits = (x2d.astype(jnp.float32) @ router.astype(jnp.float32))
-    vals, ids = lax.top_k(logits, top_k)                  # (T, k)
-    probs = jax.nn.softmax(vals, axis=-1)                 # normalize over top-k
-    return probs, ids
 
 
 def _dispatch_combine(x2d, probs, ids, expert_fn, num_experts: int, cap: int):
@@ -102,7 +116,7 @@ def moe_ffn_local(x, p, moe: MoEConfig, act: str, cdt):
     """(B, S, d) -> (B, S, d), no collectives."""
     b, s, d = x.shape
     x2 = x.reshape(-1, d)
-    probs, ids = _route(x2, p["router"], moe.top_k)
+    probs, ids = route(x2, p, moe)
     cap = _capacity(x2.shape[0], moe)
     fn = functools.partial(_expert_ffn, p=p, act=act, cdt=cdt)
     y = _dispatch_combine(x2, probs, ids, lambda blk: fn(blk), moe.num_experts, cap)
@@ -149,7 +163,7 @@ def moe_ffn_sharded(x, p, moe: MoEConfig, act: str, cdt, model_axis="model"):
         tloc = t_pad // nm
         j = lax.axis_index(model_axis)
         xj = lax.dynamic_slice_in_dim(t2p, j * tloc, tloc)      # token split (TP->token-parallel)
-        probs, ids = _route(xj, router, moe.top_k)
+        probs, ids = route(xj, {"router": router}, moe)
         cap = _capacity(tloc, moe)
 
         def experts_a2a(blocks):                 # (E, C, d) global experts
@@ -176,6 +190,38 @@ def moe_ffn_sharded(x, p, moe: MoEConfig, act: str, cdt, model_axis="model"):
     # shard_map boundary: a promotion inside would be LICM-hoisted into a
     # full f32 copy of the stacked expert tensors
     return fn(x, p["router"], *[p[k].astype(cdt) for k in expert_keys])
+
+
+def route(x2d, p, moe: MoEConfig):
+    """Top-k experts (T, k) and their weights, in float32.
+
+    ``router="softmax"``: softmax over the top-k logits. ``"sigmoid"``:
+    sigmoid scores, the top-k picked on score + selection bias (when
+    ``expert_bias``), weighted by their own scores normalised over the
+    top-k (+ 1e-6)."""
+    logits = jnp.dot(x2d.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    if moe.router != "sigmoid":
+        vals, ids = lax.top_k(logits, moe.top_k)
+        return jax.nn.softmax(vals, axis=-1), ids
+    scores = jax.nn.sigmoid(logits)
+    pick = scores + p["bias"].astype(jnp.float32) if moe.expert_bias \
+        else scores
+    _, ids = lax.top_k(pick, moe.top_k)
+    w = jnp.take_along_axis(scores, ids, axis=1)
+    return w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6), ids
+
+
+def moe_ffn_dropless(x, p, moe: MoEConfig, cdt):
+    """(B, S, d) -> ((B, S, d), (E,) int32 assignments per expert): every
+    routed token through the ``moe_experts`` kernel, none dropped."""
+    from repro.kernels.moe_experts import moe_experts
+    b, s, d = x.shape
+    x2 = x.reshape(-1, d)
+    w, ids = route(x2, p, moe)
+    y, counts = moe_experts(x2.astype(cdt), ids, w, p["w_gate"].astype(cdt),
+                            p["w_up"].astype(cdt), p["w_down"].astype(cdt))
+    return y.reshape(b, s, d).astype(cdt), counts
 
 
 def moe_ffn(x, p, moe: MoEConfig, act: str, cdt):

@@ -5,6 +5,11 @@ all-gathers happen per scan step so XLA's latency-hiding scheduler can
 overlap them with compute). The hybrid (Jamba) family scans over
 super-blocks of ``attn_period`` sublayers (7 mamba + 1 attention, MoE on
 every other FFN) so the heterogeneous interleave stays scan-friendly.
+
+A config with ``layer_types`` (LFM2) is a *pattern stack*: an irregular
+interleave of gated short convolutions and attention layers, a few leading
+dense FFNs and dropless MoE after. Its layers are a list of per-layer
+parameter dicts, run unrolled (see "pattern stacks" below).
 """
 from __future__ import annotations
 
@@ -24,7 +29,9 @@ from . import attention as A
 from .blocks import cross_entropy, init_mlp, mlp, mlp_specs, rmsnorm, rope
 from .mamba import (init_mamba, mamba_decode_step, mamba_forward,
                     mamba_init_state, mamba_specs)
-from .moe import init_moe, moe_ffn, moe_specs
+from .moe import (init_moe, init_moe_dropless, moe_ffn, moe_ffn_dropless,
+                  moe_specs)
+from .shortconv import init_shortconv, shortconv_forward, shortconv_step
 
 
 def _cdt(cfg: ModelConfig):
@@ -49,6 +56,9 @@ def init_attn(key, cfg: ModelConfig, dtype):
         p["bq"] = jnp.zeros((hq * dh,), dtype)
         p["bk"] = jnp.zeros((hkv * dh,), dtype)
         p["bv"] = jnp.zeros((hkv * dh,), dtype)
+    if cfg.qk_norm:
+        p["q_norm"] = jnp.ones((dh,), dtype)
+        p["k_norm"] = jnp.ones((dh,), dtype)
     return p
 
 
@@ -77,7 +87,9 @@ def init_lm(cfg: ModelConfig, key) -> dict:
         params["unembed"] = jax.random.normal(
             k_out, (cfg.d_model, cfg.vocab), pdt) * (cfg.d_model ** -0.5)
 
-    if cfg.family == "ssm":
+    if cfg.layer_types:
+        params["layers"] = init_pattern_layers(cfg, k_layers, pdt)
+    elif cfg.family == "ssm":
         def one(k):
             km, = jax.random.split(k, 1)
             return {"ln": jnp.ones((cfg.d_model,), pdt),
@@ -173,6 +185,9 @@ def _qkv(lp, x, cfg: ModelConfig, cdt, positions):
     q = constrain(q, "batch", "seq", "heads").reshape(b, s, hq, dh)
     k = constrain(k, "batch", "seq", "kv_heads").reshape(b, s, hkv, dh)
     v = constrain(v, "batch", "seq", "kv_heads").reshape(b, s, hkv, dh)
+    if cfg.qk_norm:
+        q = rmsnorm(q, lp["q_norm"], cfg.norm_eps)
+        k = rmsnorm(k, lp["k_norm"], cfg.norm_eps)
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
     return q, k, v
@@ -441,7 +456,9 @@ def forward_hidden(params, tokens, cfg: ModelConfig, *, embeds=None,
         h = jnp.concatenate([embeds.astype(cdt), h], axis=1)
     h = constrain(h, "batch", "seq", None)
 
-    if cfg.family == "hybrid":
+    if cfg.layer_types:
+        h = pattern_stack(params, h, cfg, cdt, impl=attn_impl)[0]
+    elif cfg.family == "hybrid":
         body = _remat(lambda hh, bp: (hybrid_block(hh, bp, cfg, cdt,
                                                    impl=attn_impl), None), remat)
         h, _ = lax.scan(body, h, params["blocks"])
@@ -481,6 +498,12 @@ def lm_loss(params, batch, cfg: ModelConfig, *, attn_impl="full", remat="full",
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
     hkv, dh = cfg.n_kv_heads, cfg.head_dim
+    if cfg.layer_types:
+        la = cfg.n_attn_layers
+        return {"k": jnp.zeros((la, batch, max_len, hkv, dh), dtype),
+                "v": jnp.zeros((la, batch, max_len, hkv, dh), dtype),
+                **init_pattern_state(cfg, batch, dtype),
+                "len": jnp.zeros((), jnp.int32)}
     if cfg.family == "ssm":
         st = mamba_init_state(cfg.d_model, cfg.mamba, batch)
         return {"conv": jnp.zeros((cfg.n_layers,) + st["conv"].shape, dtype),
@@ -529,7 +552,10 @@ def decode_step(params, token, cache, cfg: ModelConfig, *,
     clen = cache["len"]
     new_cache = dict(cache)
 
-    if cfg.family == "ssm":
+    if cfg.layer_types:
+        h, state = pattern_decode(params, h, cache, cfg, cdt)
+        new_cache.update(state)
+    elif cfg.family == "ssm":
         def body(hh, xs):
             lp, conv, ssm = xs
             x = rmsnorm(hh, lp["ln"], cfg.norm_eps)
@@ -684,12 +710,20 @@ def prefill(params, tokens, cfg: ModelConfig, max_len: int, *, embeds=None,
     cdt = _cdt(cfg)
     b = tokens.shape[0]
     s_prompt = tokens.shape[1] + (0 if embeds is None else embeds.shape[1])
-    cache = init_cache(cfg, b, max_len)
+    # a pattern stack keeps its cache in the compute dtype
+    cache = init_cache(cfg, b, max_len,
+                       cdt if cfg.layer_types else jnp.bfloat16)
     h = embed_tokens(params, tokens, cfg, cdt)
     if embeds is not None:
         h = jnp.concatenate([embeds.astype(cdt), h], axis=1)
 
-    if cfg.family == "ssm":
+    if cfg.layer_types:
+        h, ks, vs, state = pattern_stack(
+            params, h, cfg, cdt, impl=attn_impl, block_k=attn_block_k,
+            lengths=prompt_lens)
+        cache.update(k=_stack_kv(ks, max_len, cache["k"]),
+                     v=_stack_kv(vs, max_len, cache["v"]), **state)
+    elif cfg.family == "ssm":
         def body(hh, lp):
             x = rmsnorm(hh, lp["ln"], cfg.norm_eps)
             y, st = _mamba_forward_with_state(lp["mamba"], x, cfg.mamba, cdt)
@@ -729,6 +763,15 @@ def prefill(params, tokens, cfg: ModelConfig, max_len: int, *, embeds=None,
     cache["len"] = pl
     h_last = jnp.take_along_axis(h, (pl - 1)[:, None, None], axis=1)
     return unembed(params, h_last, cfg), cache
+
+
+def _stack_kv(per_layer, max_len, like):
+    """Attention layers' (B, S, Hkv, D) K or V, padded to ``max_len`` and
+    stacked in layer order (``like``: the empty cache leaf)."""
+    if not per_layer:
+        return like
+    return jnp.stack([_pad_seq(x, max_len) for x in per_layer]).astype(
+        like.dtype)
 
 
 def _pad_seq(x, max_len):
@@ -817,7 +860,7 @@ def prefill_suffix(params, tokens, prefix_k, prefix_v, prefix_len: int,
     the caller scatters them into pool pages. Attention families with
     dense-layer stacks only (the paged engine's admission gate).
     """
-    if cfg.family in ("ssm", "hybrid", "encdec"):
+    if cfg.family in ("ssm", "hybrid", "encdec") or cfg.layer_types:
         raise NotImplementedError(
             "prefill_suffix covers attention-family dense layer stacks only")
     cdt = _cdt(cfg)
@@ -850,3 +893,114 @@ def prefill_suffix(params, tokens, prefix_k, prefix_v, prefix_len: int,
     sl = jnp.asarray(suffix_lens, jnp.int32)
     h_last = jnp.take_along_axis(h, (sl - 1)[:, None, None], axis=1)
     return unembed(params, h_last, cfg), ks, vs
+
+
+# ============================================================ pattern stacks
+#
+# A config with ``layer_types`` (LFM2) runs an unrolled list of per-layer
+# parameter dicts. Each layer is pre-norm with residuals: its operator (a
+# gated short convolution, or GQA attention with per-head q/k RMSNorm),
+# then its FFN, a dense SwiGLU for the first ``n_dense_layers`` and a
+# dropless MoE (``moe_experts`` kernel) after. The decode cache holds KV
+# for the attention layers only, stacked in their order; ``conv``, the
+# short convolutions' states (L_conv, B, K - 1, d); and ``moe_counts``
+# (L_moe, E), the assignments each expert took in the last call, which the
+# engine reads after each round.
+
+
+def init_pattern_layers(cfg: ModelConfig, key, pdt) -> list:
+    d = cfg.d_model
+    layers = []
+    for i, (kind, k) in enumerate(zip(cfg.layer_kinds(),
+                                      jax.random.split(key, cfg.n_layers))):
+        ko, kf = jax.random.split(k)
+        lp = {"ln1": jnp.ones((d,), pdt), "ln2": jnp.ones((d,), pdt)}
+        if kind == "conv":
+            lp["conv"] = init_shortconv(ko, d, cfg.conv_kernel, pdt)
+        else:
+            lp["attn"] = init_attn(ko, cfg, pdt)
+        if cfg.moe is None or i < cfg.n_dense_layers:
+            lp["mlp"] = init_mlp(kf, d, cfg.d_ff, cfg.act, pdt)
+        else:
+            lp["moe"] = init_moe_dropless(kf, d, cfg.moe, pdt)
+        layers.append(lp)
+    return layers
+
+
+def init_pattern_state(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16):
+    """The per-slot state beside KV: ``conv`` (L_conv, B, K - 1, d) and,
+    with experts, ``moe_counts`` (L_moe, E) int32."""
+    out = {"conv": jnp.zeros((cfg.n_conv_layers, batch,
+                              max(cfg.conv_kernel - 1, 0), cfg.d_model),
+                             dtype)}
+    if cfg.n_moe_layers:
+        out["moe_counts"] = jnp.zeros(
+            (cfg.n_moe_layers, cfg.moe.num_experts), jnp.int32)
+    return out
+
+
+def _pattern_ffn(lp, x, cfg: ModelConfig, cdt, counts: list):
+    if "moe" in lp:
+        y, c = moe_ffn_dropless(x, lp["moe"], cfg.moe, cdt)
+        counts.append(c)
+        return y
+    return mlp(x, lp["mlp"], cfg.act, cdt)
+
+
+def _pattern_state(states, counts, like_conv):
+    out = {"conv": jnp.stack(states).astype(like_conv.dtype)
+           if states else like_conv}
+    if counts:
+        out["moe_counts"] = jnp.stack(counts)
+    return out
+
+
+def pattern_stack(params, h, cfg: ModelConfig, cdt, *, impl: str,
+                  block_k: int = 256, lengths=None):
+    """The whole prompt through a pattern stack: returns the last layer's
+    hidden states (before the final norm), each attention layer's K and V,
+    and the state
+    (``conv`` at each row's last ``K - 1`` real positions, ``lengths``
+    (B,) int32 or the full length; ``moe_counts``)."""
+    ks, vs, states, counts = [], [], [], []
+    for kind, lp in zip(cfg.layer_kinds(), params["layers"]):
+        x = rmsnorm(h, lp["ln1"], cfg.norm_eps)
+        if kind == "conv":
+            y, st = shortconv_forward(lp["conv"], x, cdt, lengths)
+            states.append(st)
+        else:
+            y, (k, v) = attn_forward(lp["attn"], x, cfg, cdt, impl=impl,
+                                     block_k=block_k)
+            ks.append(k)
+            vs.append(v)
+        h = h + y
+        h = h + _pattern_ffn(lp, rmsnorm(h, lp["ln2"], cfg.norm_eps), cfg,
+                             cdt, counts)
+    like = init_pattern_state(cfg, h.shape[0], cdt)["conv"]
+    return h, ks, vs, _pattern_state(states, counts, like)
+
+
+def pattern_decode(params, h, cache, cfg: ModelConfig, cdt):
+    """One token through a pattern stack against its paged cache (float
+    pools (L_attn, NB, bs, Hkv * D), read in place by the paged attention
+    kernel). Returns the last layer's hidden state and the cache leaves it
+    changed."""
+    if "block_table" not in cache:
+        raise NotImplementedError("pattern stacks decode from a paged cache")
+    k, v, conv = cache["k"], cache["v"], cache["conv"]
+    states, counts = [], []
+    ia = ic = 0
+    for kind, lp in zip(cfg.layer_kinds(), params["layers"]):
+        x = rmsnorm(h, lp["ln1"], cfg.norm_eps)
+        if kind == "conv":
+            y, st = shortconv_step(lp["conv"], x, conv[ic], cdt)
+            states.append(st)
+            ic += 1
+        else:
+            y, k, v = attn_decode_pages(lp["attn"], x, cfg, cdt, k, v, ia,
+                                        cache["block_table"], cache["len"])
+            ia += 1
+        h = h + y
+        h = h + _pattern_ffn(lp, rmsnorm(h, lp["ln2"], cfg.norm_eps), cfg,
+                             cdt, counts)
+    return h, dict(_pattern_state(states, counts, conv), k=k, v=v)

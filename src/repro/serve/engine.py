@@ -48,7 +48,12 @@ KV backing is picked by ``ServeConfig.kv_layout``:
   exactly the contiguous cache view (``kv_block_size`` divides
   ``max_len``), masked tail positions carry exact-zero attention weight,
   and a prefix page's K/V are bitwise independent of the bucket the donor
-  prefilled under (tests/test_paged.py locks both properties).
+  prefilled under (tests/test_paged.py locks both properties). A pattern
+  stack's short convolutions keep a per-slot state beside the pages
+  (``models/api.slot_state_axes``): written from the prefill at
+  admission, carried by decode, cleared at retirement and preemption (a
+  preempted request's re-prefill rebuilds it); its prefix cache is
+  refused, since a hit would need that state at the page boundary.
 
 ``Engine.stats`` surfaces scheduler metrics: prefill/decode-round/token
 counters, slot occupancy (occupied slot-rounds over offered slot-rounds),
@@ -72,7 +77,12 @@ counters ``serve.admit_time_s``, ``serve.upload_time_s``,
 ``perf_counter`` time of their spans. Under the paged layout,
 ``engine.decode_round`` carries ``pages``, the KV pages per layer the
 round's rows hold (each row's new position included), which the paged
-attention reads; ``serve.kv_pages_read`` adds them up. With
+attention reads; ``serve.kv_pages_read`` adds them up. A pattern stack
+(LFM2) with experts also puts ``moe_experts_hit`` (experts given a token,
+summed over its expert layers) and ``moe_assignments`` on
+``engine.prefill`` and ``engine.decode_round``, read from the call's
+``moe_counts`` after the engine waited on it, and adds them to the
+counters ``serve.moe_experts_hit`` and ``serve.moe_assignments``. With
 ``REPRO_TRACE=1`` the same spans and per-request lifecycle lanes
 (queue_wait -> prefill -> generate, each request on its own lane) also go
 to the Chrome export — write it with ``repro.obs.trace.export(path)`` and
@@ -524,47 +534,15 @@ class ServeConfig:
 
 class Engine:
     def __init__(self, cfg: ModelConfig, params, scfg: ServeConfig):
-        if scfg.scheduler not in ("continuous", "static"):
-            raise ValueError(f"unknown scheduler: {scfg.scheduler!r}")
-        if cfg.family == "encdec" and scfg.scheduler == "continuous":
-            raise NotImplementedError(
-                "continuous batching needs slotted caches; encdec is not "
-                "slotted (models/api.slot_batch_axes) — use scheduler='static'")
-        if scfg.precision not in ("float", "int8", "int8-xla", "w4a8"):
-            raise ValueError(f"unknown precision: {scfg.precision!r}")
-        if scfg.kv_cache not in ("float", "int8"):
-            raise ValueError(f"unknown kv_cache: {scfg.kv_cache!r}")
-        if scfg.kv_cache == "int8":
-            if scfg.scheduler != "continuous":
-                raise NotImplementedError(
-                    "kv_cache='int8' quantizes the resident slot cache; the "
-                    "static scheduler decodes off the float prefill cache — "
-                    "use scheduler='continuous'")
-            if cfg.family in ("ssm", "hybrid", "encdec"):
-                raise NotImplementedError(
-                    "kv_cache='int8' covers attention-family dense KV caches "
-                    "only (no ssm / hybrid / encdec)")
-        if scfg.kv_layout not in ("contiguous", "paged"):
-            raise ValueError(f"unknown kv_layout: {scfg.kv_layout!r}")
-        if scfg.kv_layout == "paged":
-            if scfg.scheduler != "continuous":
-                raise NotImplementedError(
-                    "kv_layout='paged' pages the live slotted decode cache; "
-                    "the static scheduler decodes off the prefill cache — "
-                    "use scheduler='continuous'")
-            if cfg.family in ("ssm", "hybrid", "encdec"):
-                raise NotImplementedError(
-                    "kv_layout='paged' covers attention-family dense KV "
-                    "caches only (no ssm / hybrid / encdec)")
-        if scfg.precision != "float":
-            if cfg.family in ("ssm", "hybrid", "encdec") or cfg.moe is not None:
-                raise NotImplementedError(
-                    "ServeConfig.precision='int8' quantizes dense FFN "
-                    "matmuls; moe/ssm/hybrid/encdec configs are unsupported")
-        # constructor-grade static checks beyond the enum combos above:
-        # positive batch/length/bucket knobs, non-negative temperature
-        # (repro.check.config; scripts/check_plan.py runs the strict set)
-        from repro.check.config import check_serve_config
+        # one place decides what is served (repro.check.config): the
+        # combinations of model, scheduler, cache and precision the engine
+        # does not serve raise NotImplementedError; unknown values, bad
+        # sizes and the like raise ValueError (scripts/check_plan.py runs
+        # the strict set)
+        from repro.check.config import check_serve_config, unsupported
+        no = unsupported(scfg, cfg)
+        if no:
+            raise NotImplementedError("; ".join(no))
         bad = check_serve_config(scfg, cfg, strict=False)
         if bad:
             raise ValueError("invalid ServeConfig:\n"
@@ -594,6 +572,9 @@ class Engine:
             self._write_slot = jax.jit(
                 functools.partial(api.cache_write_slot, cfg),
                 donate_argnums=() if cpu else (0,))
+        # a pattern stack's expert layers count the assignments each expert
+        # took (cache["moe_counts"]), read after each prefill and round
+        self._moe_counts = bool(cfg.layer_types) and cfg.n_moe_layers > 0
         if scfg.kv_layout == "paged":
             # page-granular cache surgery: scatter prefilled K/V into pool
             # pages, gather shared prefix pages back out, and the
@@ -604,6 +585,11 @@ class Engine:
                 donate_argnums=() if cpu else (0,))
             self._write_kv = jax.jit(api.paged_write_kv,
                                      donate_argnums=() if cpu else (0,))
+            # per-slot state beside the pages (short-convolution state)
+            self._write_state = jax.jit(
+                functools.partial(api.write_slot_state, cfg),
+                donate_argnums=() if cpu else (0,)) \
+                if api.slot_state_axes(cfg) else None
             self._gather_prefix = jax.jit(api.paged_gather_prefix)
             if scfg.prefix_cache and scfg.kv_cache == "float":
                 self.prefill_suffix = jax.jit(api.prefill_suffix_fn(
@@ -629,6 +615,10 @@ class Engine:
             "admit_time": self.metrics.counter("serve.admit_time_s"),
             "upload_time": self.metrics.counter("serve.upload_time_s"),
             "sample_time": self.metrics.counter("serve.sample_time_s"),
+            # expert layers (pattern stacks): per call, experts given at
+            # least one token, and (token, expert) assignments, summed
+            "moe_experts_hit": self.metrics.counter("serve.moe_experts_hit"),
+            "moe_assignments": self.metrics.counter("serve.moe_assignments"),
             "ttft": self.metrics.histogram("serve.ttft_s"),
             "tpot": self.metrics.histogram("serve.tpot_s"),
             "queue_wait": self.metrics.histogram("serve.queue_wait_s"),
@@ -700,6 +690,17 @@ class Engine:
         self._m["blocks_free"].set(pool.free_pages)
         self._m["prefix_hit_rate"].set(
             pool.hits / pool.lookups if pool.lookups else 0.0)
+
+    def _count_experts(self, cache: dict, sp):
+        """Add the expert counts of the call that produced ``cache`` (its
+        ``moe_counts``, (L_moe, E) assignments per expert) to the counters
+        and to span ``sp``. A small copy to the host after a call the
+        engine already waited on."""
+        c = np.asarray(cache["moe_counts"])
+        hit, n = int(np.count_nonzero(c)), int(c.sum())
+        self._m["moe_experts_hit"].inc(hit)
+        self._m["moe_assignments"].inc(n)
+        sp.set(moe_experts_hit=hit, moe_assignments=n)
 
     def _observe_retired(self, req: Request):
         """Latency histograms + the request's trace-lane replay (the spans
@@ -927,14 +928,19 @@ class Engine:
                 toks[:plen] = req.prompt
                 with obs_trace.span("engine.prefill", uid=req.uid, slot=i,
                                     plen=plen, bucket=bucket,
-                                    prefix_hit=n_hit * bs):
+                                    prefix_hit=n_hit * bs) as psp:
                     logits, fresh_cache = self.prefill(self.params, {
                         "tokens": jnp.asarray(toks[None, :]),
                         "prompt_lens": jnp.asarray([plen], jnp.int32)})
                     self._m["prefills"].inc()
                     cache = self._write_pages(cache, fresh_cache, fids,
                                               skip_blocks=n_hit)
+                    if self._write_state is not None:
+                        cache = self._write_state(cache, fresh_cache,
+                                                  jnp.int32(i))
                     logits = np.asarray(logits)
+                    if self._moe_counts:
+                        self._count_experts(fresh_cache, psp)
             # publish-at-admission: the fresh full prompt pages now hold
             # their final K/V (decode writes land strictly past them)
             pool.publish(keys[n_hit:], ids[n_hit:len(keys)])
@@ -1244,7 +1250,10 @@ class Engine:
                     # cache update in flight, skewing decode_tok_s by JAX
                     # async dispatch
                     jax.block_until_ready((logits, cache))
-                self._m["decode_time"].inc(time.perf_counter() - t0)
+                    dt = time.perf_counter() - t0
+                    if self._moe_counts:
+                        self._count_experts(cache, sp)
+                self._m["decode_time"].inc(dt)
                 if paged:
                     self._m["kv_pages_read"].inc(pages)
             except Exception as e:

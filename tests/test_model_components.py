@@ -111,10 +111,11 @@ def test_moe_combine_weights_sum_bounded(seed):
     """Each token's combine weights are a softmax subset: output norm is
     bounded by max expert output norm."""
     from repro.configs.base import MoEConfig
-    from repro.models.moe import _route
+    from repro.models.moe import route
     x = jax.random.normal(jax.random.PRNGKey(seed), (16, 8))
     router = jax.random.normal(jax.random.PRNGKey(seed + 1), (8, 4))
-    probs, ids = _route(x, router, 2)
+    probs, ids = route(x, {"router": router},
+                       MoEConfig(num_experts=4, top_k=2, d_ff=8))
     assert probs.shape == (16, 2)
     np.testing.assert_allclose(jnp.sum(probs, -1), 1.0, rtol=1e-5)
     assert int(jnp.max(ids)) < 4

@@ -21,11 +21,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import tune
+from repro.kernels.conv1d_causal import causal_conv1d
 from repro.kernels.conv_add import add_conv2d
 from repro.kernels.conv_dw import depthwise2d
 from repro.kernels.conv_im2col import conv2d_im2col
 from repro.kernels.conv_shift import shift_conv2d
 from repro.kernels.matmul_q8 import matmul
+from repro.kernels.moe_experts import moe_experts
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.pool import maxpool2d
 
@@ -130,6 +132,25 @@ def _paged_attention():
          (POOL, jnp.bfloat16), ((), I32), ((64, 128), I32), ((64,), I32)])
 
 
+# the lfm2-8b-a1b-11l-bf16 cell: 32 experts of (1792, 2048) bf16 matrices,
+# top-4; a decode round routes 64 tokens, a prefill up to a 1024 bucket;
+# short convolutions of 3 taps over 2048 channels
+EXPERTS = (32, 1792, 2048)
+
+
+def _moe_experts(tokens):
+    return (lambda x, ids, w, wg, wu, wd: moe_experts(
+        x, ids, w, wg, wu, wd, interpret=False),
+        [((tokens, 2048), jnp.bfloat16), ((tokens, 4), I32),
+         ((tokens, 4), jnp.float32), (EXPERTS, jnp.bfloat16),
+         (EXPERTS, jnp.bfloat16), (EXPERTS, jnp.bfloat16)])
+
+
+def _conv1d(length):
+    return (lambda x, w: causal_conv1d(x, w, interpret=False),
+            [((1, length, 2048), jnp.bfloat16), ((3, 2048), jnp.bfloat16)])
+
+
 CASES = {
     "conv2d_int8_stem": lambda: _conv(32, 3, 16),
     "conv2d_int8": lambda: _conv(16, 16, 32),
@@ -145,6 +166,10 @@ CASES = {
     "matmul_q8_ffn_down": lambda: _mm(N, 4864, 896),
     "matmul_w4_ffn_up": lambda: _mm(N, 896, 4864, w4=True),
     "paged_attention_decode": _paged_attention,
+    "moe_experts_decode": lambda: _moe_experts(64),
+    "moe_experts_prefill": lambda: _moe_experts(1024),
+    "conv1d_causal_prefill": lambda: _conv1d(1024),
+    "conv1d_causal_short": lambda: _conv1d(16),
 }
 
 
@@ -176,6 +201,41 @@ def test_paged_decode_step_moves_no_pool(one_chip, monkeypatch):
     hlo = jax.jit(api.decode_fn(cfg), donate_argnums=(2,)).lower(
         params, tok, cache).compile().as_text()
     assert "paged_attention" in hlo and "tpu_custom_call" in hlo
+    op = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+    big = []
+    for line in hlo.splitlines():
+        m = op.match(line)
+        if m and {"8193", "8192"} & set(m.group(1).split(",")):
+            big.append((m.group(2), "scatter" in line))
+    assert big
+    moves = [kind for kind, scatter in big if not scatter and kind not in
+             ("parameter", "get-tuple-element", "bitcast")]
+    assert not moves, moves
+
+
+def test_lfm2_paged_decode_step_moves_no_pool(one_chip, monkeypatch):
+    """The whole paged decode step of the lfm2-8b-a1b cell (its 11 layers:
+    8 short convolutions, 3 attention layers over (3, 8193, 16, 512) bf16
+    pools, 9 dropless expert layers), as the engine jits it: the expert
+    layers run the moe_experts kernel, and no operation outside the kernels
+    produces a pool-sized or table-sized array but the in-place scatters."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.models import api
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    cfg = dataclasses.replace(get_config("lfm2-8b-a1b"), n_layers=11)
+    put = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = put(api.param_structs(cfg))
+    cache = put(jax.eval_shape(
+        lambda: api.init_paged_cache(cfg, 64, 8193, 16, 2048)))
+    assert cache["k"].shape == (3, 8193, 16, 512)
+    assert cache["conv"].shape == (8, 64, 2, 2048)
+    tok = jax.ShapeDtypeStruct((64, 1), I32, sharding=one_chip)
+    hlo = jax.jit(api.decode_fn(cfg), donate_argnums=(2,)).lower(
+        params, tok, cache).compile().as_text()
+    assert "paged_attention" in hlo and "moe_experts" in hlo
     op = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
     big = []
     for line in hlo.splitlines():
