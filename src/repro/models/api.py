@@ -88,6 +88,22 @@ def decode_fn(cfg: ModelConfig, *, sp_axis: Optional[str] = None,
                              precision=precision)
 
 
+def decode_pick_fn(cfg: ModelConfig, *, sp_axis: Optional[str] = None,
+                   precision: str = "float"):
+    """:func:`decode_fn` that also picks each row's greedy token on the
+    device: ``(params, token, cache) -> (ids, logits, cache)`` with ``ids``
+    the (B,) int32 argmax of the last position's logits (ties to the first
+    index, as ``np.argmax``). The step and its float logits are unchanged;
+    a server that samples greedily copies back only ``ids``."""
+    step = decode_fn(cfg, sp_axis=sp_axis, precision=precision)
+
+    def fn(params, token, cache):
+        logits, cache = step(params, token, cache)
+        ids = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        return ids, logits, cache
+    return fn
+
+
 def cache_specs(cfg: ModelConfig):
     if cfg.family == "encdec":
         return E.encdec_cache_specs()

@@ -21,7 +21,14 @@ Two schedulers share one ``Engine`` API; ``ServeConfig.scheduler`` picks:
 
 Sampling is greedy argmax by default; a positive temperature (per
 ``ServeConfig`` with ``greedy=False``, or per-``Request`` override) switches
-that request to softmax sampling with the engine's seeded host rng.
+that request to softmax sampling with the engine's seeded host rng. The
+decode call picks every row's greedy token on the device
+(``models/api.decode_pick_fn``) and a round copies back only those (B,)
+ids: ``Engine._pick`` gets, per row, a view that answers ``argmax()`` with
+the device's pick and copies its own logits row to the host only for a
+request that samples with a temperature (counted by
+``serve.host_logit_rows``). Admission's first pick reads the prefill's
+last-position logits on the host.
 
 KV backing is picked by ``ServeConfig.kv_layout``:
 
@@ -71,7 +78,9 @@ built per drain or rebuilt after a failure), ``engine.admit`` (one per
 admission: prefix lookup, page allocation, ``engine.prefill``, the logits
 copy and the first pick), ``engine.upload`` (page growth and the
 ``len`` / block-table uploads), ``engine.decode_round`` and
-``engine.sample`` (the logits copy, the per-row picks, retirement). The
+``engine.sample`` (the ids copy, the per-row picks, retirement;
+``host_rows``, the rows whose logits came to the host, summed by
+``serve.host_logit_rows``). The
 counters ``serve.admit_time_s``, ``serve.upload_time_s``,
 ``serve.sample_time_s`` and ``serve.decode_time_s`` add up the
 ``perf_counter`` time of their spans. Under the paged layout,
@@ -116,7 +125,8 @@ dying. Every request reaches exactly one terminal ``status``:
 Fault seams (``repro.faults``): ``engine.prefill`` fires per admission
 attempt, ``engine.decode_round`` per round attempt, ``blockpool.alloc``
 inside :meth:`BlockPool.alloc`. A ``corrupt`` fault poisons that round's
-host logits; the affected uids are recorded in ``Engine.poisoned_uids``
+host logits (a corrupt round copies every row's logits and picks from
+them); the affected uids are recorded in ``Engine.poisoned_uids``
 (silent corruption is contained, not detected). With no plan active every
 seam is a single global read.
 """
@@ -532,6 +542,33 @@ class ServeConfig:
     retry_backoff_s: float = 0.0
 
 
+@jax.jit
+def _logit_row(logits, i):
+    """Row ``i`` of a decode round's (B, 1, V) logits, last position."""
+    return logits[i, -1]
+
+
+class _DeviceRow:
+    """One row of a decode round's logits, left on the device, as
+    :meth:`Engine._pick` reads it: ``argmax()`` is the token the decode
+    call picked for the row, ``shape`` the row's, ``(V,)``, and
+    ``np.asarray`` copies the row (only the row) to the host and marks it
+    ``fetched``. A greedy pick therefore moves no logits."""
+    __slots__ = ("_logits", "_i", "_id", "shape", "fetched")
+
+    def __init__(self, logits, i: int, token: int):
+        self._logits, self._i, self._id = logits, i, token
+        self.shape = logits.shape[2:]
+        self.fetched = False
+
+    def argmax(self) -> int:
+        return self._id
+
+    def __array__(self, dtype=None, copy=None):
+        self.fetched = True
+        return np.asarray(_logit_row(self._logits, np.int32(self._i)), dtype)
+
+
 class Engine:
     def __init__(self, cfg: ModelConfig, params, scfg: ServeConfig):
         # one place decides what is served (repro.check.config): the
@@ -566,8 +603,11 @@ class Engine:
         # place instead of copying the whole KV budget (CPU backends don't
         # implement donation and would warn on every compile, so skip there)
         cpu = jax.default_backend() == "cpu"
-        self.decode = jax.jit(api.decode_fn(cfg, precision=scfg.precision),
-                              donate_argnums=() if cpu else (2,))
+        # the decode step returns each row's greedy pick beside the logits
+        # and the cache (one jit); a round copies back the (B,) ids only
+        self.decode = jax.jit(
+            api.decode_pick_fn(cfg, precision=scfg.precision),
+            donate_argnums=() if cpu else (2,))
         if cfg.family != "encdec":
             self._write_slot = jax.jit(
                 functools.partial(api.cache_write_slot, cfg),
@@ -615,6 +655,9 @@ class Engine:
             "admit_time": self.metrics.counter("serve.admit_time_s"),
             "upload_time": self.metrics.counter("serve.upload_time_s"),
             "sample_time": self.metrics.counter("serve.sample_time_s"),
+            # decode-round rows whose logits were copied to the host (a
+            # temperature row, or every row of a corrupt-directive round)
+            "host_logit_rows": self.metrics.counter("serve.host_logit_rows"),
             # expert layers (pattern stacks): per call, experts given at
             # least one token, and (token, expert) assignments, summed
             "moe_experts_hit": self.metrics.counter("serve.moe_experts_hit"),
@@ -794,12 +837,17 @@ class Engine:
 
     # ----------------------------------------------------------- sampling --
 
-    def _pick(self, logits_row: np.ndarray, req: Request) -> int:
+    def _pick(self, logits_row, req: Request) -> int:
+        """THE place a served token is chosen. ``logits_row`` is a (V,)
+        numpy row (admission, the static path, a corrupt round) or a
+        decode round's :class:`_DeviceRow`, whose ``argmax()`` is the
+        device's pick and whose logits come to the host only here, for a
+        request that samples."""
         temp = req.temperature
         if temp is None:
             temp = 0.0 if self.scfg.greedy else self.scfg.temperature
         if temp <= 0.0:
-            return int(np.argmax(logits_row))
+            return int(logits_row.argmax())
         z = np.asarray(logits_row, np.float64) / temp
         z -= z.max()
         p = np.exp(z)
@@ -1243,13 +1291,13 @@ class Engine:
                     if paged:
                         pages = sum(lens[i] // bs + 1 for i in active)
                         sp.set(pages=pages)
-                    logits, cache = self.decode(self.params,
-                                                jnp.asarray(cur), cache)
-                    # block on BOTH outputs before stopping the timer:
-                    # asarray alone would sync the logits but leave the
+                    ids, logits, cache = self.decode(
+                        self.params, jnp.asarray(cur), cache)
+                    # block on ALL outputs before stopping the timer:
+                    # asarray alone would sync the ids but leave the
                     # cache update in flight, skewing decode_tok_s by JAX
                     # async dispatch
-                    jax.block_until_ready((logits, cache))
+                    jax.block_until_ready((ids, logits, cache))
                     dt = time.perf_counter() - t0
                     if self._moe_counts:
                         self._count_experts(cache, sp)
@@ -1276,14 +1324,19 @@ class Engine:
                 continue
             decode_failures = 0
             with obs_trace.span("engine.sample", active=len(active)) as sp:
-                logits = np.asarray(logits)
                 if round_fired is not None:
                     # corrupt directive: poison this round's host logits;
                     # every active request sampled from them is contained,
                     # not fixed
-                    logits = round_fired.apply(logits)
+                    rows = round_fired.apply(np.asarray(logits))[:, -1]
                     for i in active:
                         self.poisoned_uids.add(slots[i].uid)
+                else:
+                    # each row's pick was made on the device; a row's
+                    # logits reach the host only if its request samples
+                    ids = np.asarray(ids)
+                    rows = {i: _DeviceRow(logits, i, int(ids[i]))
+                            for i in active}
                 self._round += 1
                 self._m["decode_steps"].inc()
                 self._m["occupied"].inc(len(active))
@@ -1291,13 +1344,17 @@ class Engine:
                 for i in active:
                     lens[i] += 1        # this round wrote K/V at lens[i]
                     req = slots[i]
-                    t = self._pick(logits[i, -1], req)
+                    t = self._pick(rows[i], req)
                     req.out_tokens.append(t)
                     self._m["tokens_out"].inc()
                     cur[i, 0] = t
                     maybe_retire(i)
                     if slots[i] is not None and self._expired(req, now_r):
                         retire_slot(i, "timeout")   # round-boundary cancel
+                host_rows = len(active) if round_fired is not None else \
+                    sum(rows[i].fetched for i in active)
+                sp.set(host_rows=host_rows)
+                self._m["host_logit_rows"].inc(host_rows)
             self._m["sample_time"].inc(sp.seconds)
             # decode advanced every row's length, including retired/empty
             # slots; re-zero them so dead rows can never drift past max_len
@@ -1402,7 +1459,7 @@ class Engine:
                 with obs_trace.span("engine.decode_round",
                                     round=self._round,
                                     active=sum(not r.done for r in reqs)):
-                    logits, new_cache = self.decode(
+                    _, logits, new_cache = self.decode(
                         self.params, jnp.asarray(cur), cache)
                     # sync logits AND cache before stopping the timer (see
                     # the continuous path): decode_tok_s must be device

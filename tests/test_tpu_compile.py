@@ -180,27 +180,38 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     assert "tpu_custom_call" in hlo
 
 
-def test_paged_decode_step_moves_no_pool(one_chip, monkeypatch):
-    """The whole paged decode step of qwen2-0.5b, as the engine jits it
-    (cache donated): the kernel reads the stacked pools in place and the
-    new K/V is scattered into them, so no operation outside the kernel
-    produces a pool-sized (8193-page) or table-sized (8192-page) array
-    except the in-place scatters."""
-    from repro.configs import get_config
+def _engine_decode_hlo(cfg, one_chip):
+    """The paged decode step as the engine jits it at the cells' shapes
+    (64 slots, 8193 pages of 16 positions, max_len 2048): the device-pick
+    wrapper, cache donated. Returns the compiled HLO text and the cache's
+    shapes, after checking that the module's outputs are the (64,) int32
+    ids, the (64, 1, vocab) float32 logits and the cache."""
     from repro.models import api
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    cfg = get_config("qwen2-0.5b")
     put = lambda tree: jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         tree)
     params = put(api.param_structs(cfg))
     cache = put(jax.eval_shape(
         lambda: api.init_paged_cache(cfg, 64, 8193, 16, 2048)))
-    assert cache["k"].shape == POOL
     tok = jax.ShapeDtypeStruct((64, 1), I32, sharding=one_chip)
-    hlo = jax.jit(api.decode_fn(cfg), donate_argnums=(2,)).lower(
-        params, tok, cache).compile().as_text()
-    assert "paged_attention" in hlo and "tpu_custom_call" in hlo
+    lowered = jax.jit(api.decode_pick_fn(cfg), donate_argnums=(2,)).lower(
+        params, tok, cache)
+    ids, logits, out_cache = lowered.out_info
+    assert (ids.shape, ids.dtype) == ((64,), jnp.int32)
+    assert (logits.shape, logits.dtype) == ((64, 1, cfg.vocab), jnp.float32)
+    assert jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), out_cache) \
+        == jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), cache)
+    hlo = lowered.compile().as_text()
+    entry = hlo[hlo.index("\nENTRY "):]
+    root = re.search(r"^\s*ROOT .*$", entry, re.M).group(0)
+    assert re.search(rf"= \(s32\[64\]\{{[^}}]*\}}, f32\[64,1,{cfg.vocab}\]",
+                     root), root[:200]
+    return hlo, cache
+
+
+def _pool_sized_moves(hlo):
+    """Operations outside the kernels that produce a pool-sized (8193-page)
+    or table-sized (8192-page) array, the in-place scatters excepted."""
     op = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
     big = []
     for line in hlo.splitlines():
@@ -208,8 +219,23 @@ def test_paged_decode_step_moves_no_pool(one_chip, monkeypatch):
         if m and {"8193", "8192"} & set(m.group(1).split(",")):
             big.append((m.group(2), "scatter" in line))
     assert big
-    moves = [kind for kind, scatter in big if not scatter and kind not in
-             ("parameter", "get-tuple-element", "bitcast")]
+    return [kind for kind, scatter in big if not scatter and kind not in
+            ("parameter", "get-tuple-element", "bitcast")]
+
+
+def test_paged_decode_step_moves_no_pool(one_chip, monkeypatch):
+    """The whole paged decode step of qwen2-0.5b, as the engine jits it
+    (the device-pick wrapper, cache donated): the kernel reads the stacked
+    pools in place and the new K/V is scattered into them, so no operation
+    outside the kernel produces a pool-sized (8193-page) or table-sized
+    (8192-page) array except the in-place scatters; the module returns the
+    s32[64] ids beside the logits and the cache."""
+    from repro.configs import get_config
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    hlo, cache = _engine_decode_hlo(get_config("qwen2-0.5b"), one_chip)
+    assert cache["k"].shape == POOL
+    assert "paged_attention" in hlo and "tpu_custom_call" in hlo
+    moves = _pool_sized_moves(hlo)
     assert not moves, moves
 
 
@@ -217,32 +243,17 @@ def test_lfm2_paged_decode_step_moves_no_pool(one_chip, monkeypatch):
     """The whole paged decode step of the lfm2-8b-a1b cell (its 11 layers:
     8 short convolutions, 3 attention layers over (3, 8193, 16, 512) bf16
     pools, 9 dropless expert layers), as the engine jits it: the expert
-    layers run the moe_experts kernel, and no operation outside the kernels
-    produces a pool-sized or table-sized array but the in-place scatters."""
+    layers run the moe_experts kernel, no operation outside the kernels
+    produces a pool-sized or table-sized array but the in-place scatters,
+    and the module returns the s32[64] ids beside the logits and the
+    cache."""
     import dataclasses
     from repro.configs import get_config
-    from repro.models import api
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
     cfg = dataclasses.replace(get_config("lfm2-8b-a1b"), n_layers=11)
-    put = lambda tree: jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        tree)
-    params = put(api.param_structs(cfg))
-    cache = put(jax.eval_shape(
-        lambda: api.init_paged_cache(cfg, 64, 8193, 16, 2048)))
+    hlo, cache = _engine_decode_hlo(cfg, one_chip)
     assert cache["k"].shape == (3, 8193, 16, 512)
     assert cache["conv"].shape == (8, 64, 2, 2048)
-    tok = jax.ShapeDtypeStruct((64, 1), I32, sharding=one_chip)
-    hlo = jax.jit(api.decode_fn(cfg), donate_argnums=(2,)).lower(
-        params, tok, cache).compile().as_text()
     assert "paged_attention" in hlo and "moe_experts" in hlo
-    op = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
-    big = []
-    for line in hlo.splitlines():
-        m = op.match(line)
-        if m and {"8193", "8192"} & set(m.group(1).split(",")):
-            big.append((m.group(2), "scatter" in line))
-    assert big
-    moves = [kind for kind, scatter in big if not scatter and kind not in
-             ("parameter", "get-tuple-element", "bitcast")]
+    moves = _pool_sized_moves(hlo)
     assert not moves, moves
