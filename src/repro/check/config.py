@@ -18,7 +18,6 @@ SHED_POLICIES = ("reject", "drop")
 PRECISIONS = ("float", "int8", "int8-xla", "w4a8")
 KV_CACHES = ("float", "int8")
 KV_LAYOUTS = ("contiguous", "paged")
-ATTN_IMPLS = ("full", "flash", "flash_tri")
 
 _DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1}
 
@@ -145,15 +144,10 @@ def check_serve_config(scfg, cfg=None, *, hbm_budget: Optional[int] = None,
     if scfg.kv_cache not in KV_CACHES:
         errs.append(f"unknown kv_cache: {scfg.kv_cache!r} "
                     f"(choose from {KV_CACHES})")
-    if scfg.attn_impl not in ATTN_IMPLS:
-        errs.append(f"unknown attn_impl: {scfg.attn_impl!r} "
-                    f"(choose from {ATTN_IMPLS})")
     for knob in ("max_batch", "max_len", "prefill_bucket"):
         v = getattr(scfg, knob)
         if not isinstance(v, int) or v < 1:
             errs.append(f"{knob} must be a positive int, got {v!r}")
-    if scfg.temperature < 0:
-        errs.append(f"temperature must be >= 0, got {scfg.temperature!r}")
     _check_resilience(scfg, errs)
     errs += unsupported(scfg, cfg)
 
@@ -167,9 +161,8 @@ def check_serve_config(scfg, cfg=None, *, hbm_budget: Optional[int] = None,
             errs.append(f"kv_block_size must be a positive int, got {bs!r}")
         elif isinstance(scfg.max_len, int) and scfg.max_len % bs:
             errs.append(f"kv_block_size={bs} must divide max_len="
-                        f"{scfg.max_len}: the gathered block-table view "
-                        "must span exactly max_len positions for paged "
-                        "decode to be bit-identical to contiguous")
+                        f"{scfg.max_len}: a slot's block table must span "
+                        "exactly max_len positions")
         nb = scfg.kv_num_blocks
         if nb is not None and (not isinstance(nb, int) or nb < 2):
             errs.append(f"kv_num_blocks must be an int >= 2 (page 0 is the "

@@ -2,7 +2,7 @@
 KV budget (legacy static drain scheduler as baseline; engine.Engine /
 EXPERIMENTS.md §Serving) with contiguous or paged KV backing — the paged
 layout pools fixed-size pages with hash-based prefix reuse
-(engine.BlockPool / EXPERIMENTS.md §Paged-KV) — plus the CNN microbatching
+(arena.BlockPool / EXPERIMENTS.md §Paged-KV) — plus the CNN microbatching
 engine that admits queued image requests into batched CompiledPlan rounds
 (cnn.CNNEngine / EXPERIMENTS.md §Throughput). Both engines degrade
 instead of dying under faults — every request ends in a terminal status

@@ -19,48 +19,28 @@ Two schedulers share one ``Engine`` API; ``ServeConfig.scheduler`` picks:
   before admitting more. Kept as the baseline that
   ``benchmarks/serve_bench.py`` measures continuous scheduling against.
 
-Sampling is greedy argmax by default; a positive temperature (per
-``ServeConfig`` with ``greedy=False``, or per-``Request`` override) switches
-that request to softmax sampling with the engine's seeded host rng. The
-decode call picks every row's greedy token on the device
-(``models/api.decode_pick_fn``) and a round copies back only those (B,)
-ids: ``Engine._pick`` gets, per row, a view that answers ``argmax()`` with
-the device's pick and copies its own logits row to the host only for a
-request that samples with a temperature (counted by
+Sampling is greedy argmax unless a ``Request`` carries a positive
+``temperature``, which switches that request to softmax sampling with the
+engine's seeded host rng. The decode call picks every row's greedy token
+on the device (``models/api.decode_pick_fn``) and a round copies back
+only those (B,) ids: ``Engine._pick`` gets, per row, a view that answers
+``argmax()`` with the device's pick and copies its own logits row to the
+host only for a request that samples (counted by
 ``serve.host_logit_rows``). Admission's first pick reads the prefill's
 last-position logits on the host.
 
-KV backing is picked by ``ServeConfig.kv_layout``:
-
-* ``"contiguous"`` (default) — every slot owns a (max_len,) KV row of the
-  one live batched cache; memory is ``max_batch x max_len`` regardless of
-  the actual sequence lengths.
-* ``"paged"`` — K/V live in a shared :class:`BlockPool` of fixed-size
-  pages (``kv_block_size`` positions each); each slot holds a block table
-  that grows one page at a time as the sequence crosses a page boundary,
-  so resident KV scales with *actual* tokens. Prompt pages are
-  content-addressed (chained sha1 over full prompt blocks): requests that
-  share a prompt prefix map their leading table entries to the same
-  refcounted pages, paying the prefix's prefill FLOPs and KV bytes once —
-  on a float-KV hit only the suffix runs through the model
-  (``models/api.prefill_suffix_fn``); int8-KV hits share storage only
-  (dequantized codes are not the float prefix, so the prompt is recomputed
-  and the shared-page writes skipped). Pages of retired requests linger in
-  an LRU "evictable" set until memory pressure reclaims them, so serial
-  repeats of a prefix still hit. When the pool runs dry the engine parks
-  new admissions in a FIFO holdback (backpressure) and, for mid-decode
-  growth, preempts the youngest slot (greedy decode makes the replayed
-  stream identical). Greedy token streams are BIT-IDENTICAL to the
-  contiguous layout for float and int8 KV alike: pages gather back into
-  exactly the contiguous cache view (``kv_block_size`` divides
-  ``max_len``), masked tail positions carry exact-zero attention weight,
-  and a prefix page's K/V are bitwise independent of the bucket the donor
-  prefilled under (tests/test_paged.py locks both properties). A pattern
-  stack's short convolutions keep a per-slot state beside the pages
-  (``models/api.slot_state_axes``): written from the prefill at
-  admission, carried by decode, cleared at retirement and preemption (a
-  preempted request's re-prefill rebuilds it); its prefix cache is
-  refused, since a hit would need that state at the page boundary.
+The continuous loop runs over one KV arena (``repro.serve.arena``), picked
+by ``ServeConfig.kv_layout``: a ``SlotArena`` (``"contiguous"``, the
+default; every slot owns a (max_len,) KV row) or a ``PagedArena``
+(``"paged"``; a shared :class:`BlockPool` of pages with hash-based prefix
+reuse). The arena owns what the layout decides (admission's prefill and
+cache write, page growth, the uploads before a round, releasing a slot);
+the loop owns the policy: admission order, the FIFO holdback of requests
+the pool cannot back yet, preemption of the youngest slot when growth
+runs dry (greedy decode replays the identical stream), retirement and
+retries. Greedy streams match across the layouts exactly for int8 KV;
+float paged pools decode through the ``paged_attention`` kernel, whose
+online softmax rounds differently from the contiguous attention.
 
 ``Engine.stats`` surfaces scheduler metrics: prefill/decode-round/token
 counters, slot occupancy (occupied slot-rounds over offered slot-rounds),
@@ -83,10 +63,10 @@ copy and the first pick), ``engine.upload`` (page growth and the
 ``serve.host_logit_rows``). The
 counters ``serve.admit_time_s``, ``serve.upload_time_s``,
 ``serve.sample_time_s`` and ``serve.decode_time_s`` add up the
-``perf_counter`` time of their spans. Under the paged layout,
-``engine.decode_round`` carries ``pages``, the KV pages per layer the
-round's rows hold (each row's new position included), which the paged
-attention reads; ``serve.kv_pages_read`` adds them up. A pattern stack
+``perf_counter`` time of their spans. ``engine.decode_round`` carries
+``pages``, the KV pages per layer the round's rows hold (each row's new
+position included), which the paged attention reads (0 under the
+contiguous layout); ``serve.kv_pages_read`` adds them up. A pattern stack
 (LFM2) with experts also puts ``moe_experts_hit`` (experts given a token,
 summed over its expert layers) and ``moe_assignments`` on
 ``engine.prefill`` and ``engine.decode_round``, read from the call's
@@ -135,10 +115,9 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
-import hashlib
 import queue
 import time
-from typing import Dict, List, Optional
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -149,6 +128,7 @@ from repro.faults import inject as faults
 from repro.models import api
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
+from repro.serve.arena import Arena, BlockPool, make_arena
 
 
 class QueueFullError(RuntimeError):
@@ -163,7 +143,7 @@ class Request:
     prompt: np.ndarray              # (S,) int32
     max_new_tokens: int = 16
     eos_id: Optional[int] = None    # overrides ServeConfig.eos_id when set
-    temperature: Optional[float] = None  # overrides the engine default
+    temperature: Optional[float] = None  # > 0 samples; None/<= 0: argmax
     deadline_s: Optional[float] = None   # overrides ServeConfig.deadline_s
     out_tokens: list = dataclasses.field(default_factory=list)
     done: bool = False
@@ -190,249 +170,6 @@ class Request:
         return max(self.admit_t - self.submit_t, 0.0)
 
 
-class BlockPool:
-    """Host-side page allocator + hash-based prefix cache for the paged KV
-    layout (``ServeConfig.kv_layout="paged"``).
-
-    Page 0 is RESERVED as the garbage page: never allocated, so a retired
-    slot's zeroed block-table row scatters its masked (never-read) decode
-    writes there without touching a live page.
-
-    Prompt pages are content-addressed: ``prefix_keys`` chains a sha1 over
-    each FULL prompt block (every digest covers all tokens up to and
-    including its block, so equal digest == equal token prefix), and
-    ``publish`` registers digest -> page after the page's K/V are written.
-    A page whose live refcount drops to zero is NOT freed — it parks in an
-    LRU *evictable* set with its digest mapping intact, so a later request
-    with the same prefix still hits (serial-traffic TTFT wins); ``alloc``
-    reclaims evictable pages oldest-first only once the free list runs
-    dry. Retention is safe because published pages are never written again
-    (decode writes land strictly past the last full prompt block) and
-    content-addressing guarantees a hit returns K/V computed from exactly
-    the hitting request's token prefix.
-
-    Single-threaded by design — the engine drives it between device calls.
-
-    Integrity: the pool validates its own transitions instead of silently
-    corrupting the free list — ``free`` / ``release`` raise ``ValueError``
-    on a double-free, an unknown page id, a page with live references, or
-    a parked (evictable) page; ``acquire`` revalidates that a refcount-0
-    page it is un-parking was not evicted in the meantime. :meth:`audit`
-    returns every violated structural invariant (conservation
-    ``free + live-in-use + parked == usable``, positive refcounts,
-    digest bijection) as a list — the chaos harness and the property
-    sweep in ``tests/test_faults.py`` call it after every op/drain.
-    """
-
-    def __init__(self, num_blocks: int, block_size: int,
-                 prefix_cache: bool = True):
-        if num_blocks < 2:
-            raise ValueError("BlockPool needs >= 2 pages (page 0 is the "
-                             "reserved garbage page)")
-        self.num_blocks = num_blocks
-        self.block_size = block_size
-        self.prefix_cache = prefix_cache
-        # pop() -> lowest id first; freed pages return LIFO (deterministic)
-        self._free: List[int] = list(range(num_blocks - 1, 0, -1))
-        self._allocated: set = set()          # handed out, not yet freed
-        self._ref: Dict[int, int] = {}        # page id -> live refcount
-        self._digest: Dict[str, int] = {}     # digest -> page id
-        self._page_digest: Dict[int, str] = {}
-        self._evictable: "collections.OrderedDict[int, None]" = \
-            collections.OrderedDict()         # refcount-0 hashed pages, LRU
-        self.lookups = 0                      # block-granular hit telemetry
-        self.hits = 0
-
-    # ------------------------------------------------------------ capacity --
-
-    @property
-    def usable(self) -> int:
-        return self.num_blocks - 1
-
-    @property
-    def free_pages(self) -> int:
-        """Allocatable pages: truly free plus evictable-on-demand."""
-        return len(self._free) + len(self._evictable)
-
-    @property
-    def in_use(self) -> int:
-        return self.usable - self.free_pages
-
-    # -------------------------------------------------------- prefix cache --
-
-    def prefix_keys(self, prompt: np.ndarray) -> List[str]:
-        """Chained sha1 digest per full prompt block, excluding the block
-        holding the last prompt token — at least one position is always
-        recomputed so admission has last-token logits to sample from."""
-        if not self.prefix_cache:
-            return []
-        bs = self.block_size
-        toks = np.ascontiguousarray(np.asarray(prompt, np.int32))
-        h = hashlib.sha1()
-        keys = []
-        for j in range((len(toks) - 1) // bs):
-            h.update(toks[j * bs:(j + 1) * bs].tobytes())
-            keys.append(h.hexdigest())
-        return keys
-
-    def lookup(self, keys: List[str]) -> List[int]:
-        """Page ids for the longest registered leading run of ``keys``.
-        Read-only: ``acquire`` the result before any ``alloc`` so eviction
-        cannot reclaim a page the caller is about to reference."""
-        ids = []
-        for k in keys:
-            bid = self._digest.get(k)
-            if bid is None:
-                break
-            ids.append(bid)
-        self.lookups += len(keys)
-        self.hits += len(ids)
-        return ids
-
-    def acquire(self, ids: List[int]) -> None:
-        """Take a live reference on hashed pages (un-parks evictable ones).
-
-        A refcount-0 page being un-parked is revalidated: it must still be
-        parked with its digest mapping intact — if ``alloc``'s eviction scan
-        reclaimed it since the lookup, referencing it would alias a page
-        now owned by another request, so that is a ``ValueError``."""
-        for bid in ids:
-            if bid in self._ref:
-                self._ref[bid] += 1
-                continue
-            if bid not in self._evictable or bid not in self._page_digest:
-                raise ValueError(
-                    f"acquire: page {bid} has no live references and is not "
-                    f"parked evictable — it was evicted (or never published); "
-                    f"re-run lookup before acquiring")
-            self._evictable.pop(bid)
-            self._ref[bid] = 1
-
-    def release(self, ids: List[int]) -> None:
-        """Drop a live reference; pages reaching zero park as evictable.
-        Releasing a page with no live reference (double-release, or an id
-        that was never acquired/published) is a ``ValueError``."""
-        for bid in ids:
-            if self._ref.get(bid, 0) < 1:
-                raise ValueError(
-                    f"release: page {bid} has no live reference "
-                    f"(double-release or unknown page id)")
-            self._ref[bid] -= 1
-            if self._ref[bid] == 0:
-                del self._ref[bid]
-                self._evictable[bid] = None
-
-    def publish(self, keys: List[str], ids: List[int]) -> None:
-        """Register freshly written full prompt blocks (digest -> page) and
-        take the writing request's live reference. The engine is
-        single-threaded, so a digest that missed at lookup is still absent
-        here — no collision handling needed."""
-        for k, bid in zip(keys, ids):
-            self._digest[k] = bid
-            self._page_digest[bid] = k
-            self._ref[bid] = self._ref.get(bid, 0) + 1
-
-    # --------------------------------------------------------- allocation --
-
-    def alloc(self, n: int) -> Optional[List[int]]:
-        """``n`` pages, or None when the pool cannot supply them (the
-        engine then applies admission backpressure / preemption). Evicts
-        LRU refcount-0 hashed pages only when the free list runs dry.
-        Fault seam ``blockpool.alloc`` fires before any state changes, so
-        an injected raise never half-allocates."""
-        faults.check("blockpool.alloc")
-        if n > self.free_pages:
-            return None
-        out = []
-        for _ in range(n):
-            if self._free:
-                out.append(self._free.pop())
-            else:
-                bid, _ = self._evictable.popitem(last=False)
-                del self._digest[self._page_digest.pop(bid)]
-                out.append(bid)
-        self._allocated.update(out)
-        return out
-
-    def free(self, ids: List[int], hashed: int = 0) -> None:
-        """Return a retired request's pages: the leading ``hashed`` ids
-        (published/hit prompt pages) drop a reference and park when it
-        reaches zero; the rest go straight back to the free list.
-
-        The unhashed tail is validated before any state changes: every id
-        must be a currently allocated page with no live references, not
-        parked evictable, and not published — a double-free or unknown id
-        raises ``ValueError`` instead of silently corrupting the free
-        list (the old behavior, which later handed one page to two
-        requests)."""
-        tail = ids[hashed:]
-        for bid in tail:
-            if bid not in self._allocated:
-                raise ValueError(
-                    f"free: page {bid} is not allocated "
-                    f"(double-free or unknown page id)")
-            if self._ref.get(bid, 0) > 0:
-                raise ValueError(
-                    f"free: page {bid} has {self._ref[bid]} live "
-                    f"reference(s) — release them (hashed=) instead")
-            if bid in self._evictable or bid in self._page_digest:
-                raise ValueError(
-                    f"free: page {bid} is published/parked — published "
-                    f"prompt pages retire via the hashed= prefix")
-        self.release(ids[:hashed])
-        self._allocated.difference_update(tail)
-        self._free.extend(tail)
-
-    # ------------------------------------------------------------ auditing --
-
-    def audit(self, expect_drained: bool = False) -> List[str]:
-        """Every violated structural invariant, as human-readable strings
-        (empty == healthy). With ``expect_drained=True`` additionally
-        requires quiescence: no live references and no allocated page
-        outside the evictable set (i.e. nothing leaked after a drain)."""
-        bad: List[str] = []
-        free_set = set(self._free)
-        if len(free_set) != len(self._free):
-            bad.append("duplicate ids on the free list")
-        if 0 in free_set or 0 in self._allocated:
-            bad.append("reserved garbage page 0 entered circulation")
-        both = free_set & self._allocated
-        if both:
-            bad.append(f"pages simultaneously free and allocated: "
-                       f"{sorted(both)}")
-        if len(self._free) + len(self._allocated) != self.usable:
-            bad.append(
-                f"conservation broken: free({len(self._free)}) + "
-                f"allocated({len(self._allocated)}) != usable({self.usable})")
-        for bid, r in self._ref.items():
-            if r < 1:
-                bad.append(f"page {bid} has non-positive refcount {r}")
-            if bid not in self._allocated:
-                bad.append(f"referenced page {bid} is not allocated")
-            if bid in self._evictable:
-                bad.append(f"page {bid} parked evictable with live refs")
-        for bid in self._evictable:
-            if bid not in self._allocated:
-                bad.append(f"evictable page {bid} is not allocated")
-            if bid not in self._page_digest:
-                bad.append(f"evictable page {bid} has no digest mapping")
-        for d, bid in self._digest.items():
-            if self._page_digest.get(bid) != d:
-                bad.append(f"digest bijection broken at digest {d[:12]}…")
-        for bid, d in self._page_digest.items():
-            if self._digest.get(d) != bid:
-                bad.append(f"digest bijection broken at page {bid}")
-        if expect_drained:
-            if self._ref:
-                bad.append(f"live references after drain: "
-                           f"{dict(sorted(self._ref.items()))}")
-            leaked = self._allocated - set(self._evictable) - set(self._ref)
-            if leaked:
-                bad.append(f"leaked pages (allocated, unreferenced, not "
-                           f"parked): {sorted(leaked)}")
-        return bad
-
-
 @dataclasses.dataclass
 class ServeConfig:
     """Engine knobs.
@@ -445,10 +182,6 @@ class ServeConfig:
                 can equal it, so only ``max_new_tokens`` or the ``max_len``
                 cap retire a sequence. ``Request.eos_id`` overrides per
                 request (including overriding a real id back to -1).
-    greedy:     True -> argmax decoding (ignores ``temperature``).
-    temperature: softmax temperature used when ``greedy=False`` (or when a
-                request carries its own ``temperature`` override). <= 0
-                degrades to argmax.
     scheduler:  "continuous" (slot refill between decode rounds) or
                 "static" (legacy drain batches).
     prefill_bucket: floor of the power-of-two right-padding buckets used by
@@ -456,8 +189,7 @@ class ServeConfig:
                 recurrences are position-exact, so those families always
                 prefill at the exact prompt length (one compile per
                 distinct length).
-    attn_impl:  prefill attention implementation ("flash" | "full" | ...).
-    seed:       host rng seed for temperature sampling.
+    seed:       host rng seed for a request's temperature sampling.
     precision:  "float" (default) serves as-is. "int8" PTQ-quantizes every
                 layer's FFN weights at engine init (power-of-two scales,
                 paper Eq. 4) and runs those matmuls int8 x int8 -> int32
@@ -479,15 +211,17 @@ class ServeConfig:
                 path decodes straight off the float prefill cache).
     kv_layout:  "contiguous" (default) gives every slot a (max_len,) KV
                 row. "paged" backs K/V with a BlockPool of kv_num_blocks
-                fixed-size pages instead — block tables grow on demand, a
-                shared prompt prefix is stored (and, for float KV,
-                prefilled) once, and greedy streams stay bit-identical to
-                the contiguous layout. Continuous scheduler +
-                attention-family dense caches only; composes with
-                kv_cache="int8" (int8 pool pages + scale pages).
+                fixed-size pages instead — block tables grow on demand and
+                a shared prompt prefix is stored (and, for float KV,
+                prefilled) once. Greedy streams match the contiguous
+                layout exactly for int8 KV; float pools decode through the
+                ``paged_attention`` kernel, whose online softmax rounds
+                differently. Continuous scheduler + attention-family dense
+                caches only; composes with kv_cache="int8" (int8 pool
+                pages + scale pages).
     kv_block_size: positions per page under kv_layout="paged". Must divide
-                max_len (the gathered block-table view then spans exactly
-                max_len positions — the bit-exactness precondition).
+                max_len (a slot's block table then spans exactly max_len
+                positions).
                 Smaller pages waste less tail memory but hash/grow more
                 often; prefix sharing is full-page-granular.
     kv_num_blocks: pool size under kv_layout="paged", including the
@@ -523,11 +257,8 @@ class ServeConfig:
     max_batch: int = 4
     max_len: int = 256
     eos_id: int = -1                # -1: never
-    greedy: bool = True
-    temperature: float = 0.0
     scheduler: str = "continuous"
     prefill_bucket: int = 16
-    attn_impl: str = "flash"
     seed: int = 0
     precision: str = "float"
     kv_cache: str = "float"
@@ -597,8 +328,7 @@ class Engine:
         self.scfg = scfg
         self.params = params
         self.prefill = jax.jit(
-            api.prefill_fn(cfg, scfg.max_len, attn_impl=scfg.attn_impl,
-                           precision=scfg.precision))
+            api.prefill_fn(cfg, scfg.max_len, precision=scfg.precision))
         # donate the live cache so slot writes / decode rounds update it in
         # place instead of copying the whole KV budget (CPU backends don't
         # implement donation and would warn on every compile, so skip there)
@@ -633,8 +363,7 @@ class Engine:
             self._gather_prefix = jax.jit(api.paged_gather_prefix)
             if scfg.prefix_cache and scfg.kv_cache == "float":
                 self.prefill_suffix = jax.jit(api.prefill_suffix_fn(
-                    cfg, attn_impl=scfg.attn_impl,
-                    precision=scfg.precision))
+                    cfg, precision=scfg.precision))
         self.queue: "queue.Queue[Request]" = queue.Queue()
         self._rng = np.random.default_rng(scfg.seed)
         # private registry: per-engine stats isolation; handles stay valid
@@ -727,12 +456,6 @@ class Engine:
         c["retries"] = int(m["retries"].value)
         c["arena_rebuilds"] = int(m["arena_rebuilds"].value)
         return c
-
-    def _update_pool_gauges(self, pool: BlockPool):
-        self._m["blocks_in_use"].set(pool.in_use)
-        self._m["blocks_free"].set(pool.free_pages)
-        self._m["prefix_hit_rate"].set(
-            pool.hits / pool.lookups if pool.lookups else 0.0)
 
     def _count_experts(self, cache: dict, sp):
         """Add the expert counts of the call that produced ``cache`` (its
@@ -844,9 +567,7 @@ class Engine:
         device's pick and whose logits come to the host only here, for a
         request that samples."""
         temp = req.temperature
-        if temp is None:
-            temp = 0.0 if self.scfg.greedy else self.scfg.temperature
-        if temp <= 0.0:
+        if temp is None or temp <= 0.0:
             return int(logits_row.argmax())
         z = np.asarray(logits_row, np.float64) / temp
         z -= z.max()
@@ -856,7 +577,7 @@ class Engine:
     def _effective_eos(self, req: Request) -> int:
         return self.scfg.eos_id if req.eos_id is None else req.eos_id
 
-    # --------------------------------------------------------- continuous --
+    # ------------------------------------------- shared by both schedulers --
 
     def _bucket_len(self, plen: int) -> int:
         # oversized prompts were already rejected by _validate_prompt_len
@@ -868,194 +589,260 @@ class Engine:
             b *= 2
         return min(b, self.scfg.max_len)
 
+    def _padded(self, toks: np.ndarray) -> np.ndarray:
+        """``toks`` right-padded to its length bucket, as a (1, bucket)
+        row: positions 0..len-1 hold the tokens."""
+        out = np.zeros((1, self._bucket_len(len(toks))), np.int32)
+        out[0, :len(toks)] = toks
+        return out
+
+    def _prefill(self, tokens: np.ndarray, prompt_lens=None, store=None,
+                 **attrs):
+        """THE prompt prefill, in one ``engine.prefill`` span (``attrs``):
+        ``tokens`` (b, S) are right-padded rows with their
+        ``prompt_lens``, or the static batch's left-padded rows. ``store``
+        gets the fresh cache (an arena's KV write), dispatched before the
+        logits come to the host. Returns the host logits and the fresh
+        cache."""
+        batch = {"tokens": jnp.asarray(tokens)}
+        if prompt_lens is not None:
+            batch["prompt_lens"] = jnp.asarray(prompt_lens, jnp.int32)
+        with obs_trace.span("engine.prefill", **attrs) as sp:
+            logits, fresh = self.prefill(self.params, batch)
+            self._m["prefills"].inc()
+            if store is not None:
+                store(fresh)
+            logits = np.asarray(logits)
+            if self._moe_counts:
+                self._count_experts(fresh, sp)
+        return logits, fresh
+
+    def _retry_call(self, site: str, fn, *args, **kwargs):
+        """``fn(*args, **kwargs)`` behind fault site ``site``, retried up
+        to ``max_retries`` times with exponential backoff. The site fires
+        before ``fn`` makes any device call or pool change, so only an
+        ``InjectedFault`` retries: a real exception may have consumed
+        donated buffers. Returns ``(result, fired, err)``: on success err
+        is None and fired the corrupt directive, if one fired; otherwise
+        result is None and err the absorbed exception."""
+        err: Optional[BaseException] = None
+        for attempt in range(self.scfg.max_retries + 1):
+            if attempt:
+                self._m["retries"].inc()
+                if self.scfg.retry_backoff_s > 0:
+                    time.sleep(self.scfg.retry_backoff_s
+                               * (2 ** (attempt - 1)))
+            try:
+                fired = faults.check(site)
+                return fn(*args, **kwargs), fired, None
+            except faults.InjectedFault as e:
+                err = e
+            except Exception as e:
+                return None, None, e
+        return None, None, err
+
+    def _retire(self, req: Request, status: str,
+                err: Optional[str] = None) -> Request:
+        """THE terminal bookkeeping of every request the engine finishes
+        (``ok``, ``timeout``, ``error``), admitted or not, continuous or
+        static: stamps, counters, latency histograms and trace lanes. The
+        caller releases the request's slot."""
+        now = time.perf_counter()
+        req.done = True
+        req.status = status
+        if err is not None:
+            req.error = err
+        req.admit_t = req.admit_t or now           # never admitted
+        req.first_token_t = req.first_token_t or now   # no first token
+        req.finish_t = now
+        req.finish_round = self._round
+        self._m["requests_done"].inc()
+        if status != "ok":
+            self._m["timeouts" if status == "timeout" else "errors"].inc()
+        self._observe_retired(req)
+        return req
+
+    def _finished(self, req: Request, n: int) -> bool:
+        """``req``, holding ``n`` KV positions, is done: its last token is
+        its EOS, it has ``max_new_tokens``, or it reached the max_len cap."""
+        return (req.out_tokens[-1] == self._effective_eos(req)
+                or len(req.out_tokens) >= req.max_new_tokens
+                or n >= self.scfg.max_len)
+
+    def _decode_round(self, cur: np.ndarray, cache: dict, active: int,
+                      pages: int = 0):
+        """One decode call over the batch, in the ``engine.decode_round``
+        span: ``(ids, logits, cache)``. The timer stops after
+        ``block_until_ready`` of every output, so ``decode_tok_s`` is
+        device time and not async-dispatch enqueue time. ``pages`` is what
+        a paged round reads (``serve.kv_pages_read``)."""
+        t0 = time.perf_counter()
+        with obs_trace.span("engine.decode_round", round=self._round,
+                            active=active) as sp:
+            sp.set(pages=pages)
+            out = self.decode(self.params, jnp.asarray(cur), cache)
+            jax.block_until_ready(out)
+            dt = time.perf_counter() - t0
+            if self._moe_counts:
+                self._count_experts(out[2], sp)
+        self._m["decode_time"].inc(dt)
+        self._m["kv_pages_read"].inc(pages)
+        return out
+
+    # --------------------------------------------------------- continuous --
+
+    def _new_arena(self, rebuild: bool = False) -> Arena:
+        """THE arena constructor: at each drain's start, and in place of
+        the old one after a decode round failed for good (the decode jit
+        donates the cache, so its buffers may be dead; a paged pool starts
+        empty too, since its digests name pages of the old cache)."""
+        attrs = {}
+        if rebuild:
+            self._m["arena_rebuilds"].inc()
+            attrs["rebuild"] = True
+        with obs_trace.span("engine.arena_init", layout=self.scfg.kv_layout,
+                            **attrs):
+            arena = make_arena(self)
+        self.pool = arena.pool
+        return arena
+
     def _run_continuous(self) -> List[Request]:
-        B = self.scfg.max_batch
-        paged = self.scfg.kv_layout == "paged"
-        bs = self.scfg.kv_block_size
-        with obs_trace.span("engine.arena_init",
-                            layout=self.scfg.kv_layout):
-            if paged:
-                from repro.check.config import paged_num_blocks
-                nblocks = paged_num_blocks(self.scfg)
-                cache = api.init_paged_cache(self.cfg, B, nblocks, bs,
-                                             self.scfg.max_len,
-                                             kv=self.scfg.kv_cache)
-                pool = BlockPool(nblocks, bs,
-                                 prefix_cache=self.scfg.prefix_cache)
-                self.pool = pool        # audited by repro.faults.chaos
-                table = np.zeros((B, self.scfg.max_len // bs), np.int32)
-                slot_ids: List[List[int]] = [[] for _ in range(B)]
-                slot_hashed = [0] * B   # leading refcounted pages per slot
-                holdback: "collections.deque[Request]" = collections.deque()
-                self._update_pool_gauges(pool)
-            else:
-                cache = api.init_slot_cache(self.cfg, B, self.scfg.max_len,
-                                            kv=self.scfg.kv_cache)
-        slots: List[Optional[Request]] = [None] * B
-        admit_seq = [0] * B             # admission order, for victim choice
-        seq = 0
-        lens = [0] * B                  # host mirror of cache["len"]
-        cur = np.zeros((B, 1), np.int32)
+        arena = self._new_arena()
+        # requests the paged pool could not back yet, or preempted; they
+        # go before the queue, so admission stays FIFO (empty under the
+        # contiguous layout, which never runs short)
+        holdback: "collections.deque[Request]" = collections.deque()
         finished: List[Request] = []
+        stalls = 0                      # passes with nothing to decode
+        while True:
+            # 1. refill free slots between rounds
+            self._refill(arena, holdback, finished)
+            if not arena.order:
+                if not holdback:
+                    break               # the queue is drained
+                # can't-admit stall: nothing active to retire and the
+                # holdback head still does not fit. Give retirements
+                # max_retries+1 passes to change that, then retire the head
+                # as "error" instead of deadlocking
+                stalls += 1
+                if stalls > self.scfg.max_retries:
+                    finished.append(self._retire(
+                        holdback.popleft(), "error",
+                        "paged KV pool cannot admit this request even "
+                        "with every page reclaimable — kv_num_blocks "
+                        "is below its worst-case page need"))
+                    stalls = 0
+                continue
+            stalls = 0
+            # 2. grow and upload: when the pool cannot back a row's next
+            # page, preempt the youngest admission. It restarts from its
+            # prompt through the holdback: greedy decode replays the same
+            # stream, and its published prompt pages usually survive as
+            # evictable, so the re-prefill hits. The discarded tokens stay
+            # in tokens_out (they were real decode work)
+            for _ in arena.prepare():
+                req = arena.release(arena.order[-1])
+                req.out_tokens, req.done = [], False
+                holdback.appendleft(req)
+            active = [i for i, r in enumerate(arena.reqs) if r is not None]
+            if not active:
+                continue                # preemption emptied the batch
+            # 3. decode, with retry
+            got, fired, err = self._retry_call(
+                "engine.decode_round", self._decode_round, arena.cur,
+                arena.cache, len(active), arena.pages(active))
+            if err is not None:
+                # the batch shares one donated cache, so no request can be
+                # singled out: retire the active set as "error" and go on
+                # draining against a fresh arena
+                for i in active:
+                    finished.append(self._retire(arena.reqs[i], "error",
+                                                 repr(err)))
+                arena = self._new_arena(rebuild=True)
+                continue
+            ids, logits, arena.cache = got
+            # 4. sample and retire
+            with obs_trace.span("engine.sample", active=len(active)) as sp:
+                if fired is None:
+                    # each row's pick was made on the device; a row's
+                    # logits reach the host only if its request samples
+                    ids = np.asarray(ids)
+                    rows = {i: _DeviceRow(logits, i, int(ids[i]))
+                            for i in active}
+                else:
+                    # corrupt directive: poison this round's host logits;
+                    # every request sampled from them is contained, not
+                    # fixed
+                    rows = fired.apply(np.asarray(logits))[:, -1]
+                    self.poisoned_uids.update(arena.reqs[i].uid
+                                              for i in active)
+                self._round += 1
+                self._m["decode_steps"].inc()
+                self._m["occupied"].inc(len(active))
+                now = time.perf_counter()
+                reqs, lens, cur = arena.reqs, arena.lens, arena.cur
+                for i in active:
+                    lens[i] += 1        # this round wrote K/V at lens[i]
+                    req = reqs[i]
+                    t = self._pick(rows[i], req)
+                    req.out_tokens.append(t)
+                    self._m["tokens_out"].inc()
+                    cur[i, 0] = t
+                    if self._finished(req, lens[i]):
+                        finished.append(self._retire(req, "ok"))
+                        arena.release(i)
+                    elif self._expired(req, now):   # round-boundary cancel
+                        finished.append(self._retire(req, "timeout"))
+                        arena.release(i)
+                host_rows = len(active) if fired is not None else \
+                    sum(rows[i].fetched for i in active)
+                sp.set(host_rows=host_rows)
+                self._m["host_logit_rows"].inc(host_rows)
+            self._m["sample_time"].inc(sp.seconds)
+            # 5. upload len
+            with obs_trace.span("engine.upload") as sp:
+                arena.upload_len()
+            self._m["upload_time"].inc(sp.seconds)
+        return finished
 
-        def next_request() -> Optional[Request]:
-            # holdback (pool-backpressured / preempted) drains before the
-            # queue so paged admission stays FIFO
-            if paged and holdback:
-                return holdback.popleft()
-            return self._next_request()
+    def _refill(self, arena: Arena, holdback, finished: List[Request]):
+        """Admit into every free slot, the holdback before the queue. A
+        slot whose request finished at admission (max_new_tokens=1, an
+        instant EOS, a failed admission) refills at once; a request
+        already past its deadline retires unadmitted (its prefill would be
+        wasted). A request the pool cannot back goes back to the head of
+        the holdback and ends the refill until retirements release
+        pages."""
+        for i in range(self.scfg.max_batch):
+            while arena.reqs[i] is None:
+                req = holdback.popleft() if holdback else self._next_request()
+                if req is None:
+                    break
+                if self._expired(req):
+                    finished.append(self._retire(req, "timeout"))
+                elif self._admit(arena, i, req, finished) == "full":
+                    holdback.appendleft(req)
+                    return
 
-        def admit_paged(i: int, req: Request, plen: int, sp):
-            """Returns last-position logits, or None when the pool cannot
-            supply the prompt's pages (admission backpressure). Exception-
-            safe: any failure after pages were referenced/allocated rolls
-            the pool back before re-raising, so a retried (or retired)
-            admission never leaks pages. ``sp`` is the engine.admit span,
-            which gets the page counts."""
-            nonlocal cache
-            nb = -(-plen // bs)         # pages covering positions [0, plen)
-            keys = pool.prefix_keys(req.prompt)
-            hit_ids = pool.lookup(keys)
-            n_hit = len(hit_ids)
-            sp.set(hit_blocks=n_hit, new_blocks=nb - n_hit)
-            # reference the hit pages BEFORE alloc so its eviction scan
-            # cannot reclaim them out from under this admission
-            pool.acquire(hit_ids)
-            try:
-                fresh = pool.alloc(nb - n_hit)
-            except Exception:
-                pool.release(hit_ids)   # injected blockpool.alloc fault
-                raise
-            if fresh is None:
-                pool.release(hit_ids)
-                return None
-            req.admit_t = time.perf_counter()
-            ids = hit_ids + fresh
-            try:
-                return _admit_paged_prefill(i, req, plen, keys, hit_ids,
-                                            fresh, ids, nb)
-            except Exception:
-                pool.release(hit_ids)
-                pool.free(fresh)        # unpublished: straight back
-                self._update_pool_gauges(pool)
-                raise
-
-        def _admit_paged_prefill(i, req, plen, keys, hit_ids, fresh, ids,
-                                 nb):
-            nonlocal cache
-            n_hit = len(hit_ids)
-            fids = np.asarray(fresh, np.int32)
-            if n_hit and "k_scale" not in cache:
-                # float-KV prefix hit: the shared pages already hold the
-                # prefix K/V — gather them and run ONLY the suffix (the
-                # near-zero-TTFT path)
-                pfx = n_hit * bs
-                s_sfx = plen - pfx
-                sbucket = self._bucket_len(s_sfx)
-                toks = np.zeros((sbucket,), np.int32)
-                toks[:s_sfx] = req.prompt[pfx:]
-                with obs_trace.span("engine.prefill", uid=req.uid, slot=i,
-                                    plen=plen, bucket=sbucket,
-                                    prefix_hit=pfx):
-                    pk, pv = self._gather_prefix(
-                        cache, np.asarray(hit_ids, np.int32))
-                    logits, ks, vs = self.prefill_suffix(self.params, {
-                        "tokens": jnp.asarray(toks[None, :]),
-                        "prefix_k": pk, "prefix_v": pv,
-                        "suffix_lens": jnp.asarray([s_sfx], jnp.int32)})
-                    self._m["prefills"].inc()
-                    cache = self._write_kv(cache, ks, vs, fids)
-                    logits = np.asarray(logits)
-            else:
-                # prefix miss — or an int8-KV hit, which shares STORAGE
-                # only: dequantized codes are not the float prefix the
-                # suffix math needs, so recompute the whole prompt and
-                # just skip writing the shared pages
-                bucket = self._bucket_len(plen)
-                toks = np.zeros((bucket,), np.int32)
-                toks[:plen] = req.prompt
-                with obs_trace.span("engine.prefill", uid=req.uid, slot=i,
-                                    plen=plen, bucket=bucket,
-                                    prefix_hit=n_hit * bs) as psp:
-                    logits, fresh_cache = self.prefill(self.params, {
-                        "tokens": jnp.asarray(toks[None, :]),
-                        "prompt_lens": jnp.asarray([plen], jnp.int32)})
-                    self._m["prefills"].inc()
-                    cache = self._write_pages(cache, fresh_cache, fids,
-                                              skip_blocks=n_hit)
-                    if self._write_state is not None:
-                        cache = self._write_state(cache, fresh_cache,
-                                                  jnp.int32(i))
-                    logits = np.asarray(logits)
-                    if self._moe_counts:
-                        self._count_experts(fresh_cache, psp)
-            # publish-at-admission: the fresh full prompt pages now hold
-            # their final K/V (decode writes land strictly past them)
-            pool.publish(keys[n_hit:], ids[n_hit:len(keys)])
-            slot_ids[i] = ids
-            slot_hashed[i] = len(keys)
-            table[i, :nb] = ids
-            table[i, nb:] = 0
-            self._update_pool_gauges(pool)
-            return logits
-
-        def try_admit(i: int, req: Request) -> str:
-            """Admit ``req`` into free slot ``i`` inside one engine.admit
-            span (an admission that finishes its request at once retires
-            it there too). Returns "ok", "full" (paged pool backpressure —
-            park in the holdback), or "failed" (the admission survived
-            max_retries and the request was retired with status="error")."""
-            with obs_trace.span("engine.admit", uid=req.uid,
-                                plen=len(req.prompt)) as sp:
-                res = admit_attempts(i, req, sp)
-                sp.set(result=res)
-                if res == "ok":
-                    maybe_retire(i)
-            self._m["admit_time"].inc(sp.seconds)
-            return res
-
-        def admit_attempts(i: int, req: Request, sp) -> str:
-            """try_admit's attempts. The ``engine.prefill`` fault seam
-            fires once per attempt, BEFORE any device call or pool
-            mutation, so an injected raise is always retry-safe."""
-            nonlocal cache, seq
+    def _admit(self, arena: Arena, i: int, req: Request,
+               finished: List[Request]) -> str:
+        """Admit ``req`` into free slot ``i`` in one ``engine.admit`` span:
+        the arena's prefill (retried), the logits copy, the first pick,
+        and retirement if that already finishes the request. Returns "ok",
+        "full" (the paged pool cannot back the prompt) or "failed" (the
+        admission outlived max_retries: retired as "error")."""
+        with obs_trace.span("engine.admit", uid=req.uid,
+                            plen=len(req.prompt)) as sp:
             self._validate_prompt_len(req)   # directly enqueued requests
-            plen = len(req.prompt)
-            last_err: Optional[BaseException] = None
-            for attempt in range(self.scfg.max_retries + 1):
-                if attempt:
-                    self._m["retries"].inc()
-                    if self.scfg.retry_backoff_s > 0:
-                        time.sleep(self.scfg.retry_backoff_s
-                                   * (2 ** (attempt - 1)))
-                try:
-                    fired = faults.check("engine.prefill")
-                    if paged:
-                        logits = admit_paged(i, req, plen, sp)
-                        if logits is None:
-                            return "full"
-                    else:
-                        bucket = self._bucket_len(plen)
-                        req.admit_t = time.perf_counter()
-                        toks = np.zeros((bucket,), np.int32)
-                        toks[:plen] = req.prompt  # right-pad: 0..plen-1
-                        with obs_trace.span("engine.prefill", uid=req.uid,
-                                            slot=i, plen=plen,
-                                            bucket=bucket):
-                            logits, fresh = self.prefill(self.params, {
-                                "tokens": jnp.asarray(toks[None, :]),
-                                "prompt_lens": jnp.asarray([plen],
-                                                           jnp.int32)})
-                            self._m["prefills"].inc()
-                            cache = self._write_slot(cache, fresh,
-                                                     jnp.int32(i))
-                            logits = np.asarray(logits)
-                except faults.InjectedFault as e:
-                    last_err = e        # fired pre-dispatch: retry is safe
-                    continue
-                except Exception as e:
-                    last_err = e        # real failure: state may be gone
-                    break               # (donated buffers) — do not retry
+            logits, fired, err = self._retry_call("engine.prefill",
+                                                  arena.admit, i, req, sp)
+            if err is not None:
+                res = "failed"
+                finished.append(self._retire(req, "error", repr(err)))
+            elif logits is None:
+                res = "full"
+            else:
+                res = "ok"
                 if fired is not None:   # corrupt directive: poison the
                     logits = fired.apply(logits)   # sampled logits only
                     self.poisoned_uids.add(req.uid)
@@ -1065,303 +852,13 @@ class Engine:
                 req.out_tokens.append(t)
                 self._m["tokens_out"].inc()
                 self._m["ttft"].observe(req.ttft_s)
-                cur[i, 0] = t
-                slots[i] = req
-                lens[i] = plen
-                admit_seq[i] = seq
-                seq += 1
-                return "ok"
-            retire_unadmitted(req, "error", repr(last_err))
-            return "failed"
-
-        def retire_unadmitted(req: Request, status: str,
-                              err: Optional[str] = None):
-            """Terminal bookkeeping for a request that never held a slot
-            (queue/holdback deadline expiry, failed admission)."""
-            now = time.perf_counter()
-            req.done = True
-            req.status = status
-            req.error = err
-            if req.admit_t == 0.0:
-                req.admit_t = now
-            if req.first_token_t == 0.0:
-                req.first_token_t = now
-            req.finish_t = now
-            req.finish_round = self._round
-            finished.append(req)
-            self._m["requests_done"].inc()
-            self._m["timeouts" if status == "timeout" else "errors"].inc()
-            self._observe_retired(req)
-
-        def retire_slot(i: int, status: str = "ok",
-                        err: Optional[str] = None):
-            """Retire slot ``i``'s request with terminal ``status`` and
-            reclaim its KV slot + pool pages — THE slot-release path, so
-            ok/timeout/error retirement can never diverge on cleanup."""
-            nonlocal cache
-            req = slots[i]
-            req.done = True
-            req.status = status
-            if err is not None:
-                req.error = err
-            req.finish_t = time.perf_counter()
-            req.finish_round = self._round
-            finished.append(req)
-            self._m["requests_done"].inc()
-            if status == "timeout":
-                self._m["timeouts"].inc()
-            elif status == "error":
-                self._m["errors"].inc()
-            self._observe_retired(req)
-            slots[i] = None
-            lens[i] = 0
-            if paged:
-                pool.free(slot_ids[i], hashed=slot_hashed[i])
-                slot_ids[i] = []
-                slot_hashed[i] = 0
-                table[i, :] = 0
-                self._update_pool_gauges(pool)
-            cache = api.cache_free_slot(cache, i)
-
-        def maybe_retire(i: int):
-            req = slots[i]
-            full = lens[i] >= self.scfg.max_len
-            if (req.out_tokens[-1] == self._effective_eos(req)
-                    or len(req.out_tokens) >= req.max_new_tokens or full):
-                retire_slot(i, "ok")
-
-        def preempt(victim: int):
-            """Evict the youngest slot mid-decode to free its pages. Its
-            request restarts from the prompt via the holdback — greedy
-            decode replays the identical stream (and its published prompt
-            pages usually survive as evictable, so the re-prefill hits)."""
-            nonlocal cache
-            req = slots[victim]
-            pool.free(slot_ids[victim], hashed=slot_hashed[victim])
-            # the discarded tokens stay in tokens_out (they were real decode
-            # work); the replay after re-admission counts its own
-            req.out_tokens = []
-            req.done = False
-            holdback.appendleft(req)
-            slots[victim] = None
-            lens[victim] = 0
-            slot_ids[victim] = []
-            slot_hashed[victim] = 0
-            table[victim, :] = 0
-            cache = api.cache_free_slot(cache, victim)
-            self._update_pool_gauges(pool)
-
-        def pool_alloc(n: int) -> Optional[List[int]]:
-            """``pool.alloc`` with the injected-fault seam absorbed: an
-            InjectedFault degrades to a transient shortage (None), which
-            the callers already handle via backpressure/preemption — so a
-            blockpool.alloc fault can never escape mid-decode."""
-            try:
-                return pool.alloc(n)
-            except faults.InjectedFault:
-                self._m["retries"].inc()
-                return None
-
-        def grow_tables() -> int:
-            """Allocate the next page for every slot whose write position
-            reached a page boundary; under pool pressure preempt youngest-
-            admitted slots (oldest-first processing guarantees progress —
-            a lone grower can always reclaim evictable pages). Returns the
-            number of pages allocated."""
-            order = sorted((i for i in range(B) if slots[i] is not None),
-                           key=lambda i: admit_seq[i])
-            grown = 0
-            for i in order:
-                if slots[i] is None:        # preempted by an older grower
-                    continue
-                pos = lens[i]
-                if pos >= self.scfg.max_len or pos % bs \
-                        or pos // bs < len(slot_ids[i]):
-                    continue
-                got = pool_alloc(1)
-                while got is None:
-                    victim = max((v for v in range(B)
-                                  if slots[v] is not None),
-                                 key=lambda v: admit_seq[v])
-                    preempt(victim)
-                    if victim == i:
-                        break
-                    got = pool_alloc(1)
-                if slots[i] is None or got is None:
-                    continue
-                slot_ids[i].append(got[0])
-                table[i, pos // bs] = got[0]
-                grown += 1
-            self._update_pool_gauges(pool)
-            return grown
-
-        def rebuild_arena():
-            """Fresh KV arena after an unrecoverable decode failure: the
-            decode jit donates the cache on accelerator backends, so the
-            old buffers must be assumed dead. The paged pool restarts
-            empty too — its prefix digests would otherwise resolve to
-            pages of the reset arena."""
-            nonlocal cache, pool
-            self._m["arena_rebuilds"].inc()
-            with obs_trace.span("engine.arena_init",
-                                layout=self.scfg.kv_layout, rebuild=True):
-                if paged:
-                    cache = api.init_paged_cache(self.cfg, B, nblocks, bs,
-                                                 self.scfg.max_len,
-                                                 kv=self.scfg.kv_cache)
-                    pool = BlockPool(nblocks, bs,
-                                     prefix_cache=self.scfg.prefix_cache)
-                    self.pool = pool
-                    table[:] = 0
-                    for i in range(B):
-                        slot_ids[i] = []
-                        slot_hashed[i] = 0
-                    self._update_pool_gauges(pool)
-                else:
-                    cache = api.init_slot_cache(self.cfg, B,
-                                                self.scfg.max_len,
-                                                kv=self.scfg.kv_cache)
-
-        stalls = 0                      # consecutive can't-admit iterations
-        decode_failures = 0             # consecutive failed round attempts
-        while True:
-            # refill free slots from the queue between decode rounds; the
-            # inner while re-admits into a slot whose request retired at
-            # admission (max_new_tokens=1 / instant EOS / failed / already
-            # past deadline). A paged admission the pool cannot back parks
-            # its request in the FIFO holdback and stops refilling until
-            # retirements release pages.
-            blocked = False
-            for i in range(B):
-                while slots[i] is None and not blocked:
-                    req = next_request()
-                    if req is None:
-                        break
-                    if self._expired(req):
-                        # expired while queued/parked: never admit — the
-                        # prefill would be wasted work past the budget
-                        retire_unadmitted(req, "timeout")
-                        continue
-                    if try_admit(i, req) == "full":
-                        holdback.appendleft(req)
-                        blocked = True
-                    # "ok" / "failed": retired inside try_admit if it is
-                    # done already — keep refilling
-                if blocked:
-                    break
-            active = [i for i in range(B) if slots[i] is not None]
-            if not active:
-                if paged and holdback:
-                    # can't-admit stall: nothing active to retire and the
-                    # holdback head still does not fit. Give retirements
-                    # max_retries+1 iterations to change the picture, then
-                    # retire the head as "error" instead of deadlocking or
-                    # killing the engine (the old RuntimeError) — the
-                    # message keeps the kv_num_blocks diagnosis.
-                    stalls += 1
-                    if stalls > self.scfg.max_retries:
-                        retire_unadmitted(
-                            holdback.popleft(), "error",
-                            "paged KV pool cannot admit this request even "
-                            "with every page reclaimable — kv_num_blocks "
-                            "is below its worst-case page need")
-                        stalls = 0
-                    continue
-                break                   # the admit loop drained the queue
-            stalls = 0
-            if paged:
-                with obs_trace.span("engine.upload") as sp:
-                    sp.set(new_blocks=grow_tables())
-                    active = [i for i in range(B) if slots[i] is not None]
-                    if active:
-                        cache["len"] = jnp.asarray(np.asarray(lens,
-                                                              np.int32))
-                        cache["block_table"] = jnp.asarray(table)
-                self._m["upload_time"].inc(sp.seconds)
-                if not active:
-                    continue            # preemption emptied the batch
-            try:
-                # the seam fires BEFORE the device call (retrying an
-                # injected fault is safe: the donated cache is untouched)
-                round_fired = faults.check("engine.decode_round")
-                t0 = time.perf_counter()
-                with obs_trace.span("engine.decode_round",
-                                    round=self._round,
-                                    active=len(active)) as sp:
-                    if paged:
-                        pages = sum(lens[i] // bs + 1 for i in active)
-                        sp.set(pages=pages)
-                    ids, logits, cache = self.decode(
-                        self.params, jnp.asarray(cur), cache)
-                    # block on ALL outputs before stopping the timer:
-                    # asarray alone would sync the ids but leave the
-                    # cache update in flight, skewing decode_tok_s by JAX
-                    # async dispatch
-                    jax.block_until_ready((ids, logits, cache))
-                    dt = time.perf_counter() - t0
-                    if self._moe_counts:
-                        self._count_experts(cache, sp)
-                self._m["decode_time"].inc(dt)
-                if paged:
-                    self._m["kv_pages_read"].inc(pages)
-            except Exception as e:
-                retriable = isinstance(e, faults.InjectedFault)
-                decode_failures += 1
-                if retriable and decode_failures <= self.scfg.max_retries:
-                    self._m["retries"].inc()
-                    if self.scfg.retry_backoff_s > 0:
-                        time.sleep(self.scfg.retry_backoff_s
-                                   * (2 ** (decode_failures - 1)))
-                    continue
-                # unrecoverable round: the batch shares one donated cache,
-                # so per-request attribution is impossible — retire the
-                # whole active set as "error" and rebuild the arena, then
-                # keep draining the queue against the fresh one
-                for i in active:
-                    retire_slot(i, "error", repr(e))
-                rebuild_arena()
-                decode_failures = 0
-                continue
-            decode_failures = 0
-            with obs_trace.span("engine.sample", active=len(active)) as sp:
-                if round_fired is not None:
-                    # corrupt directive: poison this round's host logits;
-                    # every active request sampled from them is contained,
-                    # not fixed
-                    rows = round_fired.apply(np.asarray(logits))[:, -1]
-                    for i in active:
-                        self.poisoned_uids.add(slots[i].uid)
-                else:
-                    # each row's pick was made on the device; a row's
-                    # logits reach the host only if its request samples
-                    ids = np.asarray(ids)
-                    rows = {i: _DeviceRow(logits, i, int(ids[i]))
-                            for i in active}
-                self._round += 1
-                self._m["decode_steps"].inc()
-                self._m["occupied"].inc(len(active))
-                now_r = time.perf_counter()
-                for i in active:
-                    lens[i] += 1        # this round wrote K/V at lens[i]
-                    req = slots[i]
-                    t = self._pick(rows[i], req)
-                    req.out_tokens.append(t)
-                    self._m["tokens_out"].inc()
-                    cur[i, 0] = t
-                    maybe_retire(i)
-                    if slots[i] is not None and self._expired(req, now_r):
-                        retire_slot(i, "timeout")   # round-boundary cancel
-                host_rows = len(active) if round_fired is not None else \
-                    sum(rows[i].fetched for i in active)
-                sp.set(host_rows=host_rows)
-                self._m["host_logit_rows"].inc(host_rows)
-            self._m["sample_time"].inc(sp.seconds)
-            # decode advanced every row's length, including retired/empty
-            # slots; re-zero them so dead rows can never drift past max_len
-            with obs_trace.span("engine.upload") as sp:
-                cache["len"] = jnp.asarray(np.asarray(lens, np.int32))
-            self._m["upload_time"].inc(sp.seconds)
-        return finished
+                arena.cur[i, 0] = t
+                if self._finished(req, arena.lens[i]):
+                    finished.append(self._retire(req, "ok"))
+                    arena.release(i)
+            sp.set(result=res)
+        self._m["admit_time"].inc(sp.seconds)
+        return res
 
     # ------------------------------------------------------------- static --
 
@@ -1374,31 +871,6 @@ class Engine:
             finished.extend(self._run_batch(batch))
         return finished
 
-    def _retry_call(self, site: str, fn):
-        """Run ``fn`` behind fault-site ``site`` with bounded retries.
-        Returns ``(result, fired, err)``: on success err is None and fired
-        is the corrupt directive (if one fired); after exhausting
-        ``max_retries`` (InjectedFault only — a real exception may have
-        consumed donated buffers, so it never retries) result is None and
-        err carries the absorbed exception."""
-        last_err: Optional[BaseException] = None
-        for attempt in range(self.scfg.max_retries + 1):
-            if attempt:
-                self._m["retries"].inc()
-                if self.scfg.retry_backoff_s > 0:
-                    time.sleep(self.scfg.retry_backoff_s
-                               * (2 ** (attempt - 1)))
-            try:
-                fired = faults.check(site)
-                return fn(), fired, None
-            except faults.InjectedFault as e:
-                last_err = e
-                continue
-            except Exception as e:
-                last_err = e
-                break
-        return None, None, last_err
-
     def _run_batch(self, reqs: List[Request]) -> List[Request]:
         b = len(reqs)
         plen = max(len(r.prompt) for r in reqs)
@@ -1408,24 +880,12 @@ class Engine:
         now = time.perf_counter()
         for r in reqs:
             r.admit_t = now
-
-        def do_prefill():
-            with obs_trace.span("engine.prefill", batch=b, plen=plen):
-                logits, cache = self.prefill(self.params,
-                                             {"tokens": jnp.asarray(toks)})
-                self._m["prefills"].inc()
-                return np.asarray(logits), cache
-
-        got, fired, err = self._retry_call("engine.prefill", do_prefill)
+        got, fired, err = self._retry_call("engine.prefill", self._prefill,
+                                           toks, batch=b, plen=plen)
         if err is not None:
-            # the static batch shares one prefill: retire it whole — the
-            # next _take_batch keeps draining the queue
-            for r in reqs:
-                r.status = "error"
-                r.error = repr(err)
-                r.done = True
-                self._m["errors"].inc()
-            return self._finish_batch(reqs)
+            # the batch shares one prefill: retire it whole; the next
+            # _take_batch keeps draining the queue
+            return [self._retire(r, "error", repr(err)) for r in reqs]
         lg, cache = got
         if fired is not None:
             lg = fired.apply(lg)
@@ -1448,37 +908,16 @@ class Engine:
             now = time.perf_counter()
             for r in reqs:
                 if not r.done and self._expired(r, now):
-                    r.done = True       # round-boundary cancellation
-                    r.status = "timeout"
-                    self._m["timeouts"].inc()
+                    self._retire(r, "timeout")  # round-boundary cancel
             if all(r.done for r in reqs):
                 break
-
-            def do_round():
-                t0 = time.perf_counter()
-                with obs_trace.span("engine.decode_round",
-                                    round=self._round,
-                                    active=sum(not r.done for r in reqs)):
-                    _, logits, new_cache = self.decode(
-                        self.params, jnp.asarray(cur), cache)
-                    # sync logits AND cache before stopping the timer (see
-                    # the continuous path): decode_tok_s must be device
-                    # time
-                    jax.block_until_ready((logits, new_cache))
-                self._m["decode_time"].inc(time.perf_counter() - t0)
-                return np.asarray(logits), new_cache
-
-            got, fired, err = self._retry_call("engine.decode_round",
-                                               do_round)
+            got, fired, err = self._retry_call(
+                "engine.decode_round", self._decode_round, cur, cache,
+                sum(not r.done for r in reqs))
             if err is not None:
-                for r in reqs:          # one shared (donated) cache: no
-                    if not r.done:      # per-request attribution possible
-                        r.status = "error"
-                        r.error = repr(err)
-                        r.done = True
-                        self._m["errors"].inc()
                 break
-            lg, cache = got
+            _, lg, cache = got
+            lg = np.asarray(lg)
             if fired is not None:
                 lg = fired.apply(lg)
                 self.poisoned_uids.update(
@@ -1499,18 +938,12 @@ class Engine:
                             or len(r.out_tokens) >= r.max_new_tokens):
                         r.done = True
             self._m["sample_time"].inc(sp.seconds)
-        return self._finish_batch(reqs)
-
-    def _finish_batch(self, reqs: List[Request]) -> List[Request]:
-        now = time.perf_counter()
         for r in reqs:
-            r.done = True
-            if r.status == "pending":
-                r.status = "ok"
-            if r.first_token_t == 0.0:  # batch failed before first token
-                r.first_token_t = now
-            r.finish_t = now
-            r.finish_round = self._round
-            self._m["requests_done"].inc()
-            self._observe_retired(r)
+            if r.status != "pending":
+                continue                # timed out mid-batch
+            if err is not None and not r.done:
+                # one shared (donated) cache: no per-request attribution
+                self._retire(r, "error", repr(err))
+            else:
+                self._retire(r, "ok")
         return reqs

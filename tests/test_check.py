@@ -274,7 +274,7 @@ def test_check_serve_config_enums_and_ranges():
     from repro.serve.engine import ServeConfig
     assert check_serve_config(ServeConfig()) == []
     errs = check_serve_config(ServeConfig(scheduler="bogus", max_batch=0,
-                                          temperature=-1.0))
+                                          prefill_bucket=0))
     assert len(errs) == 3
     errs = check_serve_config(ServeConfig(kv_cache="int8",
                                           scheduler="static"))

@@ -469,6 +469,55 @@ def test_engine_phase_counters_fit_the_drain(engine_setup, layout):
         assert seen[1][1] > seen[0][1] > 0.0
 
 
+@pytest.mark.parametrize("layout", ["paged", "contiguous"])
+def test_engine_round_device_calls_per_drain(engine_setup, layout):
+    """The device calls of a continuous round, as the benchmark's paged
+    cells run them: over two drains, two ``engine.upload`` spans a decode
+    round under the paged layout (``len`` + block table before the call,
+    ``len`` after it) and one under the contiguous; one
+    ``engine.decode_round`` span per ``serve.decode_steps``; one
+    ``engine.arena_init`` per drain, plus one per rebuild; and a fresh
+    ``Engine.pool`` each paged drain. A third drain whose decode round
+    fails for good rebuilds the arena once."""
+    from repro.faults import FaultPlan, FaultSpec
+    cfg, params = engine_setup
+    eng = Engine(cfg, params, ServeConfig(**dict(PAGED, kv_layout=layout)))
+    rng = np.random.default_rng(0)
+
+    def drain():
+        for uid in range(3):
+            eng.submit(Request(uid=uid, prompt=rng.integers(
+                0, 64, (4,)).astype(np.int32), max_new_tokens=3))
+        eng.run_until_drained()
+        return eng.pool
+
+    def count(name):
+        return sum(e["ph"] == "B" and e["name"] == name
+                   for e in obs_trace.TRACER.events())
+
+    obs_trace.TRACER.clear()
+    obs_trace.enable()
+    try:
+        pools = [drain(), drain()]
+        rounds = eng.stats["decode_steps"]
+        assert rounds > 0
+        assert count("engine.upload") == \
+            (2 if layout == "paged" else 1) * rounds
+        assert count("engine.decode_round") == rounds
+        assert count("engine.arena_init") == 2
+        if layout == "paged":
+            assert pools[0] is not None and pools[1] is not pools[0]
+        with FaultPlan([FaultSpec(site="engine.decode_round", kind="raise",
+                                  nth=1, times=3)], seed=0):
+            drain()
+        assert eng.stats["arena_rebuilds"] == 1
+        assert count("engine.arena_init") == 3 + 1
+        assert count("engine.decode_round") == eng.stats["decode_steps"]
+    finally:
+        obs_trace.disable()
+        obs_trace.TRACER.clear()
+
+
 def test_static_scheduler_sample_span(engine_setup, tmp_path):
     cfg, params = engine_setup
     box = {}
