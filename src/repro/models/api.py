@@ -104,6 +104,62 @@ def decode_pick_fn(cfg: ModelConfig, *, sp_axis: Optional[str] = None,
     return fn
 
 
+# the leaves the serving step reads only through .astype(compute_dtype)
+# (the audit is in serving_params' docstring)
+_STEP_CAST_KEYS = frozenset({
+    "wq", "wk", "wv", "wo", "bq", "bk", "bv",
+    "w_gate", "w_up", "w_down",
+    "in_proj", "out_proj", "conv_w",
+    "conv_b", "x_proj", "dt_proj", "dt_bias", "D"})
+
+
+def serving_params(cfg: ModelConfig, params):
+    """``params`` as the serving step reads it: every leaf the step reads
+    only through ``.astype(cfg.compute_dtype)`` rounded to the compute
+    dtype once, here, instead of inside every call.
+
+    The step's arithmetic is unchanged: it rounds these leaves to the
+    compute dtype before any use, and rounding once gives the same bits.
+    The decision is per leaf, from its key and its dtype: an array under
+    one of ``_STEP_CAST_KEYS`` and wider than the compute dtype is cast (a
+    widening cast would hold more bytes than the tree it came from); every
+    other leaf (one already in the compute dtype, one read at another
+    precision, a quantized ``QTensor``) is returned as the same object, so
+    a tree stored in the compute dtype comes back without a copy.
+    ``params`` is not mutated: dicts and lists are rebuilt around the
+    leaves.
+
+    The audit (every read of each key in ``models/`` and ``serve/``):
+
+    * cast, read only as ``.astype(cdt)``:
+      attention ``wq wk wv wo bq bk bv`` (``transformer._qkv``, the
+      ``attn_*`` functions and ``prefill_suffix`` for ``wo``, ``encdec``);
+      FFN ``w_gate w_up w_down`` (``blocks.mlp``, ``moe._expert_ffn``,
+      ``moe_ffn_sharded``, ``moe_ffn_dropless``); short convolution
+      ``in_proj out_proj conv_w`` (``shortconv``; ``shortconv_step``
+      widens ``conv_w`` to float32 after the cast); Mamba ``in_proj
+      out_proj conv_w conv_b x_proj dt_proj dt_bias D`` (``mamba_forward``,
+      ``mamba_decode_step``, ``transformer._mamba_forward_with_state``).
+    * kept, read at another precision: ``embed`` and ``unembed`` (the head
+      reads them in float32, ``transformer.unembed``), every norm weight
+      (``blocks.rmsnorm`` / ``layernorm`` read float32), ``router`` and
+      the expert ``bias`` (``moe.route``, float32), ``A_log`` (float32).
+      The PTQ tree ``qmlp`` (int8 / w4a8) holds no float leaf.
+    """
+    cdt = jnp.dtype(cfg.compute_dtype)
+
+    def walk(node, key):
+        if isinstance(node, dict):
+            return {k: walk(v, k) for k, v in node.items()}
+        if isinstance(node, list):
+            return [walk(v, key) for v in node]
+        if (key in _STEP_CAST_KEYS and isinstance(node, jax.Array)
+                and node.dtype.itemsize > cdt.itemsize):
+            return node.astype(cdt)
+        return node
+    return walk(params, None)
+
+
 def cache_specs(cfg: ModelConfig):
     if cfg.family == "encdec":
         return E.encdec_cache_specs()

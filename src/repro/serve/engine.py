@@ -29,6 +29,12 @@ host only for a request that samples (counted by
 ``serve.host_logit_rows``). Admission's first pick reads the prefill's
 last-position logits on the host.
 
+The jitted steps read ``api.serving_params(cfg, params)``: the matmul
+weights cast to the compute dtype once, at construction, instead of in
+every call (the same bits), beside the caller's tree, which is left as
+it is. The gauge ``serve.cast_weight_bytes`` holds the copies' bytes (0
+for a tree stored in the compute dtype).
+
 The continuous loop runs over one KV arena (``repro.serve.arena``), picked
 by ``ServeConfig.kv_layout``: a ``SlotArena`` (``"contiguous"``, the
 default; every slot owns a (max_len,) KV row) or a ``PagedArena``
@@ -324,6 +330,13 @@ class Engine:
             layers["qmlp"] = quantize_mlp_params(
                 layers["mlp"], bits=4 if scfg.precision == "w4a8" else 8)
             params = dict(params, layers=layers)
+        # after the PTQ, which reads the float32 FFN weights: the steps
+        # get the matmul weights cast once, here, not in every call
+        kept = {id(x) for x in jax.tree_util.tree_leaves(params)}
+        params = api.serving_params(cfg, params)
+        self._cast_weight_bytes = sum(
+            x.nbytes for x in jax.tree_util.tree_leaves(params)
+            if id(x) not in kept)
         self.cfg = cfg
         self.scfg = scfg
         self.params = params
@@ -399,6 +412,10 @@ class Engine:
             "blocks_in_use": self.metrics.gauge("serve.blocks_in_use"),
             "blocks_free": self.metrics.gauge("serve.blocks_free"),
             "prefix_hit_rate": self.metrics.gauge("serve.prefix_hit_rate"),
+            # bytes of the compute-dtype weight copies made at construction
+            # (api.serving_params); 0 when the tree is stored in it
+            "cast_weight_bytes": self.metrics.gauge(
+                "serve.cast_weight_bytes"),
             # resilience counters (EXPERIMENTS.md §Resilience): terminal
             # statuses other than "ok", retry attempts, arena rebuilds
             "timeouts": self.metrics.counter("serve.timeouts"),
@@ -414,6 +431,7 @@ class Engine:
     def reset_stats(self):
         """Zero the counters (e.g. after a compile-warmup drain)."""
         self.metrics.reset()
+        self._m["cast_weight_bytes"].set(self._cast_weight_bytes)
         self._round = 0
         # uids whose logits an injected "corrupt" fault poisoned — silent
         # corruption is contained (recorded), not detected; the chaos
